@@ -27,21 +27,6 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
-if not hasattr(_jax, "shard_map"):
-    # jax 0.4.x compat: the engine targets the jax.shard_map API
-    # (check_vma=); route through jax.experimental.shard_map, whose
-    # equivalent knob is check_rep=.
-    from jax.experimental.shard_map import shard_map as _esm_shard_map
-
-    def _shard_map_compat(f, mesh=None, in_specs=None, out_specs=None,
-                          check_vma=True, **kw):
-        return _esm_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=bool(check_vma), **kw,
-        )
-
-    _jax.shard_map = _shard_map_compat
-
 from presto_tpu.types import (  # noqa: E402
     BIGINT,
     BOOLEAN,

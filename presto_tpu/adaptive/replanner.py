@@ -44,9 +44,8 @@ SWAP_RATIO = 2
 # (StageStats.ici_bytes > 0) moved its freight over the device
 # interconnect — a flip to broadcast would move the SAME bytes back
 # onto the spool serde/HTTP wire, which ships this many times slower
-# per byte (ROOFLINE §16 measures the q3-family rung; the TPU v4
-# ICI:DCN ratio is far larger still). The flip must fit a budget
-# shrunk by this ratio before it can win.
+# per byte (the TPU v4 ICI:DCN ratio is far larger still). The flip
+# must fit a budget shrunk by this ratio before it can win.
 ICI_WIRE_RATIO = 16
 
 
@@ -103,7 +102,7 @@ class Replanner:
         byte share was wired) and stay under the per-buffer row
         ceiling. The byte test charges freight_bytes (ISSUE 17) —
         broadcast ships the spool over the WIRE once per consumer,
-        and after the per-column page codecs (ROOFLINE §14 table)
+        and after the per-column page codecs
         the measured wire bytes run 2-8x under the raw spool bytes
         the static planner had to assume; costing on raw bytes
         over-prices broadcast and leaves codec-friendly builds

@@ -32,7 +32,7 @@ class StageStats:
     task_rows: Tuple[int, ...]
     # measured post-codec wire bytes of the spool (ISSUE 17): what the
     # exchange actually ships after the per-column page codecs
-    # (dist/serde.py, ROOFLINE §14 codec table). Device-resident spool
+    # (dist/serde.py). Device-resident spool
     # entries that never serialized report their raw footprint, so
     # this is an upper bound on true freight. 0 = producer predates
     # the wire-stats plane (fall back to `bytes`).
@@ -55,7 +55,7 @@ class StageStats:
         """The byte count broadcast-vs-partitioned costing should
         charge: measured wire bytes when the producer reported them,
         else the raw spool bytes. Per-column codecs routinely ship
-        2-8x under raw (ROOFLINE §14), so costing on raw bytes
+        2-8x under raw, so costing on raw bytes
         systematically over-prices broadcast."""
         return self.wire_bytes if self.wire_bytes > 0 else self.bytes
 

@@ -222,12 +222,11 @@ def server_from_etc(etc_dir: str, port: Optional[int] = None, **kw):
     mem = int(conf.get("query.max-memory-bytes", "0")) or None
     # persistent compile cache (reference analog: compiled-artifact
     # reuse across queries): one dir per machine outlives every server
-    # process pointed at it
-    cache_dir = conf.get("compile-cache.dir", "")
-    if cache_dir:
-        from presto_tpu import compilecache
+    # process pointed at it. compilecache decides the directory
+    # (JAX_COMPILATION_CACHE_DIR, else this key, else its default)
+    from presto_tpu import compilecache
 
-        compilecache.enable_persistent_cache(cache_dir)
+    compilecache.enable_persistent_cache(conf.get("compile-cache.dir"))
     default_catalog = conf.get(
         "default-catalog", sorted(catalogs)[0]
     )

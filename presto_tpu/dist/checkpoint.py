@@ -222,7 +222,7 @@ class QueryCheckpoint:
                      sha: str) -> None:
         """Consumed-spool progress for one final-stage task: tokens +
         rolling sha256 of the consumed prefix (diagnostics + the
-        ROOFLINE cost model; resume correctness rides the client-page
+        cost model; resume correctness rides the client-page
         digests, not these)."""
         def mut(r):
             r["drain"].setdefault(str(fid), {})[str(index)] = {

@@ -357,7 +357,7 @@ class DistExecutor(Executor):
                 spec=spec, label="split-starts",
             )
             datas, valid = fn(start_arr)
-            # launch amortization (ROOFLINE §7): a mesh round is one
+            # launch amortization: a mesh round is one
             # program covering D splits — the same accounting the
             # split-batched local scan reports
             self.program_launches += 1

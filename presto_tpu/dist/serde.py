@@ -110,7 +110,7 @@ _DELTA_SIZE = {_DELTA8: 1, _DELTA16: 2, _DELTA32: 4}
 # the general-fallback compression level. The pre-v3 plane shipped
 # whole-payload zlib level 1; per-array framing lets the fallback
 # afford a denser level because only incompressible-after-codec
-# arrays reach it (ROOFLINE wire-cost table measures both).
+# arrays reach it.
 _ZLIB_LEVEL = 6
 # don't probe zlib below this: the deflate header + probe CPU cannot
 # win on tiny frames

@@ -288,7 +288,7 @@ def device_partition_pages(
     """Device-tier `partition_host_page`: ONE jitted program computes
     every partition assignment and compacts all `nparts` output pages
     to their ladder-bucket capacity without the page ever crossing to
-    host (ISSUE 13 — the ROOFLINE §11 d2h/h2d exchange pair deletes).
+    host (ISSUE 13 — the d2h/h2d exchange pair deletes).
     Every partition is emitted (empties carry all-False validity) so a
     replayed task regenerates an identical page sequence. The
     OR-reduced per-partition overflow flag joins the executor's

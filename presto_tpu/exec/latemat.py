@@ -4,7 +4,7 @@ Reference: presto-spi spi/block/DictionaryBlock.java — the reference
 engine's joins emit DictionaryBlocks over the build-side PagesIndex
 (positions + a shared values block) so carried columns are never copied
 per operator; values materialize once, at the first consumer that needs
-them. The TPU translation (ROOFLINE.md §4: the join chain is
+them. The TPU translation (the join chain is
 gather-bound at ~25 M rows/s per carried column, floor = 1 gather per
 column per JOIN) replaces the per-join value gathers with ONE int64
 row-id indirection column per build side:

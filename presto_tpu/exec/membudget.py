@@ -9,7 +9,7 @@ ladder BEFORE compile, so a pipeline's peak live device bytes is a
 static function of the plan — computable, checkable, and fixable
 (by chunked rewrites) before a single program launches.
 
-The model (ROOFLINE.md §8):
+The model:
 
     bytes(buffer)   = bucket(rows) * row_bytes        (the allocation)
     bytes(pipeline) = sum of concurrently-live buffer footprints
@@ -46,10 +46,6 @@ from typing import Callable, List, Optional
 
 from presto_tpu.exec import shapes as SH
 
-# Fallback HBM size when the runtime exposes no memory_stats (v5e:
-# 16 GiB per chip — the bench target in BASELINE.md).
-DEFAULT_TPU_HBM = 16 << 30
-
 # Fraction of HBM held back from the governor: runtime scratch,
 # compiled-program buffers, XLA temp allocations. budget = HBM * 7/8.
 HEADROOM_DIV = 8
@@ -71,36 +67,40 @@ STORE_SHARE_DIV = 2
 
 def device_hbm_bytes() -> Optional[int]:
     """Physical device memory of the default backend's first device,
-    None when the runtime does not expose it (CPU, some TPU stacks)."""
+    None when the runtime does not expose it (the CPU backend)."""
     import jax
 
-    try:
-        dev = jax.local_devices()[0]
-        stats = dev.memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") or stats.get(
-                "bytes_reservable_limit"
-            )
-            if limit:
-                return int(limit)
-    except Exception:  # noqa: BLE001 - memory_stats is an optional
-        pass           # backend API; absence means "unknown HBM"
+    stats = jax.local_devices()[0].memory_stats()
+    if stats:
+        limit = stats.get("bytes_limit") or stats.get(
+            "bytes_reservable_limit"
+        )
+        if limit:
+            return int(limit)
     return None
 
 
 def resolve_budget(setting: int, backend: Optional[str] = None) -> int:
     """device_memory_budget resolution: an explicit positive setting
     wins; 0 (auto) = real HBM minus headroom on TPU, the generous
-    CPU_BUDGET elsewhere."""
+    CPU_BUDGET elsewhere. A TPU whose runtime reports no memory limit
+    is an error, not a guessed size."""
     if setting and int(setting) > 0:
         return int(setting)
-    if backend is None:
-        import jax
+    import jax
 
+    if backend is None:
         backend = jax.default_backend()
     if backend != "tpu":
         return CPU_BUDGET
-    hbm = device_hbm_bytes() or DEFAULT_TPU_HBM
+    hbm = device_hbm_bytes()
+    if not hbm:
+        raise RuntimeError(
+            "device_memory_budget=auto needs the device's HBM size, "
+            "and memory_stats() of "
+            f"{jax.local_devices()[0].device_kind!r} reports no "
+            "bytes_limit; set device_memory_budget explicitly"
+        )
     return hbm - hbm // HEADROOM_DIV
 
 

@@ -67,7 +67,7 @@ SPAN_KINDS: Dict[str, str] = {
               "skew-hint counts, or rejected=true with the "
               "verify_dag reason when the mutation rolled back — "
               "the interval is the stats-summation + re-verify wall "
-              "the ROOFLINE §13 cost model prices",
+              "the re-plan cost model prices",
     "xfer": "one metered host<->device crossing (exec/xfer.py choke "
             "points): d2h:<label> pulls pages/arrays to host (spill, "
             "exchange serialization, result decode), h2d:<label> "
@@ -84,7 +84,7 @@ SPAN_KINDS: Dict[str, str] = {
     "checkpoint": "one durable coordinator-journal publish "
                   "(dist/checkpoint.py): attrs carry the record "
                   "state and serialized bytes — the barrier-write "
-                  "cost the ROOFLINE §18 model prices against the "
+                  "cost the checkpoint model prices against the "
                   "stage wall it rides on",
 }
 

@@ -1245,7 +1245,7 @@ class TaskRuntime:
                         # ladder) and spool the partition Pages
                         # themselves; host bytes materialize lazily
                         # only for HTTP (remote/replay) fetches. The
-                        # ROOFLINE §11 d2h-at-emit term deletes here.
+                        # d2h-at-emit term deletes here.
                         # with_counts: the same program also emits the
                         # per-partition row counts (spool-stats plane)
                         pp, counts = SPOOL.device_partition_pages(

@@ -22,7 +22,7 @@ stream, a refresh:
      / LIMIT) over the finalized page via a RemoteSource supplier.
 
 Cost: O(new rows) + O(group cardinality) per refresh instead of a
-full recompute — ROOFLINE §12's model. "Advance on write": the view's
+full recompute. "Advance on write": the view's
 result-cache entry carries its offset WATERMARK and is replaced in
 place by a refresh; the store's append-path reclaim keeps watermarked
 entries alive (cache/store.advance_tables).

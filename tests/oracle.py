@@ -19,7 +19,7 @@ from presto_tpu.connectors.base import Connector
 # Disk cache for loaded oracle databases: decoding the deterministic
 # generator pages into sqlite is pure (connector class, scale, tables)
 # — and slow enough that the bench oracle phase never finished inside
-# its 240s reserve (VERDICT Weak #8). Loaded DBs persist as sqlite
+# its 240s reserve. Loaded DBs persist as sqlite
 # files keyed by the load's content fingerprint; cache hits open the
 # file READ-ONLY (uri mode=ro), so a test that tried to mutate a
 # shared oracle fails loudly instead of poisoning later runs.

@@ -1,0 +1,227 @@
+"""Chip-compiler rehearsals: the main path's kernels compiled for a
+described (not attached) TPU v5e at real sizes.
+
+The TPU's compiler is installed here and compiles for a topology that
+is described, so what it refuses — a Pallas kernel that does not lower
+through Mosaic, a program over the device's memory, a collective that
+cannot be partitioned — is caught in tier-1, at no chip time. Nothing
+runs: these tests say nothing about results or speed (chip_smoke.py
+does, on the chip).
+
+The topology is described inside a module-scoped fixture (only the
+xdist worker that is handed this file loads the TPU library), and the
+persistent compile cache is off around the compiles: an entry written
+for a described device cannot be read back without a chip.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the
+tests compile the jitted kernels themselves and steer the few backend
+switches the kernels consult (``ops/agg._MM_BACKEND``, the dist
+executor's CPU-only rendezvous fence) to their TPU side.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as PS,
+    SingleDeviceSharding,
+)
+
+from presto_tpu import types as T
+from presto_tpu.exec import shapes as SH
+from presto_tpu.page import Block, Page
+
+PAGE_ROWS = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_branches(monkeypatch):
+    """The TPU side of the backend switches the compiled kernels read."""
+    from presto_tpu.ops import agg as A
+
+    monkeypatch.setattr(A, "_MM_BACKEND", True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(tree, sharding):
+    """The same pytree of shapes, placed on the described device(s)."""
+    return jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, sharding), tree)
+
+
+def _compile(fn, *specs):
+    return jax.jit(fn).lower(*specs).compile()
+
+
+def _bigint_page(n, sharding, columns=2):
+    col = _spec((n,), jnp.int64, sharding)
+    return Page(
+        blocks=tuple(Block(data=col, type=T.BIGINT, nulls=None,
+                           dictionary=None) for _ in range(columns)),
+        valid=_spec((n,), jnp.bool_, sharding),
+    )
+
+
+def test_dim_pallas_probe_lowers_through_mosaic(one_chip):
+    from presto_tpu.ops import pallas_join as PJ
+
+    layout = PJ.plan_layout(1800)
+    assert layout[0] == "dim" and PJ.layout_lowers_on_tpu(layout)
+    tables = tuple(_spec((layout[1], 8, 128), jnp.int32, one_chip)
+                   for _ in range(4))
+    compiled = _compile(
+        lambda ph, t: PJ.probe_index(ph, t, layout, interpret=False),
+        _spec((100352,), jnp.uint64, one_chip), tables)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pallas_partition_ids_lower_through_mosaic(one_chip):
+    from presto_tpu.dist import spool as SPOOL
+
+    compiled = _compile(
+        lambda pg: SPOOL._pallas_part_ids(
+            pg, (0, 1), (None, None), 8, interpret=False),
+        _bigint_page(PAGE_ROWS, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("qnum", [1, 6])
+def test_fused_page_step_compiles(qnum, one_chip, tpu_branches):
+    """Filter -> project -> partial aggregation of one 2^20-row
+    lineitem page as ONE program (the step __graft_entry__.entry()
+    exposes for Q1)."""
+    from presto_tpu.connectors.base import Split
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import agg_states as S
+    from presto_tpu.exec import plan as P
+    from presto_tpu.exec.executor import (
+        _partial_agg_page,
+        _partial_global_agg,
+        _project_page,
+    )
+    from presto_tpu.expr.eval import evaluate_filter
+    from presto_tpu.runner import LocalRunner
+    from tests.tpch_queries import QUERIES
+
+    conn = TpchConnector(scale=1.0)
+    runner = LocalRunner({"tpch": conn}, page_rows=PAGE_ROWS)
+    plan = runner.plan(QUERIES[qnum])
+
+    def find(node, t):
+        if isinstance(node, t):
+            return node
+        for c in node.children():
+            r = find(c, t)
+            if r is not None:
+                return r
+        return None
+
+    agg = find(plan, P.Aggregation)
+    while isinstance(agg.source, P.Aggregation):
+        agg = agg.source  # the partial step sits under the final one
+    filt, scan = find(plan, P.Filter), find(plan, P.TableScan)
+    in_types = runner.executor._agg_in_types(agg)
+    layouts = tuple(tuple(S.state_layout(s.function, t))
+                    for s, t in zip(agg.aggregates, in_types))
+
+    def step(page):
+        projected = _project_page(
+            agg.source.exprs,
+            evaluate_filter(filt.predicate, page, jnp))
+        if agg.group_channels:
+            return _partial_agg_page(
+                agg.group_channels, agg.aggregates, layouts, projected,
+                8, 64)
+        return _partial_global_agg(agg.aggregates, layouts, projected)
+
+    page = jax.eval_shape(lambda: conn.page_for_split(
+        Split("lineitem", 0, PAGE_ROWS), scan.columns))
+    assert page.capacity == PAGE_ROWS
+    _compile(step, _on(page, one_chip))
+
+
+@pytest.mark.parametrize("groups", [8, 4096])
+def test_onehot_matmul_aggregation_compiles(groups, one_chip):
+    from presto_tpu.ops import agg as A
+
+    compiled = _compile(
+        lambda data, ids: (A._mm_sum_int(data, ids, groups),
+                           A._mm_count(ids, groups)),
+        _spec((PAGE_ROWS,), jnp.int64, one_chip),
+        _spec((PAGE_ROWS,), jnp.int32, one_chip))
+    # the n x G one-hot fuses into the dot: it is never a buffer
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < PAGE_ROWS * groups)
+
+
+def test_sort_join_probe_compiles_at_the_build_ceiling(one_chip):
+    """searchsorted(method="sort") + gather of one 2^20-row probe page
+    against a SAFE_BUFFER_ROWS (2M) hash-sorted unique-key build."""
+    from presto_tpu.exec.executor import _probe_join_page_unique
+
+    nb = SH.SAFE_BUFFER_ROWS
+    index = (
+        (_spec((nb,), jnp.uint64, one_chip),),
+        _spec((nb,), jnp.bool_, one_chip),
+        _spec((nb,), jnp.uint64, one_chip),
+        _spec((nb,), jnp.int32, one_chip),
+    )
+    _compile(
+        lambda page, build, idx: _probe_join_page_unique(
+            (0,), (0,), "inner", False, page, build, idx, PAGE_ROWS),
+        _bigint_page(PAGE_ROWS, one_chip), _bigint_page(nb, one_chip),
+        index)
+
+
+def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
+    from presto_tpu.dist import executor as DX
+
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    d = mesh.devices.size
+    assert d == 4
+    rows = d * (1 << 18)
+    out_cap = SH.exchange_partition_cap(rows, d, 1)
+    program = DX._ici_program(None, mesh, (0,), (None,), 0, d, out_cap)
+    compiled = program.lower(
+        _bigint_page(rows, NamedSharding(mesh, PS("d")))).compile()
+    assert "all-to-all" in compiled.as_text()
+    # each device holds its shard, not the whole page
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < rows * (8 + 8 + 1)

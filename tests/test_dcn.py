@@ -128,7 +128,7 @@ def _boot_subprocess_worker(port_env, extra_env=None):
 
 @pytest.mark.slow
 def test_two_real_processes_and_kill(single):
-    """The VERDICT ring-3.5 gate, upgraded for fault-tolerant
+    """The ring-3.5 gate, upgraded for fault-tolerant
     execution: Q3 across 2 real OS processes matches single-process;
     a worker that hard-exits MID-QUERY (FAULT_KILL_AFTER_FETCHES) is
     recovered by task re-dispatch — the query COMPLETES with
@@ -321,7 +321,7 @@ def test_bare_scan_query_falls_back_local(coord, single):
 
 
 def test_union_cut_multijoin_distributes(coord, single):
-    """VERDICT r4 #7 done-criterion: a multi-join query with NO
+    """Done-criterion: a multi-join query with NO
     aggregation distributes across 2 workers (union cut: workers run
     the row-local join subtree over their split share, shipped as a
     serialized fragment; the coordinator unions the pages)."""
@@ -450,7 +450,7 @@ def test_session_props_reach_both_halves(workers, single):
 
 
 def test_partitioned_join_across_workers(workers, single):
-    """VERDICT r3 #5: a PARTITIONED join (both sides hash-split on the
+    """A PARTITIONED join (both sides hash-split on the
     join key — the DCN repartition exchange) across 2 workers matches
     single-process. partition_threshold=1 forces every scanned table
     into the co-partitioned set at this tiny SF."""

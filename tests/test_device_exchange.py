@@ -236,7 +236,7 @@ def test_mesh_local_exchange_zero_crossings(workers, q3_base):
     # d2h is the adaptive spool-stats plane (ISSUE 15): ONE int64
     # per spooled partition entry — the per-partition row-count
     # vector the device partition program emits alongside the pages
-    # (ROOFLINE §13). Pinning EXACT equality keeps the zero-copy
+    #. Pinning EXACT equality keeps the zero-copy
     # contract falsifiable: any real page pull would dwarf 8
     # bytes/entry.
     ex_h2d = at_stage["totals"]["h2d_bytes"] - t0["h2d_bytes"]
@@ -265,7 +265,7 @@ def test_mesh_local_exchange_zero_crossings(workers, q3_base):
 def test_host_spool_path_pays_the_copy_tax(workers):
     """The transfer-ledger diff the tentpole is graded by: the
     host-spool path records real h2d AND d2h exchange volume for the
-    same query the device tier completes at zero (the ROOFLINE §11
+    same query the device tier completes at zero (the
     d2h/h2d pair)."""
     coord = _coord(workers, device_exchange_enabled="false")
     t0 = XF.process_totals()

@@ -3,7 +3,7 @@
 Reference: spi/block/DictionaryBlock.java (joins emit indirections over
 the build PagesIndex; values materialize at the first consumer) and
 operator/ScanFilterAndProjectOperator.java (pipeline fusion), extended
-per ROOFLINE.md §4: carry build ROW IDS through the chain and gather
+further: carry build ROW IDS through the chain and gather
 each carried column exactly once; compile scan→filter→project→partial
 aggregation to one XLA program per split.
 

@@ -3,7 +3,7 @@
 __graft_entry__.dryrun_multichip is the contract the driver snapshot
 checks between rounds: an n-device virtual mesh running the FULL
 distributed engine step with exact single-device parity. It regressed
-silently between snapshots once (VERDICT Weak #7) because nothing in
+silently between snapshots once because nothing in
 tier-1 exercised it — this wrapper makes any future break loud.
 
 Runs in a SUBPROCESS because dryrun_multichip must set

@@ -155,9 +155,19 @@ def test_oracle_parity_under_bucketed_capacities(conn):
 
 
 # ------------------------------------------------- cache/session wiring
-def test_compile_cache_session_property(tmp_path):
+@pytest.fixture
+def restore_cache_placement():
+    """enable_persistent_cache is process-global: put the suite's own
+    placement back after a test re-points it."""
+    yield
+    CC.enable_persistent_cache()
+
+
+def test_compile_cache_session_property(tmp_path, monkeypatch,
+                                        restore_cache_placement):
     from presto_tpu.runner import LocalRunner
 
+    monkeypatch.delenv(CC.ENV_CACHE_DIR, raising=False)
     runner = LocalRunner(
         {"tpch": TpchConnector(scale=0.001)}, default_catalog="tpch"
     )
@@ -171,6 +181,125 @@ def test_compile_cache_session_property(tmp_path):
     out = runner.prewarm("select count(*) from lineitem")
     assert out["programs_compiled"] == 0
     assert out["cache_dir"] == cache_dir
+
+
+def _jax_cache_dir_setting():
+    """jax's own configured cache directory (what
+    enable_persistent_cache may or may not have written)."""
+    import jax
+
+    (name,) = [k for k in jax.config.values
+               if k.endswith("compilation_cache_dir")]
+    return jax.config.values[name]
+
+
+@pytest.mark.parametrize("env_set,via", [
+    (True, "argument"), (True, "session"), (True, "etc"),
+    (False, "default"), (False, "argument"),
+])
+def test_compile_cache_placement(env_set, via, tmp_path, monkeypatch,
+                                 restore_cache_placement):
+    """ONE place decides where the cache lives: where
+    JAX_COMPILATION_CACHE_DIR is set the cache is there — a caller's
+    path, the session property and the etc key are ignored and jax's
+    own setting is not written; where it is unset the default is the
+    fixed <checkout>/.jax_cache and a caller's path still wins."""
+    import os
+
+    env_dir = str(tmp_path / "from_env")
+    mine = str(tmp_path / "mine")
+    if env_set:
+        monkeypatch.setenv(CC.ENV_CACHE_DIR, env_dir)
+    else:
+        monkeypatch.delenv(CC.ENV_CACHE_DIR, raising=False)
+    before = _jax_cache_dir_setting()
+
+    if via == "session":
+        from presto_tpu.runner import LocalRunner
+
+        runner = LocalRunner(
+            {"tpch": TpchConnector(scale=0.001)},
+            default_catalog="tpch")
+        runner.session.set("compile_cache_dir", mine)
+        runner.apply_session()
+    elif via == "etc":
+        from presto_tpu.config import server_from_etc
+
+        etc = tmp_path / "etc"
+        (etc / "catalog").mkdir(parents=True)
+        (etc / "config.properties").write_text(
+            f"compile-cache.dir={mine}\n")
+        (etc / "catalog" / "tpch.properties").write_text(
+            "connector.name=tpch\ntpch.scale-factor=0.001\n")
+        server_from_etc(str(etc), port=0)
+    elif via == "argument":
+        CC.enable_persistent_cache(mine)
+    else:
+        CC.enable_persistent_cache()
+
+    if env_set:
+        assert CC.cache_dir() == env_dir
+        assert _jax_cache_dir_setting() == before  # nothing written
+        assert not os.path.exists(mine)
+    elif via == "default":
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        assert CC.cache_dir() == os.path.join(checkout, ".jax_cache")
+        assert CC.cache_dir() == CC.DEFAULT_CACHE_DIR
+        assert _jax_cache_dir_setting() == CC.DEFAULT_CACHE_DIR
+    else:
+        assert CC.cache_dir() == mine
+        assert _jax_cache_dir_setting() == mine
+
+
+def test_tpu_budget_needs_the_device_memory_limit(monkeypatch):
+    """On a TPU whose runtime reports no memory limit, auto budget
+    resolution is an error that names the device — never a guessed
+    HBM size."""
+    from presto_tpu.exec import membudget as MB
+
+    monkeypatch.setattr(MB, "device_hbm_bytes", lambda: None)
+    with pytest.raises(RuntimeError, match="bytes_limit") as err:
+        MB.resolve_budget(0, "tpu")
+    import jax
+
+    assert jax.local_devices()[0].device_kind in str(err.value)
+    # an explicit setting never asks the device
+    assert MB.resolve_budget(1 << 30, "tpu") == 1 << 30
+    monkeypatch.setattr(MB, "device_hbm_bytes", lambda: 16 << 30)
+    assert MB.resolve_budget(0, "tpu") == (16 << 30) * 7 // 8
+
+
+def test_forced_pallas_layout_raises_on_tpu(monkeypatch):
+    """pallas_join_enabled=force on a TPU backend never falls back to
+    interpret mode: a layout (or the Pallas aggregation) that does not
+    lower raises, naming it. The CPU keeps interpret mode."""
+    import jax
+
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.ops import pallas_join as PJ
+
+    radix = PJ.plan_layout(1 << 16)
+    dim = PJ.plan_layout(1000)
+    assert radix[0] == "radix" and dim[0] == "dim"
+    assert Executor._pallas_interpret(radix) is True  # CPU: interpret
+    assert Executor._pallas_interpret(dim) is True
+
+    ex = Executor({"tpch": TpchConnector(scale=0.001)})
+    ex.pallas_join = "force"
+    assert ex._pallas_agg_on() is True
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert Executor._pallas_interpret(dim) is False  # lowers for real
+    with pytest.raises(NotImplementedError, match="'radix'"):
+        Executor._pallas_interpret(radix)
+    with pytest.raises(NotImplementedError,
+                       match="Pallas aggregation"):
+        ex._pallas_agg_on()
+    ex.pallas_join = "auto"
+    assert ex._pallas_agg_on() is False
+    assert ex._pallas_mode_allows(dim) is True
+    assert ex._pallas_mode_allows(radix) is False
 
 
 def test_explain_analyze_reports_compile_counters(conn):

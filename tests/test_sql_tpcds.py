@@ -22,7 +22,7 @@ SF = 0.01
 # the same store, within the qualified color/price band) is empty below
 # SF ~0.025, and the 18-table plan takes many minutes of XLA compile on
 # the 1-core CPU CI — so the Q64 correctness test runs at its own scale,
-# opt-in via RUN_SLOW=1 (same pattern as test_tpu_smoke.py). It is part
+# opt-in via RUN_SLOW=1. It is part
 # of the bench ladder on real hardware.
 Q64_SF = 0.025
 

@@ -268,7 +268,7 @@ def test_execute_rejects_broken_plan_before_compile(tiny_runner):
 
 # ------------------------------------------------------ mutation suite
 # Each seeded-broken plan must be rejected with a message pointing at
-# the exact invariant — these are the drifts VERDICT round 5 lost
+# the exact invariant — these are the drifts round 5 lost
 # correctness gates to.
 
 _VALUES2 = P.Values(types=(T.BIGINT, T.DOUBLE), rows=((1, 2.0),))

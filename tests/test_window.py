@@ -73,7 +73,7 @@ CASES = [
                                       order by o_orderkey)
        from orders where o_custkey % 5 = 0
        order by o_orderkey limit 100""",
-    # distribution + ntile (round 3: VERDICT r2 weak-8)
+    # distribution + ntile (round 3)
     """select o_custkey, o_orderkey,
               ntile(4) over (partition by o_custkey order by o_orderkey),
               percent_rank() over (partition by o_custkey

@@ -82,7 +82,7 @@ def _wire_misestimate_case(failures: list) -> None:
     """ISSUE 17: one seeded wire-misestimate pin. A build whose RAW
     spool bytes blow the broadcast byte share but whose MEASURED
     post-codec wire bytes fit (scan-ordered keys delta+deflate to
-    almost nothing, ROOFLINE §14) must pass the re-planner's
+    almost nothing) must pass the re-planner's
     broadcast test — and the pre-wire-stats behavior (raw-byte
     costing) must be reproduced exactly by wire_bytes=0, so legacy
     producers never get mis-flipped."""
