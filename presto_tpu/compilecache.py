@@ -18,6 +18,16 @@ EXPLAIN ANALYZE / tools/analyze_rung.py / tools/compile_stats.py /
 bench.py report the deltas. A persistent-cache HIT does not count as a
 compile — `programs_compiled == 0` on a warmed run is the contract.
 
+Program load, split (ISSUE 25): what a program's first call costs in a
+process has four parts, and the hooks below keep a process total of
+each — `program_trace_wall_s` (Python tracing to a jaxpr),
+`program_lower_wall_s` (jaxpr to an MLIR module), which no cache saves,
+and `program_retrieval_wall_s` (reading and loading an executable from
+the persistent cache) beside `compile_wall_s` (a real XLA compile). A
+jitted function traced while another is being traced (most of `jnp` is
+jitted) reports its own trace event inside the outer one's interval:
+the total counts the union, per thread, not the sum.
+
 Counters are process-global (jax compiles are); concurrent queries in
 one process attribute each other's compiles to whichever query's
 window they land in — same caveat as every process-wide metric.
@@ -26,6 +36,8 @@ window they land in — same caveat as every process-wide metric.
 from __future__ import annotations
 
 import os
+import threading
+import time
 from typing import Dict, List, Optional
 
 from presto_tpu.obs.sanitizer import make_lock
@@ -40,6 +52,8 @@ from presto_tpu.obs.sanitizer import make_lock
 # minus the hits' retrieval wall.
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAXPR_TO_MLIR = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
@@ -50,7 +64,15 @@ _raw: Dict[str, float] = {
     "hits": 0,
     "retrieval_wall_s": 0.0,
     "misses": 0,
+    "traces": 0,
+    "trace_wall_s": 0.0,
+    "lowerings": 0,
+    "lower_wall_s": 0.0,
 }
+# per thread, the trace events not yet seen inside an outer one:
+# (start, duration) in completion order, the newest few only
+_tls = threading.local()
+_MAX_OPEN_TRACES = 256
 # recent per-request walls (tools/compile_stats.py's per-program
 # breakdown; a persistent-cache hit's wall is its retrieval time);
 # bounded so a long-lived server can't grow it
@@ -60,8 +82,32 @@ _installed = False
 _cache_dir: Optional[str] = None
 
 
+def _outermost_part(duration: float) -> float:
+    """The part of a trace event's duration that no event already
+    counted on this thread lies inside: an inner jitted function's
+    event ends before the outer's and began after it, so the outer
+    one takes back what its inner ones added."""
+    start = time.perf_counter() - duration
+    stack = _tls.__dict__.setdefault("traces", [])
+    inner = 0.0
+    while stack and stack[-1][0] >= start:
+        inner += stack.pop()[1]
+    stack.append((start, duration))
+    del stack[:-_MAX_OPEN_TRACES]
+    return max(duration - inner, 0.0)
+
+
 def _on_duration(event: str, duration: float, **kw) -> None:
-    if event == _BACKEND_COMPILE:
+    if event == _JAXPR_TRACE:
+        part = _outermost_part(duration)
+        with _lock:
+            _raw["traces"] += 1
+            _raw["trace_wall_s"] += part
+    elif event == _JAXPR_TO_MLIR:
+        with _lock:
+            _raw["lowerings"] += 1
+            _raw["lower_wall_s"] += duration
+    elif event == _BACKEND_COMPILE:
         with _lock:
             _raw["requests"] += 1
             _raw["request_wall_s"] += duration
@@ -109,14 +155,24 @@ def snapshot() -> Dict[str, float]:
             ),
             "program_cache_hits": int(_raw["hits"]),
             "persistent_cache_misses": int(_raw["misses"]),
+            "programs_traced": int(_raw["traces"]),
+            "program_trace_wall_s": _raw["trace_wall_s"],
+            "programs_lowered": int(_raw["lowerings"]),
+            "program_lower_wall_s": _raw["lower_wall_s"],
+            "program_retrieval_wall_s": _raw["retrieval_wall_s"],
         }
 
 
+_WALLS = ("compile_wall_s", "program_trace_wall_s",
+          "program_lower_wall_s", "program_retrieval_wall_s")
+
+
 def delta(since: Dict[str, float]) -> Dict[str, float]:
-    """Counter deltas since a snapshot(), rounding the wall."""
+    """Counter deltas since a snapshot(), rounding the walls."""
     cur = snapshot()
     out = {k: cur[k] - since.get(k, 0) for k in cur}
-    out["compile_wall_s"] = round(max(out["compile_wall_s"], 0.0), 3)
+    for k in _WALLS:
+        out[k] = round(max(out[k], 0.0), 3)
     return out
 
 

@@ -51,6 +51,16 @@ class Split:
     row_count: int
 
 
+def _launch_on_sink(program, *args):
+    """A connector's program called at THE launch point
+    (exec/programs.py), counted on the executor that is running a
+    query on this thread (none: called uncounted)."""
+    from presto_tpu.exec import programs as PG
+    from presto_tpu.exec import xfer as XF
+
+    return PG.launch(XF.current_sink(), program, *args)
+
+
 class GeneratorConnector:
     """Mixin for on-device deterministic generators (tpch/tpcds): column-
     pruned, jit-compiled chunk generation from the global row index.
@@ -94,9 +104,11 @@ class GeneratorConnector:
         Generated rows past the real count mask out of `valid` (the
         generators are unbounded past the table end; the dist scan
         relies on the same property)."""
-        import jax
+        import functools
+
         import jax.numpy as jnp
 
+        from presto_tpu.exec import programs as PG
         from presto_tpu.exec import shapes as SH
 
         n_pad = SH.bucket(n)
@@ -109,8 +121,8 @@ class GeneratorConnector:
                 in_range = jnp.arange(_n, dtype=jnp.int64) < count
                 return datas, valid & in_range
 
-            self._gen_cache[key] = jax.jit(padded)
-        return self._gen_cache[key]
+            self._gen_cache[key] = PG.Program("scan_gen", padded)
+        return functools.partial(_launch_on_sink, self._gen_cache[key])
 
     def _lazy_rows(self, table: str, start, n: int):
         """The table's _Lazy over rows [start, start+n). Tables whose

@@ -20,6 +20,9 @@ def drain(tree) -> None:
     import jax
     import numpy as np
 
+    from presto_tpu.exec.xfer import device_wait
+
     leaves = jax.tree_util.tree_leaves(tree)
     if leaves and hasattr(leaves[-1], "ravel") and leaves[-1].size:
-        np.asarray(leaves[-1].ravel()[:1])
+        with device_wait("drain"):
+            np.asarray(leaves[-1].ravel()[:1])

@@ -189,6 +189,9 @@ class DcnRunner:
         # reference's node-rejoin model)
         self._excluded: set = set()
         self._rng = random.Random()
+        # the serving coordinator's trace of the statement being run
+        # (server/http_server._DcnServerRunner sets it around execute)
+        self.handed_trace = None
         cat = default_catalog or next(iter(catalogs))
         self.runner = LocalRunner(
             catalogs,
@@ -654,13 +657,39 @@ class DcnRunner:
             **self.runner._session_dist_options(),
         )
 
+    def _begin_trace(self, qid: str, sql: Optional[str] = None):
+        """(trace, whether this runner owns it): the coordinator's
+        trace of the statement where the serving coordinator handed
+        one over (``handed_trace``; its owner ends and writes it),
+        else one of this runner's own when the session traces."""
+        from presto_tpu import obs as OBS
+
+        trace, owned = self.handed_trace, False
+        if trace is None:
+            trace = OBS.maybe_trace(self.runner.session, query_id=qid,
+                                    sql=sql)
+            owned = True
+        if trace is not None:
+            OBS.attach(self.runner.executor, trace)
+        return trace, owned
+
+    def _end_trace(self, trace, owned: bool) -> None:
+        from presto_tpu import obs as OBS
+
+        if not owned:
+            OBS.detach(self.runner.executor, trace)
+            return
+        if trace is not None:
+            OBS.finalize(self.runner.executor, trace,
+                         self.runner.session.get("query_trace_dir"))
+        self.runner.last_trace = trace
+
     def _execute_dag(self, dag):
         """Run a fragmented DAG through the general stage scheduler
         (dist/scheduler.py): spooled exchanges, non-leaf replay,
         straggler speculation, per-stage pool recomputation."""
         import uuid as _uuid
 
-        from presto_tpu import obs as OBS
         from presto_tpu.dist.scheduler import StageScheduler
 
         self.last_distribution = "stage-dag"
@@ -669,9 +698,7 @@ class DcnRunner:
         # (it snapshots ex.trace); the coordinator's root-fragment
         # execute() records its attempt/operator spans into the same
         # trace, so one timeline covers stages + final drain
-        trace = OBS.maybe_trace(self.runner.session, query_id=qid)
-        if trace is not None:
-            OBS.attach(self.runner.executor, trace)
+        trace, owned = self._begin_trace(qid)
         sched = StageScheduler(self, dag, qid,
                                stage_hook=self._stage_hook)
         self.last_scheduler = sched
@@ -681,10 +708,7 @@ class DcnRunner:
                                              None)
             return rows
         finally:
-            if trace is not None:
-                OBS.finalize(self.runner.executor, trace,
-                             self.runner.session.get("query_trace_dir"))
-            self.runner.last_trace = trace
+            self._end_trace(trace, owned)
 
     # ---------------------------------------------------------- execute
     def execute(self, sql: str):
@@ -757,7 +781,7 @@ class DcnRunner:
                 # probe timeouts)
                 self.last_distribution = "local"
                 self.last_pool = []
-                res = self.runner.execute(sql)
+                res = self.runner.execute(sql, trace=self.handed_trace)
                 self.last_output_names = list(res.column_names)
                 return res.rows
             partition_cols = hash_fanout_source(
@@ -789,17 +813,12 @@ class DcnRunner:
         # launch one task per pooled worker; the task body carries the
         # SERIALIZED fragment (plan shipping — reference:
         # TaskUpdateRequest.fragment), not SQL to replay
-        from presto_tpu import obs as OBS
-
         fragment = plan_serde.dumps(partial)
         qid = uuid.uuid4().hex[:12]
         # lifecycle tracing for the legacy cuts: one trace covering
         # dispatch, the token-acked fetches, recovery annotations, and
         # the coordinator-side final stage's attempt/operator spans
-        trace = OBS.maybe_trace(self.runner.session, query_id=qid,
-                                sql=sql)
-        if trace is not None:
-            OBS.attach(ex, trace)
+        trace, owned = self._begin_trace(qid, sql)
         tasks: List[_TaskState] = []
         key = f"dcn-{qid}"
         check_payloads = ex._plan_check_on()
@@ -937,7 +956,4 @@ class DcnRunner:
             # expiry) — shared with the stage-DAG scheduler's cleanup
             for st in tasks:
                 self._release_task(st.uri, st.task_id)
-            if trace is not None:
-                OBS.finalize(ex, trace,
-                             self.runner.session.get("query_trace_dir"))
-            self.runner.last_trace = trace
+            self._end_trace(trace, owned)

@@ -45,6 +45,20 @@ QUERY_COUNTERS: Dict[str, tuple] = {
     "program_launches": (
         "gauge", "fused-scan program launches this attempt "
         "(split-batched execution)"),
+    "device_launches": (
+        "gauge", "calls of a program made by Executor._jit this "
+        "attempt, counted at the one launch point "
+        "(exec/programs.launch) on the calling executor; "
+        "program_launches is the fused-scan part of it"),
+    "dispatch_wall_us": (
+        "gauge", "host microseconds inside those calls this attempt: "
+        "trace-cache lookup, argument handling, enqueue (and a "
+        "program's first call's tracing, lowering and compile or "
+        "cache load)"),
+    "device_wait_us": (
+        "gauge", "host microseconds blocked on the device this "
+        "attempt: every exec/xfer.py pull (to_host, np_host), "
+        "devsync.drain and the overflow-flag read"),
     "splits_scanned": (
         "gauge", "real (unpadded) splits covered by this attempt's "
         "fused-scan launches — splits_per_launch is the ratio"),

@@ -37,6 +37,7 @@ from presto_tpu.exec import faults as FAULTS
 from presto_tpu.exec import latemat as LM
 from presto_tpu.exec import membudget as MB
 from presto_tpu.exec import plan as P
+from presto_tpu.exec import programs as PG
 from presto_tpu.exec import prune as PR
 from presto_tpu.exec import shapes as SH
 from presto_tpu.exec import xfer as XF
@@ -418,6 +419,16 @@ class Executor:
         self.program_launches = 0
         self.splits_scanned = 0
         self.split_batch_fallbacks = 0
+        # every program made by _jit is called through exec/programs.
+        # launch, which counts on the CALLING executor, this attempt:
+        # device_launches = calls, dispatch_wall_us = host time inside
+        # them, device_wait_us = host time blocked on the device
+        # (exec/xfer.py pulls, devsync.drain, the overflow-flag read);
+        # _launches_by_label feeds the attempt span while tracing
+        self.device_launches = 0
+        self.dispatch_wall_us = 0
+        self.device_wait_us = 0
+        self._launches_by_label: Dict[str, int] = {}
         # blocking-aggregation sizing heuristics (session properties
         # agg_optimistic_rows / agg_compact_enabled): start group
         # capacities tight and densify join-sparse inputs, both guarded
@@ -533,6 +544,10 @@ class Executor:
         # attempt boundaries ONLY, never inside traced code, so jit
         # keys and compiled programs carry no trace state.
         self.trace = None
+        # trace_parent: the span an execute() nests under (the runner
+        # sets its open ``plan`` span while plan-time scalar subqueries
+        # run); None = the run is the statement's own ``execute`` phase
+        self.trace_parent = None
         # trace_spans: spans this executor recorded into the active
         # trace (per query; the tracing-off test pins it at 0, and
         # obs.finalize settles it to the trace's full span count)
@@ -958,39 +973,59 @@ class Executor:
             )
         return True
 
-    def _jit(self, key, fn, static_argnums=(), donate_argnums=()):
-        """One jit wrapper per CANONICAL program key. Keys name exactly
-        the inputs that shape the traced program (the kernel's bound
-        args, static sizes, dictionary signatures) and deliberately
-        exclude plan-node identity/estimates — two plans that differ
-        only in a capacity estimate share one wrapper, and the bucketed
-        static sizes (exec/shapes.py) make their programs identical.
+    def _jit(self, key, fn=None, static_argnums=(), donate_argnums=(),
+             make=None):
+        """One program per CANONICAL key, jitted under the label the
+        key begins with (exec/programs.py) and called through THE
+        launch point, which counts and annotates the call on this
+        executor. Keys name exactly the inputs that shape the traced
+        program (the kernel's bound args, static sizes, dictionary
+        signatures) and deliberately exclude plan-node
+        identity/estimates — two plans that differ only in a capacity
+        estimate share one program, and the bucketed static sizes
+        (exec/shapes.py) make their programs identical. ``make``
+        builds the function on a cache miss where building it is work
+        (the fused-scan sites).
 
         ``donate_argnums`` marks args whose buffer the CALLER provably
         never touches again (fold/topn merge accumulators); when
         donation resolves on (_donate_on) the program reuses that HBM
         in place and the invocation counts on buffers_donated. The
-        donated wrapper caches under a salted key so flipping the
+        donated program caches under a salted key so flipping the
         session property mid-executor can never hand a donating
         program to a non-donating call site."""
         if not self.use_jit:
-            return fn
-        if donate_argnums and self._donate_on():
-            dkey = (key, "donate")
-            if dkey not in self._jit_cache:
+            return fn if fn is not None else make()
+        donate = bool(donate_argnums) and self._donate_on()
+        if donate:
+            key = (key, "donate")
+        prog = self._jit_cache.get(key)
+        if prog is None:
+            kw = {"static_argnums": static_argnums}
+            if donate:
                 _filter_donation_warning()
-                jitted = jax.jit(fn, static_argnums=static_argnums,
-                                 donate_argnums=donate_argnums)
+                kw["donate_argnums"] = donate_argnums
+            prog = self._jit_cache[key] = PG.Program(
+                PG.label_of(key[0] if donate else key),
+                fn if fn is not None else make(), donates=donate, **kw)
+        return functools.partial(PG.launch, self, prog)
 
-                def counted(*a, _j=jitted, **kw):
-                    self.buffers_donated += 1
-                    return _j(*a, **kw)
+    def count_launch(self, prog, wall_ns: int) -> None:
+        """THE sink exec/programs.launch counts a program call on."""
+        self.device_launches += 1
+        self.dispatch_wall_us += (wall_ns + 500) // 1000
+        if prog.fused_scan:
+            self.program_launches += 1
+        if prog.donates:
+            self.buffers_donated += 1
+        if self.trace is not None:
+            by = self._launches_by_label
+            by[prog.label] = by.get(prog.label, 0) + 1
 
-                self._jit_cache[dkey] = counted
-            return self._jit_cache[dkey]
-        if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(fn, static_argnums=static_argnums)
-        return self._jit_cache[key]
+    def count_device_wait(self, wall_s: float) -> None:
+        """THE sink exec/xfer.py counts host time blocked on the
+        device on (its pulls, devsync.drain, the overflow-flag read)."""
+        self.device_wait_us += int(round(wall_s * 1e6))
 
     # ------------------------------------------- device-memory governor
     # floor for OOM-tightened budgets: the governor's sizing math stays
@@ -1495,9 +1530,8 @@ class Executor:
                 B = len(entries)
                 jkey = ("xq_batch", node, key_extra, cur.table,
                         n_pad, B)
-                if jkey not in self._jit_cache:
-                    self._jit_cache[jkey] = jax.jit(
-                        make_xq_fn(n_pad, B))
+                run_xq = self._jit(
+                    jkey, make=lambda: make_xq_fn(n_pad, B))
                 starts = np.zeros(B, np.int64)
                 counts = np.zeros(B, np.int64)
                 for j, (s0, c0) in enumerate(entries):
@@ -1507,7 +1541,7 @@ class Executor:
                     # metered h2d: 2xB int64 slot descriptors per
                     # shared launch (exec/xfer.py choke point),
                     # attributed to the leader
-                    out = self._jit_cache[jkey](
+                    out = run_xq(
                         XF.to_device(starts, label="batch-starts"),
                         XF.to_device(counts, label="batch-starts"))
                 except Exception:
@@ -1527,9 +1561,9 @@ class Executor:
             page, flags, width, waited_ms, leader = res
             if leader:
                 # ONE launch covers every ganged query — only the
-                # leader pays it, so aggregate program_launches
-                # measures real dispatches
-                self.program_launches += 1
+                # leader pays it (make_batched ran on this executor
+                # and the launch point counted it), so aggregate
+                # program_launches measures real dispatches
                 self.cross_query_batches += 1
             self.cross_query_batched_queries += 1
             self.queries_per_launch = max(
@@ -1557,12 +1591,11 @@ class Executor:
                     ("xq", node, key_extra, cur.table, n_pad))
             n_pad = SH.bucket(split.row_count)
             key = ("fused", node, key_extra, cur.table, n_pad)
-            if key not in self._jit_cache:
-                gen_fn = conn.gen_body(cur.table, n_pad, names)
-                self._jit_cache[key] = jax.jit(
-                    functools.partial(run_split, gen_fn, n_pad))
+            run_fused = self._jit(key, make=lambda: functools.partial(
+                run_split, conn.gen_body(cur.table, n_pad, names),
+                n_pad))
             with solo_mark:
-                page, flags = self._jit_cache[key](
+                page, flags = run_fused(
                     jnp.int64(split.start_row),
                     jnp.int64(split.row_count),
                 )
@@ -1572,7 +1605,6 @@ class Executor:
             self.peak_memory_bytes = max(
                 self.peak_memory_bytes, n_pad * scan_row_b
             )
-            self.program_launches += 1
             self.splits_scanned += 1
             self._pending_overflow.extend(flags)
             return page
@@ -1695,8 +1727,7 @@ class Executor:
                 B = SH.split_batch_bucket(len(chunk))
                 key = ("fused_batch", node, key_extra, cur.table,
                        n_pad_all, B)
-                if key not in self._jit_cache:
-                    self._jit_cache[key] = jax.jit(build_batch_fn())
+                run_batch = self._jit(key, make=build_batch_fn)
                 starts = np.zeros(B, np.int64)
                 counts = np.zeros(B, np.int64)
                 for j, s in enumerate(chunk):
@@ -1705,7 +1736,7 @@ class Executor:
                 try:
                     # metered h2d: 2xB int64 split descriptors per
                     # batched launch (exec/xfer.py choke point)
-                    page, flags = self._jit_cache[key](
+                    page, flags = run_batch(
                         XF.to_device(starts, label="batch-starts"),
                         XF.to_device(counts, label="batch-starts"))
                 except Exception:
@@ -1719,7 +1750,6 @@ class Executor:
                     self.split_batch_fallbacks += 1
                     yield from stream_single()
                     return
-                self.program_launches += 1
                 self.splits_scanned += len(chunk)
                 self._pending_overflow.extend(flags)
                 # vmapped batches materialize the [B, n_pad] stack;
@@ -2020,7 +2050,7 @@ class Executor:
                 return
             merged = concat_all(pages)
             self._account_page(merged)
-            key = ("sort", node.keys, None, merged.capacity)
+            key = ("sort_page", node.keys, None, merged.capacity)
             fn = self._jit(
                 key, functools.partial(sort_page, sort_keys=node.keys)
             )
@@ -2122,7 +2152,14 @@ class Executor:
             own_stats = True
         exec_span = None
         if tr is not None:
-            exec_span = tr.begin("execute", type(node).__name__)
+            # the statement's own run is a phase (it opens where the
+            # plan phase ends); a plan-time scalar subquery's run
+            # nests under the open plan span
+            exec_span = (
+                tr.phase("execute", type(node).__name__)
+                if self.trace_parent is None else
+                tr.begin("execute", type(node).__name__,
+                         parent=self.trace_parent))
             self.trace_spans += 1
         _prev_sink = XF.swap_sink(self)
         try:
@@ -2177,7 +2214,8 @@ class Executor:
                     continue
                 if tr is not None:
                     self._trace_operators(tr, att_span)
-                    tr.end(att_span, outcome="ok", rows=len(rows))
+                    tr.end(att_span, outcome="ok", rows=len(rows),
+                           launches=dict(self._launches_by_label))
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
                 self._publish_cache_pending()
@@ -2217,6 +2255,10 @@ class Executor:
         self.gathers_materialized = 0
         self.fused_partial_aggs = 0
         self.program_launches = 0
+        self.device_launches = 0
+        self.dispatch_wall_us = 0
+        self.device_wait_us = 0
+        self._launches_by_label = {}
         self.splits_scanned = 0
         self.queries_per_launch = 0
         self.memory_chunked_pipelines = 0
@@ -2411,7 +2453,8 @@ class Executor:
         flag = self._pending_overflow[0]
         for f in self._pending_overflow[1:]:
             flag = flag | f
-        return bool(flag)
+        with XF.device_wait("overflow-flag"):
+            return bool(flag)
 
     def stream_fragment(self, node: P.PhysicalNode, emit,
                         cancelled=lambda: False,

@@ -27,7 +27,12 @@ Two sides, one discipline (the QUERY_COUNTERS / LOCK_REGISTRY model):
            d2h_transfers + the computed transfer_wall_s), and emit an
            `xfer` span (obs.SPAN_KINDS) when that executor is traced,
            so Chrome traces and critical_path() show copy time as its
-           own phase.
+           own phase. A d2h pull is also host time BLOCKED ON THE
+           DEVICE (the value is ready when the programs that make it
+           have run): it counts on `device_wait_us` with the two waits
+           that cross no page, `devsync.drain` and the executor's
+           overflow-flag read (`device_wait`), and every such wait is
+           a `wait:<site>` annotation on the profiler's host plane.
 
 Sink binding is per-thread (execute()/stream_fragment() install the
 running executor via swap_sink), so concurrent per-query executors on
@@ -52,6 +57,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
+
+from presto_tpu.obs.trace import annotation
 
 # ---------------------------------------------------------------------
 # site -> (direction, plane, justification)
@@ -378,6 +385,37 @@ def _host_nbytes(tree) -> int:
     return n
 
 
+def _wait_note(site: str):
+    """The ``wait:<site>`` annotation, begun now."""
+    return annotation("wait:" + site)
+
+
+def _count_wait(wall: float) -> None:
+    sink = getattr(_tls, "sink", None)
+    if sink is not None:
+        sink.count_device_wait(wall)
+
+
+class device_wait:
+    """``with device_wait(site):`` around a host read that blocks on the
+    device and crosses no page (devsync.drain, the overflow-flag read):
+    counted on ``device_wait_us`` and annotated like the pulls below."""
+
+    __slots__ = ("note", "t0")
+
+    def __init__(self, site: str):
+        self.note = _wait_note(site)
+        self.t0 = time.perf_counter()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        wall = time.perf_counter() - self.t0
+        self.note.__exit__(None, None, None)
+        _count_wait(wall)
+
+
 def _meter(direction: str, nbytes: int, wall: float, label: str) -> None:
     if direction == "h2d":
         _totals.h2d_transfers += 1
@@ -385,6 +423,7 @@ def _meter(direction: str, nbytes: int, wall: float, label: str) -> None:
     else:
         _totals.d2h_transfers += 1
         _totals.d2h_bytes += nbytes
+        _count_wait(wall)
     _totals.transfer_wall_s += wall
     sink = getattr(_tls, "sink", None)
     if sink is None:
@@ -407,7 +446,8 @@ def to_host(tree, label: str = "page"):
     if nbytes == 0:
         return tree
     t0 = time.perf_counter()
-    host = jax.device_get(tree)
+    with _wait_note(label):
+        host = jax.device_get(tree)
     _meter("d2h", nbytes, time.perf_counter() - t0, label)
     return host
 
@@ -433,7 +473,8 @@ def np_host(arr, label: str = "array"):
     plain np.asarray view: zero copies, zero meters."""
     if isinstance(arr, jax.Array):
         t0 = time.perf_counter()
-        out = np.asarray(arr)
+        with _wait_note(label):
+            out = np.asarray(arr)
         _meter("d2h", out.nbytes, time.perf_counter() - t0, label)
         return out
     return np.asarray(arr)

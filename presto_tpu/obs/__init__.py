@@ -46,16 +46,31 @@ SPAN_KINDS: Dict[str, str] = {
     "query": "the whole query: wall anchor + every child span",
     "execute": "one local executor run of a plan (the overflow-ladder "
                "driver; the coordinator's root fragment and every "
-               "LocalRunner query get one)",
-    "attempt": "one overflow-ladder attempt (attrs: capacity boost; "
-               "a query with N-1 boosted retries has N of these)",
+               "LocalRunner query get one); a statement's own run is "
+               "a phase, on the profiler's host plane "
+               "execute:<query id>",
+    "attempt": "one overflow-ladder attempt (attrs: capacity boost, "
+               "launches: device program launches by label; a query "
+               "with N-1 boosted retries has N of these)",
     "operator": "per-plan-node wall/rows/pages from the EXPLAIN "
                 "ANALYZE accounting, anchored at its attempt's start",
     "stage": "one stage-DAG wave dispatched by dist/scheduler.py",
     "task": "one logical task of a stage (coordinator view; attrs: "
             "uri, retries, pages; worker-side spans nest inside)",
     "dispatch": "one task-submit POST to a worker",
-    "queue": "worker-side: task created -> fragment execution started",
+    "queue": "coordinator-side phase: statement submitted -> admitted "
+             "(attrs: gate, the one of resource group, footprint "
+             "arbiter and execution lock that held it longest, and "
+             "each gate's wait in microseconds); worker-side: task "
+             "created -> fragment execution started",
+    "parse": "phase: admitted -> statement parsed, session applied "
+             "and access checked (the runner's front end before "
+             "planning)",
+    "plan": "phase: analyze + plan + optimize + fragment, up to the "
+            "instant the executor takes the plan (plan-time scalar "
+            "subqueries' execute spans nest inside)",
+    "encode": "coordinator-side phase: executor done -> rows encoded "
+              "as JSON protocol values; the root ends with it",
     "run": "worker-side: fragment execution (attrs: pages, spooled)",
     "fetch": "coordinator-side page drain of one task's results",
     "retry": "one task re-dispatch (attrs: from/to uri, cause) — the "
@@ -90,10 +105,14 @@ SPAN_KINDS: Dict[str, str] = {
 
 
 def maybe_trace(session, query_id: Optional[str] = None,
-                sql: Optional[str] = None) -> Optional[QueryTrace]:
+                sql: Optional[str] = None,
+                anchor_mono: Optional[float] = None,
+                anchor_wall: Optional[float] = None
+                ) -> Optional[QueryTrace]:
     """A QueryTrace when the session enables tracing, else None (the
     near-zero-cost off switch: every recording site guards on the
-    executor's `trace is None`)."""
+    executor's `trace is None`). The coordinator anchors the trace at
+    the statement's submission."""
     if not (bool(session.get("query_trace_enabled"))
             or session.get("query_trace_dir")):
         return None
@@ -101,28 +120,44 @@ def maybe_trace(session, query_id: Optional[str] = None,
         import uuid
 
         query_id = f"q-{uuid.uuid4().hex[:12]}"
-    return QueryTrace(query_id, sql=sql)
+    return QueryTrace(query_id, sql=sql, anchor_mono=anchor_mono,
+                      anchor_wall=anchor_wall)
 
 
 def attach(executor, trace: QueryTrace) -> None:
     """Hand a trace to an executor for the next query; resets the
     per-query `trace_spans` counter the tracing-off test pins."""
     executor.trace = trace
+    executor.trace_parent = None
     executor.trace_spans = 0
+
+
+def detach(executor, trace: QueryTrace) -> None:
+    """Take the trace off the executor and settle the span-count
+    counter; the trace's owner ends and writes it (``close``)."""
+    executor.trace = None
+    executor.trace_parent = None
+    executor.trace_spans = trace.span_count
 
 
 def finalize(executor, trace: QueryTrace,
              trace_dir: Optional[str] = None) -> None:
-    """End the root span, write the Chrome-trace file when a directory
-    is configured (session prop `query_trace_dir` / etc key
-    `query-trace.dir`), detach, and settle the span-count counter.
-    The file write degrades gracefully (same discipline as
-    profile.ProfileStore.record): finalize runs inside callers'
-    finally blocks, so an unwritable trace dir must neither fail a
-    successful query nor mask an in-flight error."""
-    trace.finish()
-    executor.trace = None
-    executor.trace_spans = trace.span_count
+    """What the owner of a trace attached to its own executor does at
+    the end: detach, end the root span, write the file."""
+    detach(executor, trace)
+    close(trace, trace_dir)
+
+
+def close(trace: QueryTrace, trace_dir: Optional[str] = None,
+          at_mono: Optional[float] = None) -> None:
+    """End the root span (at the owner's finish clock where it has
+    one) and write the Chrome-trace file when a directory is
+    configured (session prop `query_trace_dir` / etc key
+    `query-trace.dir`). The file write degrades gracefully (same
+    discipline as profile.ProfileStore.record): this runs inside
+    callers' finally blocks, so an unwritable trace dir must neither
+    fail a successful query nor mask an in-flight error."""
+    trace.finish(at_mono)
     if trace_dir:
         try:
             os.makedirs(trace_dir, exist_ok=True)

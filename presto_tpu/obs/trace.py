@@ -19,16 +19,47 @@ parent links, one lock. All structure (QueryInfo tree, Chrome trace,
 critical path) is derived at read time — recording at page/stage
 boundaries stays O(1) and allocation-light, and NOTHING here is
 reachable from jit keys or traced functions (tools/lint purity rule).
+
+Phases: the top-level spans ``queue``, ``parse``, ``plan``, ``execute``
+and ``encode`` tile a served statement from submission to its last
+encoded row. ``phase()`` opens one where the last one ended (one clock
+reading ends the old and begins the new), so the tiling holds by
+construction however the threads are scheduled between them.
+
+Every span opened with ``begin()``/``phase()`` is also a
+``jax.profiler.TraceAnnotation`` (``annotation`` below), so a profiler
+session's ``/host:CPU`` plane holds the program's own account on the
+device trace's clock. With no session an annotation is one inactive
+TraceMe; a span ended on another thread than it began on leaves its
+annotation unended, which the profiler drops.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import json
+import threading
 import time
 from typing import Dict, List, Optional
 
 from presto_tpu.obs.sanitizer import make_lock, register_owner
+
+
+_TraceAnnotation = None
+
+# the phases of a served statement, in order (obs.SPAN_KINDS has each)
+PHASE_KINDS = ("queue", "parse", "plan", "execute", "encode")
+
+
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` begun now (a context manager:
+    its exit ends it). Callers build ``name`` once per site or program,
+    not per call. jax is imported on first use: the recorder itself
+    stays importable without it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 @dataclasses.dataclass
@@ -43,6 +74,9 @@ class Span:
     t0: float
     t1: Optional[float] = None
     attrs: Dict[str, object] = dataclasses.field(default_factory=dict)
+    # (annotation, ident of the thread that began it) while open
+    note: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False)
 
     def dur(self, now: float = 0.0) -> float:
         end = self.t1 if self.t1 is not None else now
@@ -55,7 +89,7 @@ class QueryTrace:
 
     # lock discipline (tools/lint `locks` rule): the span list and its
     # sequence counter are the shared recording surface
-    _shared_attrs = ("_spans", "_seq")
+    _shared_attrs = ("_spans", "_seq", "_phase", "_phase_end")
 
     def __init__(self, query_id: str, sql: Optional[str] = None,
                  anchor_mono: Optional[float] = None,
@@ -70,6 +104,15 @@ class QueryTrace:
         self._lock = make_lock("obs.trace.QueryTrace._lock")
         self._spans: List[Span] = []
         self._seq = 0
+        # the open phase span, and where the last phase ended
+        self._phase: Optional[Span] = None
+        self._phase_end: Optional[float] = None
+        # origin of the QueryInfo tree's startMs/endMs (seconds from
+        # the anchor): the instant the runner has parsed the statement
+        # and begins to plan it, which is where a runner-made trace
+        # used to be created — a coordinator-owned trace is anchored
+        # earlier, at submission, and the tree keeps its origin
+        self.stage_origin = 0.0
         attrs = {"sql": sql} if sql else {}
         self.root = self._new("query", query_id, None, 0.0, None, attrs)
         register_owner(self)
@@ -86,17 +129,59 @@ class QueryTrace:
             self._spans.append(sp)
             return sp
 
+    def _annotate(self, span: Span) -> Span:
+        if span.kind in PHASE_KINDS:
+            name = (f"execute:{self.query_id}" if span.kind == "execute"
+                    else span.kind)
+        else:
+            name = f"{span.kind}:{span.name}"
+        span.note = (annotation(name), threading.get_ident())
+        return span
+
+    @staticmethod
+    def _end_note(span: Span) -> None:
+        note, span.note = span.note, None
+        if note is not None and note[1] == threading.get_ident():
+            note[0].__exit__(None, None, None)
+
     def begin(self, kind: str, name: str,
               parent: Optional[Span] = None, **attrs) -> Span:
         pid = (parent or self.root).span_id
-        return self._new(kind, name, pid, self.now(), None, attrs)
+        return self._annotate(
+            self._new(kind, name, pid, self.now(), None, attrs))
 
     def end(self, span: Span, **attrs) -> Span:
+        self._end_note(span)
         with self._lock:
             if span.t1 is None:
                 span.t1 = time.monotonic() - self._anchor_mono
+                if span is self._phase:
+                    self._phase, self._phase_end = None, span.t1
             span.attrs.update(attrs)
         return span
+
+    def phase(self, kind: str, name: str = "",
+              at: Optional[float] = None, **attrs) -> Span:
+        """Open the next top-level phase where the last one ended: an
+        open phase ends at the instant this one begins, a closed one
+        hands over its end (what ran between them is this phase's).
+        The first phase begins now, or ``at`` seconds from the anchor
+        (the coordinator's ``queue`` begins at submission, 0.0)."""
+        with self._lock:
+            prev = self._phase
+            if prev is not None:
+                self._end_note(prev)
+                t = prev.t1 = time.monotonic() - self._anchor_mono
+            else:
+                t = self._phase_end if at is None else at
+                if t is None:  # the first phase, not backdated
+                    t = time.monotonic() - self._anchor_mono
+            self._seq += 1
+            sp = Span(self._seq, self.root.span_id, kind, name, t, None,
+                      dict(attrs))
+            self._spans.append(sp)
+            self._phase = sp
+        return self._annotate(sp)
 
     def complete(self, kind: str, name: str, t0: float, t1: float,
                  parent: Optional[Span] = None, **attrs) -> Span:
@@ -122,14 +207,23 @@ class QueryTrace:
                 continue  # a malformed remote span is dropped, not fatal
         return n
 
-    def finish(self) -> None:
-        self.end(self.root)
-        # close any straggler open spans at the root's end (a failed
-        # query abandons its in-flight task spans)
+    def finish(self, at_mono: Optional[float] = None) -> None:
+        """End the root (at ``at_mono``, a time.monotonic() reading,
+        where the owner has its own finish clock) and with it the open
+        phase and any straggler."""
         with self._lock:
-            for sp in self._spans:
-                if sp.t1 is None:
-                    sp.t1 = self.root.t1
+            if self.root.t1 is None:
+                self.root.t1 = max(
+                    (time.monotonic() if at_mono is None else at_mono)
+                    - self._anchor_mono, 0.0)
+            # close any straggler open spans at the root's end (a failed
+            # query abandons its in-flight task spans)
+            stragglers = [sp for sp in self._spans if sp.t1 is None]
+            for sp in stragglers:
+                sp.t1 = max(self.root.t1, sp.t0)
+            self._phase = None
+        for sp in stragglers:
+            self._end_note(sp)
 
     # ----------------------------------------------------------- reads
     @property
@@ -140,6 +234,24 @@ class QueryTrace:
     def spans(self) -> List[Span]:
         with self._lock:
             return list(self._spans)
+
+    def has(self, kind: str) -> bool:
+        with self._lock:
+            return any(sp.kind == kind for sp in self._spans)
+
+    def phases(self) -> List[dict]:
+        """The top-level spans in order, microseconds from the anchor
+        (/v1/query/{id}'s ``phases``): they tile the root."""
+        now = self.now()
+        root = self.root.span_id
+        return [{
+            "kind": sp.kind,
+            "startUs": int(round(sp.t0 * 1e6)),
+            "endUs": int(round(
+                (sp.t1 if sp.t1 is not None else now) * 1e6)),
+            "attrs": sp.attrs,
+        } for sp in sorted(self.spans(), key=lambda s: (s.t0, s.span_id))
+            if sp.parent_id == root and sp.kind in PHASE_KINDS]
 
     def export(self) -> List[dict]:
         """Wire form for shipping to a coordinator (worker status
@@ -170,8 +282,13 @@ class QueryTrace:
             if sp.parent_id is not None:
                 children.setdefault(sp.parent_id, []).append(sp)
 
+        origin = self.stage_origin
+
         def ms(t: float) -> int:
             return int(round(t * 1000))
+
+        def at(t: float) -> int:
+            return ms(t - origin)
 
         def descend(sp: Span) -> List[dict]:
             out = []
@@ -179,8 +296,8 @@ class QueryTrace:
                             key=lambda s: (s.t0, s.span_id)):
                 out.append({
                     "kind": c.kind, "name": c.name,
-                    "startMs": ms(c.t0),
-                    "endMs": ms(c.t1 if c.t1 is not None else now),
+                    "startMs": at(c.t0),
+                    "endMs": at(c.t1 if c.t1 is not None else now),
                     "attrs": c.attrs,
                 })
                 out.extend(descend(c))
@@ -192,8 +309,8 @@ class QueryTrace:
                 "uri": sp.attrs.get("uri"),
                 "state": ("RUNNING" if sp.t1 is None else
                           str(sp.attrs.get("state", "FINISHED"))),
-                "startMs": ms(sp.t0),
-                "endMs": ms(sp.t1 if sp.t1 is not None else now),
+                "startMs": at(sp.t0),
+                "endMs": at(sp.t1 if sp.t1 is not None else now),
                 "wallMs": ms(sp.dur(now)),
                 "rows": sp.attrs.get("rows"),
                 "pages": sp.attrs.get("pages"),
@@ -210,8 +327,8 @@ class QueryTrace:
             stages.append({
                 "stageId": sp.name,
                 "state": "RUNNING" if sp.t1 is None else "FINISHED",
-                "startMs": ms(sp.t0),
-                "endMs": ms(sp.t1 if sp.t1 is not None else now),
+                "startMs": at(sp.t0),
+                "endMs": at(sp.t1 if sp.t1 is not None else now),
                 "wallMs": ms(sp.dur(now)),
                 "tasks": tasks,
             })
@@ -226,16 +343,18 @@ class QueryTrace:
                     "state": ("RUNNING" if any(s.t1 is None
                                                for s in execs)
                               else "FINISHED"),
-                    "startMs": ms(min(s.t0 for s in execs)),
-                    "endMs": ms(max(s.t1 if s.t1 is not None else now
+                    "startMs": at(min(s.t0 for s in execs)),
+                    "endMs": at(max(s.t1 if s.t1 is not None else now
                                     for s in execs)),
                     "wallMs": ms(max(s.dur(now) for s in execs)),
                     "tasks": tasks,
                 }]
         return {
             "queryId": self.query_id,
-            "createTime": self.anchor_wall,
-            "elapsedMs": ms(self.root.dur(now)),
+            # the tree's own clock: the wall instant its startMs/endMs
+            # count from, and the time from there to the root's end
+            "createTime": self.anchor_wall + origin,
+            "elapsedMs": ms(self.root.dur(now) - origin),
             "spanCount": len(spans),
             "stages": stages,
         }

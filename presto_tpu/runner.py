@@ -465,44 +465,62 @@ class LocalRunner:
             # loudly on the normal execute path instead
             return False
 
-    def execute(self, sql: str) -> QueryResult:
-        stmt = parse(sql)
-        # session properties gate the accelerator path per query
-        # (reference: SystemSessionProperties; north-star's
-        # tpu_offload_enabled -> compiled XLA vs eager fallback)
-        self.apply_session()
-        self.access_control.check_can_execute_query(
-            self.session.user, sql
-        )
+    def execute(self, sql: str, trace=None) -> QueryResult:
+        """Run one statement. ``trace`` is the coordinator's
+        obs.QueryTrace of the statement (anchored at submission, its
+        ``queue`` phase recorded and ``parse`` open): the runner
+        records into it and its owner ends and writes it. A runner
+        used directly makes its own when the session enables
+        tracing, and keeps it on ``last_trace``."""
         # query-lifecycle tracing (ISSUE 9, presto_tpu/obs/): one
-        # trace per query when enabled — the executor records attempt/
-        # operator spans into it, /v1/query serves it live, and
-        # query_trace_dir exports a Chrome-trace file at the end.
-        # last_trace keeps the finished trace reachable for tools and
-        # the HTTP server's QueryInfo snapshot.
+        # trace per query when enabled — parse and plan phases here,
+        # the executor's execute/attempt/operator spans below them,
+        # /v1/query serves it live, and query_trace_dir exports a
+        # Chrome-trace file at the end.
         from presto_tpu import obs as OBS
 
-        trace = OBS.maybe_trace(self.session, sql=sql)
+        owned = trace is None
+        if owned:
+            trace = OBS.maybe_trace(self.session, sql=sql)
+            if trace is not None:
+                trace.phase("parse")
+        try:
+            stmt = parse(sql)
+            # session properties gate the accelerator path per query
+            # (reference: SystemSessionProperties; north-star's
+            # tpu_offload_enabled -> compiled XLA vs eager fallback)
+            self.apply_session()
+            self.access_control.check_can_execute_query(
+                self.session.user, sql
+            )
+        except BaseException:
+            if owned and trace is not None:
+                trace.finish()
+            raise
         if trace is not None:
+            # where the QueryInfo tree's milliseconds count from
+            trace.stage_origin = trace.now()
             OBS.attach(self.executor, trace)
         token = _ACTIVE_SESSION.set(self.session)
         try:
             return self._execute_stmt(stmt)
         finally:
             _ACTIVE_SESSION.reset(token)
-            if trace is not None:
-                if trace.span_count > 1:
-                    OBS.finalize(self.executor, trace,
-                                 self.session.get("query_trace_dir"))
-                    self.last_trace = trace
-                else:
-                    # control statements (SET SESSION, PREPARE, ...)
-                    # never reached the executor: discard the empty
-                    # trace — no junk file, and last_trace keeps the
-                    # previous REAL query's timeline
-                    self.executor.trace = None
-            else:
+            if trace is None:
                 self.last_trace = None  # this query was not traced
+            elif not owned:
+                OBS.detach(self.executor, trace)
+            elif trace.has("execute"):
+                OBS.finalize(self.executor, trace,
+                             self.session.get("query_trace_dir"))
+                self.last_trace = trace
+            else:
+                # control statements (SET SESSION, PREPARE, ...)
+                # never reached the executor: discard the trace — no
+                # junk file, and last_trace keeps the previous REAL
+                # query's timeline
+                OBS.detach(self.executor, trace)
+                trace.finish()
 
     def _execute_stmt(self, stmt: N.Node) -> QueryResult:
         if isinstance(stmt, N.CreateView):
@@ -865,16 +883,28 @@ class LocalRunner:
         # fresh scalar-subquery record per plan pass (the statement
         # cache reads it right after planning the outermost statement)
         self._scalar_subplans = []
-        out = self._planner().plan_statement(query)
-        self._check_plan_access(out)
-        out = prune_plan(out, self.catalogs)
-        out = push_scan_constraints(out)
-        if self.mesh is not None:
-            from presto_tpu.dist.fragmenter import add_exchanges
+        # the plan phase of a traced statement: analyze + plan +
+        # optimize + fragment; it stays open until the executor takes
+        # the plan (or the statement ends). Plan-time scalar
+        # subqueries run on the executor meanwhile and nest here.
+        ex = self.executor
+        tr = ex.trace
+        if tr is not None:
+            ex.trace_parent = tr.phase("plan")
+        try:
+            out = self._planner().plan_statement(query)
+            self._check_plan_access(out)
+            out = prune_plan(out, self.catalogs)
+            out = push_scan_constraints(out)
+            if self.mesh is not None:
+                from presto_tpu.dist.fragmenter import add_exchanges
 
-            out, _dist = add_exchanges(
-                out, self.catalogs, **self._session_dist_options()
-            )
+                out, _dist = add_exchanges(
+                    out, self.catalogs, **self._session_dist_options()
+                )
+        finally:
+            if tr is not None:
+                ex.trace_parent = None
         return out
 
     def _check_plan_access(self, plan) -> None:

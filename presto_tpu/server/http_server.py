@@ -61,11 +61,14 @@ class _Query:
         self.finished_mono: Optional[float] = None
         self.cancelled = False
         self.done = threading.Event()
-        # lifecycle trace (obs.QueryTrace), captured from the runner
-        # when the query completes; while RUNNING the live trace is
-        # read off the runner's executor (see QueryManager.query_info)
+        # lifecycle trace (obs.QueryTrace): the query owns it from
+        # submission (anchored at created_mono) to _finish_clock; the
+        # runner and its executor record into it while it runs
         self.trace = None
-        self.runner = None
+        self.queue_span = None
+        # microseconds waited at each admission gate (the queue
+        # span's attrs)
+        self.gate_wait_us: Dict[str, int] = {}
         # tailing cursor (ISSUE 14): non-None turns this query into a
         # never-finishing stream cursor served by _tail_results
         self.tail: Optional["TailCursor"] = None
@@ -78,6 +81,11 @@ class _Query:
         if self.finished_at is None:
             self.finished_at = time.time()
             self.finished_mono = time.monotonic()
+
+    def waited(self, gate: str, since_mono: float) -> None:
+        """Record the time since ``since_mono`` as spent at ``gate``."""
+        self.gate_wait_us[gate] = self.gate_wait_us.get(gate, 0) + int(
+            (time.monotonic() - since_mono) * 1e6)
 
     def info(self) -> Dict:
         end_mono = (self.finished_mono if self.finished_mono
@@ -499,16 +507,21 @@ class QueryManager:
     _EXEC_TOTAL_SUMS = (
         "program_launches", "splits_scanned", "cross_query_batches",
         "cross_query_batched_queries", "batch_gather_wait_ms",
+        "device_launches", "dispatch_wall_us", "device_wait_us",
     )
     _EXEC_TOTAL_MAX = ("queries_per_launch",)
 
     def __init__(self, runner_factory, listeners=(),
                  resource_groups=None, memory_arbiter=None,
                  listener_error_counter=None, journal=None,
-                 counter_executor=None, dcn=None):
+                 counter_executor=None, dcn=None, session_seed=None):
         from presto_tpu.obs.histo import Histogram
 
         self._runner_factory = runner_factory
+        # seeds a submitted statement's session with the deployment's
+        # defaults (tracing among them) before its trace is made; the
+        # runner factory seeds the same way, idempotently
+        self._session_seed = session_seed
         # durable coordinator journal (ISSUE 20): server-configured
         # (checkpoint.dir etc key) or lazily bound from the
         # checkpoint_dir session property at first enabled submit
@@ -581,6 +594,7 @@ class QueryManager:
 
     def submit(self, sql: str, session: Session) -> _Query:
         from presto_tpu import events as E
+        from presto_tpu import obs as OBS
 
         group = None
         if self.resource_groups is not None:
@@ -594,6 +608,11 @@ class QueryManager:
             q = _Query(qid, sql, session)
             q.resource_group = group
             self._queries[qid] = q
+        if self._session_seed is not None:
+            self._session_seed(session)
+        q.trace = OBS.maybe_trace(
+            session, query_id=qid, sql=sql,
+            anchor_mono=q.created_mono, anchor_wall=q.created)
         j = self._journal_for(session)
         if j is not None:
             # admission barrier (ISSUE 20): statement + session +
@@ -651,14 +670,16 @@ class QueryManager:
             return None
         info = q.info()
         tr = q.trace
-        if tr is None and not q.done.is_set():
-            r = q.runner
-            tr = getattr(r.executor, "trace", None) if r is not None \
-                else None
         if tr is not None:
             tree = tr.to_info()
             info["stages"] = tree["stages"]
             info["spanCount"] = tree["spanCount"]
+            # the top-level spans, microseconds from submission: they
+            # tile elapsedTimeMillis (queue, parse, plan, execute,
+            # encode); stages[*] count from the instant the runner
+            # begins to plan, as they always have
+            info["phases"] = tr.phases()
+            info["anchorMonotonicS"] = q.created_mono
         else:
             info["stages"] = []
             info["spanCount"] = 0
@@ -667,6 +688,10 @@ class QueryManager:
     def _run(self, q: _Query) -> None:
         group = getattr(q, "resource_group", None)
         runner = None
+        if q.trace is not None:
+            # begins at submission; this thread, started there, holds
+            # its annotation
+            q.queue_span = q.trace.phase("queue", at=0.0)
         if self.memory is not None and not q.cancelled:
             # cache-aware admission (ISSUE 17): a statement the
             # result cache would serve whole costs near nothing —
@@ -693,9 +718,11 @@ class QueryManager:
                 self.resource_groups.cancel_queued(group)
                 self._record_completion(q)
                 return
-            if not self.resource_groups.acquire(
-                group, should_abort=lambda: q.cancelled
-            ):
+            t0 = time.monotonic()
+            admitted = self.resource_groups.acquire(
+                group, should_abort=lambda: q.cancelled)
+            q.waited("resource_group", t0)
+            if not admitted:
                 # canceled while queued: acquire released the queue slot
                 self._record_completion(q)
                 return
@@ -726,7 +753,9 @@ class QueryManager:
     # method ACQUIRES the execution lock/arbiter itself
     def _run_admitted(self, q: _Query, runner=None) -> None:
         if self.memory is None:
+            t0 = time.monotonic()
             with self._exec_lock:
+                q.waited("execution_lock", t0)
                 self._execute(q)
             return
         # concurrent path: admission by estimated footprint replaces
@@ -756,15 +785,19 @@ class QueryManager:
         if group is not None and self.resource_groups is not None:
             # per-group memory quotas gate before the global arbiter
             # (reference: soft_memory_limit per resource group)
-            if not self.resource_groups.reserve_memory(
-                group, est, should_abort=lambda: q.cancelled
-            ):
+            t0 = time.monotonic()
+            reserved = self.resource_groups.reserve_memory(
+                group, est, should_abort=lambda: q.cancelled)
+            q.waited("resource_group", t0)
+            if not reserved:
                 self._record_completion(q)
                 return
         try:
-            if not self.memory.acquire(
-                est, should_abort=lambda: q.cancelled
-            ):
+            t0 = time.monotonic()
+            admitted = self.memory.acquire(
+                est, should_abort=lambda: q.cancelled)
+            q.waited("footprint_arbiter", t0)
+            if not admitted:
                 self._record_completion(q)
                 return
             try:
@@ -777,6 +810,15 @@ class QueryManager:
 
     def _execute(self, q: _Query, runner=None) -> None:
             self._queue_exit(q)
+            tr = q.trace
+            if tr is not None:
+                # admitted: the queue phase ends here with the gates'
+                # waits, and parse opens at the same instant
+                waits = q.gate_wait_us
+                tr.end(q.queue_span,
+                       gate=max(waits, key=waits.get) if waits else None,
+                       **{f"{g}_us": us for g, us in waits.items()})
+                tr.phase("parse")
             ckpt = q.checkpoint
             if q.cancelled:
                 # canceled while queued: still record completion so event
@@ -793,13 +835,12 @@ class QueryManager:
                 # serial path only, so one query owns it at a time
                 if self._dcn is not None:
                     self._dcn.checkpoint_handle = ckpt
-            prev_trace = None
             try:
                 if runner is None:
                     runner = self._runner_factory(q.session)
-                q.runner = runner  # live-trace handle for query_info
-                prev_trace = getattr(runner, "last_trace", None)
-                result = runner.execute(q.sql)
+                result = runner.execute(q.sql, trace=tr)
+                if tr is not None:
+                    tr.phase("encode")
                 types = result.column_types or [
                     "unknown" for _ in result.column_names
                 ]
@@ -842,13 +883,6 @@ class QueryManager:
                 if ckpt is not None and self._dcn is not None:
                     self._dcn.checkpoint_handle = None
                 q._finish_clock()
-                if runner is not None:
-                    # snapshot the finished trace before the serial
-                    # runner moves on to its next query; a control
-                    # statement keeps the runner's previous trace —
-                    # only a NEW trace belongs to this query
-                    lt = getattr(runner, "last_trace", None)
-                    q.trace = lt if lt is not prev_trace else None
                 q.done.set()
                 self._record_completion(q)
                 self._accumulate_exec_totals(runner)
@@ -874,8 +908,14 @@ class QueryManager:
 
     def _record_completion(self, q: _Query) -> None:
         from presto_tpu import events as E
+        from presto_tpu import obs as OBS
 
         self._queue_exit(q)
+        if q.trace is not None:
+            # the root ends at the query's own finish clock, so the
+            # phases tile elapsedTimeMillis
+            OBS.close(q.trace, q.session.get("query_trace_dir"),
+                      at_mono=q.finished_mono)
         wall_ms = q.info()["elapsedTimeMillis"]
         with self._lock:
             self.completed_by_state[q.state] = (
@@ -998,6 +1038,25 @@ class QueryManager:
                 f"presto_tpu_transfer_wall_seconds "
                 f"{xf['transfer_wall_s']}",
             ]
+        # program load, split (compilecache.py): process totals —
+        # what every program's first call in this process cost, by
+        # part; the registry's programs_compiled/program_cache_hits
+        # above are the last statement's
+        from presto_tpu import compilecache
+
+        cc = compilecache.snapshot()
+        for name in ("program_trace_wall_s", "program_lower_wall_s",
+                     "program_retrieval_wall_s", "compile_wall_s"):
+            lines += [f"# TYPE presto_tpu_{name} gauge",
+                      f"presto_tpu_{name} {cc[name]:.6f}"]
+        for name, key in (
+            ("programs_traced", "programs_traced"),
+            ("programs_lowered", "programs_lowered"),
+            ("process_programs_compiled", "programs_compiled"),
+            ("process_program_cache_hits", "program_cache_hits"),
+        ):
+            lines += [f"# TYPE presto_tpu_{name}_total counter",
+                      f"presto_tpu_{name}_total {cc[key]}"]
         # cache-aware admission (ISSUE 17): replays that never took a
         # resource-group slot — next to the hit-rate so loadbench can
         # assert near-zero-cost hits stop occupying the queue
@@ -1114,11 +1173,7 @@ class _DcnServerRunner:
     def executor(self):
         return self._local.executor
 
-    @property
-    def last_trace(self):
-        return getattr(self._local, "last_trace", None)
-
-    def execute(self, sql: str):
+    def execute(self, sql: str, trace=None):
         from presto_tpu.runner import QueryResult
         from presto_tpu.sql import ast_nodes as N
         from presto_tpu.sql.parser import parse
@@ -1128,12 +1183,16 @@ class _DcnServerRunner:
         except Exception:  # noqa: BLE001 - not dispatchable: the
             stmt = None    # local path raises the proper error body
         if isinstance(stmt, N.Query):
-            rows = self._dcn.execute(sql)
+            self._dcn.handed_trace = trace
+            try:
+                rows = self._dcn.execute(sql)
+            finally:
+                self._dcn.handed_trace = None
             return QueryResult(
                 column_names=self._dcn.last_output_names or [],
                 rows=rows,
             )
-        return self._local.execute(sql)
+        return self._local.execute(sql, trace=trace)
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -1552,7 +1611,7 @@ class PrestoTpuServer:
         if session_defaults:
             Session(properties=session_defaults)
 
-        def runner_factory(session: Session):
+        def seed_session(session: Session):
             # deployment-tier session defaults (etc/config.properties,
             # see config.server_from_etc): seed properties the client
             # session did not explicitly set — an explicit
@@ -1570,6 +1629,11 @@ class PrestoTpuServer:
             # explicit client/deployment off always wins.
             if not session.is_set("query_trace_enabled"):
                 session.set("query_trace_enabled", True)
+
+        def runner_factory(session: Session):
+            # the manager seeds at submission (before it makes the
+            # statement's trace); a factory called directly seeds too
+            seed_session(session)
             if memory_arbiter is None:
                 # serial path: one engine, re-sessioned per query;
                 # with a worker fleet, plain queries route through the
@@ -1620,6 +1684,7 @@ class PrestoTpuServer:
             journal=self._journal,
             counter_executor=self._runner.executor,
             dcn=self._dcn,
+            session_seed=seed_session,
         )
         if self._launch_batcher is not None:
             # gather only when there is someone to gang with: a lone

@@ -1,0 +1,294 @@
+"""The device-program registry and launch point (ISSUE 25,
+presto_tpu/exec/programs.py): every program the executor makes has a
+declared label and is jitted under it, every call of one is counted and
+annotated at one place, on the executor that made the call."""
+
+import ast
+import glob
+import os
+
+import jax
+import pytest
+
+from presto_tpu.connectors.tpch import TpchConnector
+from presto_tpu.exec import programs as PG
+from presto_tpu.exec.counters import QUERY_COUNTERS
+from presto_tpu.exec.executor import Executor
+from presto_tpu.runner import LocalRunner
+from tests.tpch_queries import QUERIES
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SF = 0.01
+
+
+def _jit_key_labels():
+    """(file, line, label) of every ``<x>._jit(key, ...)`` call in the
+    engine whose key is a tuple literal beginning with a string, or a
+    name bound to one in the same function."""
+    out = []
+    for path in glob.glob(
+            os.path.join(REPO, "presto_tpu", "**", "*.py"), recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for fn in ast.walk(tree):
+            if not isinstance(fn, (ast.FunctionDef, ast.Lambda)):
+                continue
+            bound = {
+                n.targets[0].id: n.value for n in ast.walk(fn)
+                if isinstance(n, ast.Assign) and len(n.targets) == 1
+                and isinstance(n.targets[0], ast.Name)}
+            for call in ast.walk(fn):
+                if not (isinstance(call, ast.Call)
+                        and isinstance(call.func, ast.Attribute)
+                        and call.func.attr == "_jit" and call.args):
+                    continue
+                key = call.args[0]
+                if isinstance(key, ast.Name):
+                    key = bound.get(key.id, key)
+                first = key.elts[0] if isinstance(key, ast.Tuple) else key
+                assert isinstance(first, ast.Constant) and isinstance(
+                    first.value, str), (
+                    f"{path}:{call.lineno}: a _jit key has to begin "
+                    "with its label, a string literal")
+                out.append((os.path.relpath(path, REPO), call.lineno,
+                            first.value))
+    return out
+
+
+def test_every_jit_key_label_is_declared():
+    sites = _jit_key_labels()
+    assert len({(f, n) for f, n, _ in sites}) >= 45
+    undeclared = sorted({(f, n, lab) for f, n, lab in sites
+                         if lab not in PG.PROGRAM_LABELS})
+    assert not undeclared, (
+        "a _jit key's label is missing from "
+        f"exec/programs.PROGRAM_LABELS: {undeclared}")
+    used = {lab for _f, _n, lab in sites} | {"scan_gen"}
+    assert set(PG.PROGRAM_LABELS) == used, (
+        "stale labels", sorted(set(PG.PROGRAM_LABELS) - used))
+    assert set(PG.PROGRAM_LABELS.values()) <= set(PG.FAMILIES)
+
+
+def test_family_of_reads_a_trace_program_name():
+    assert PG.family_of("jit_join_probe(5456584955919556897)") == "join"
+    assert PG.family_of("jit_fused_batch(12)") == "scan"
+    assert PG.family_of("jit_sort_page") == "sort_topn"
+    # what this registry did not name: an eager jnp call, the old name
+    assert PG.family_of("jit_gather(77)") is None
+    assert PG.family_of("jit__unknown(5456584955919556897)") is None
+    assert PG.family_of("jit_sort(3)") is None
+    assert PG.label_of(("agg_merge", 1, 2)) == "agg_merge"
+    assert PG.label_of((("agg_merge", 1), "donate")) == "program"
+
+
+@pytest.fixture(scope="module")
+def traced_tpch():
+    """Q1/Q3/Q5/Q6 at SF0.01 on a fresh runner with the fused paths the
+    chip takes forced on, traced: the names of every program XLA was
+    asked for meanwhile, and the launches by label of each attempt."""
+    from jax import monitoring
+
+    runner = LocalRunner({"tpch": TpchConnector(SF)}, page_rows=1 << 13)
+    runner.session.set("query_trace_enabled", True)
+    runner.session.set("fused_partial_agg_enabled", "true")
+    runner.session.set("split_batch_size", 8)
+    names = []
+
+    def on(event, _duration, **kw):
+        if event.endswith("backend_compile_duration"):
+            names.append(kw.get("fun_name", "?"))
+
+    monitoring.register_event_duration_secs_listener(on)
+    launches, counters = {}, {}
+    try:
+        for q in (1, 3, 5, 6):
+            runner.execute(QUERIES[q])
+            ex = runner.executor
+            counters[q] = (ex.device_launches, ex.program_launches,
+                           ex.dispatch_wall_us, ex.device_wait_us)
+            for sp in runner.last_trace.spans():
+                if sp.kind == "attempt" and sp.attrs.get("launches"):
+                    for lab, n in sp.attrs["launches"].items():
+                        launches[lab] = launches.get(lab, 0) + n
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+    return names, launches, counters
+
+
+def test_no_program_of_tpch_is_unnamed(traced_tpch):
+    names, launches, _ = traced_tpch
+    assert names, "no program was requested: the listener saw nothing"
+    unnamed = [n for n in names if "unknown" in n or "lambda" in n]
+    assert not unnamed, unnamed
+    assert launches and set(launches) <= set(PG.PROGRAM_LABELS), launches
+    # the executor's own programs are there under their labels
+    requested = {n[len("jit("):-1] for n in names if n.startswith("jit(")}
+    assert set(launches) <= requested, (sorted(launches),
+                                        sorted(requested))
+    assert {"fused_batch", "join_probe"} & set(launches)
+
+
+def test_launch_counters_per_statement(traced_tpch):
+    _names, launches, counters = traced_tpch
+    for q, (device, fused, dispatch_us, wait_us) in counters.items():
+        assert device >= fused >= 1, (q, device, fused)
+        assert dispatch_us > 0 and wait_us > 0, (q, dispatch_us, wait_us)
+    # the joins launch more than their fused scans
+    assert counters[3][0] > counters[3][1]
+    for name in ("device_launches", "dispatch_wall_us", "device_wait_us"):
+        assert QUERY_COUNTERS[name][0] == "gauge"
+
+
+def test_launches_count_on_the_calling_executor():
+    """The concurrent server shares one jit cache between per-query
+    executors: a program counts its call, and its donation, on the
+    executor that called it, not on the one that built it."""
+    import jax.numpy as jnp
+
+    catalogs = {"tpch": TpchConnector(SF)}
+    builder, caller = Executor(catalogs), Executor(catalogs)
+    caller._jit_cache = builder._jit_cache
+    for ex in (builder, caller):
+        ex.buffer_donation = "true"
+
+    def bump(x):
+        return x + 1
+
+    first = builder._jit(("agg_merge", "t"), bump, donate_argnums=(0,))
+    first(jnp.arange(4))
+    assert (builder.device_launches, builder.buffers_donated) == (1, 1)
+    again = caller._jit(("agg_merge", "t"), bump, donate_argnums=(0,))
+    assert len(builder._jit_cache) == 1, "the program was built twice"
+    again(jnp.arange(4))
+    again(jnp.arange(4))
+    assert (caller.device_launches, caller.buffers_donated) == (2, 2)
+    assert (builder.device_launches, builder.buffers_donated) == (1, 1)
+    assert caller.program_launches == 0  # no fused scan among them
+    fused = caller._jit(("fused", "t"), make=lambda: bump)
+    fused(jnp.arange(4))
+    assert (caller.device_launches, caller.program_launches) == (3, 1)
+    assert builder.program_launches == 0
+    assert caller.dispatch_wall_us > 0
+
+
+def test_profiler_recording_holds_the_statements_own_account(tmp_path):
+    """A jax.profiler recording with the harness's options: the host
+    plane holds execute:<query id> and, nested inside it, the launches
+    and the waits, on the recording's own clock."""
+    from jax.profiler import ProfileData
+
+    runner = LocalRunner({"tpch": TpchConnector(SF)}, page_rows=1 << 13)
+    runner.session.set("query_trace_enabled", True)
+    sql = QUERIES[6]
+    runner.execute(sql)  # compiled before the recording
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        runner.execute(sql)
+    finally:
+        jax.profiler.stop_trace()
+    query_id = runner.last_trace.query_id
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for line in host.lines for e in line.events]
+    by_name = {}
+    for name, lo, hi in events:
+        by_name.setdefault(name, []).append((lo, hi))
+    assert {"parse", "plan", f"execute:{query_id}"} <= set(by_name), (
+        sorted(n for n in by_name if ":" in n or n in ("parse", "plan")))
+    ((x_lo, x_hi),) = by_name[f"execute:{query_id}"]
+    launches = [(n, lo, hi) for n, lo, hi in events
+                if n.startswith("launch:")]
+    waits = [(n, lo, hi) for n, lo, hi in events if n.startswith("wait:")]
+    assert launches and waits
+    for _name, lo, hi in launches + waits:
+        assert x_lo <= lo and hi <= x_hi
+    assert {n for n, _lo, _hi in launches} == {
+        f"launch:{lab}" for sp in runner.last_trace.spans()
+        if sp.kind == "attempt" for lab in sp.attrs["launches"]}
+    assert by_name["plan"][0][1] <= x_lo + 1000  # plan ends as it begins
+
+
+# ------------------------------------------------ program load, split
+def test_nested_trace_events_count_once():
+    """A jitted function traced while another is traced reports its
+    event inside the outer one's interval: the total is the union."""
+    import threading
+
+    from presto_tpu import compilecache as CC
+
+    out = {}
+
+    def feed():  # a thread of its own: the account is per thread
+        base = CC.snapshot()
+        CC._on_duration(CC._JAXPR_TRACE, 0.2)   # inner, ends now
+        CC._on_duration(CC._JAXPR_TRACE, 0.1)   # its sibling
+        CC._on_duration(CC._JAXPR_TRACE, 0.5)   # the outer one
+        out["nested"] = CC.delta(base)
+        base = CC.snapshot()
+        CC._on_duration(CC._JAXPR_TO_MLIR, 0.25)
+        CC._on_duration(CC._CACHE_RETRIEVAL, 0.125)
+        out["rest"] = CC.delta(base)
+
+    t = threading.Thread(target=feed)
+    t.start()
+    t.join()
+    assert out["nested"]["programs_traced"] == 3
+    # 0.2 and 0.1 overlap as fed (both end "now"), the outer holds both
+    assert out["nested"]["program_trace_wall_s"] == pytest.approx(
+        0.5, abs=0.01)
+    assert out["rest"]["programs_lowered"] == 1
+    assert out["rest"]["program_lower_wall_s"] == pytest.approx(0.25)
+    assert out["rest"]["program_retrieval_wall_s"] == pytest.approx(0.125)
+
+
+def test_a_real_first_call_is_split_into_its_parts():
+    import time
+
+    import jax.numpy as jnp
+
+    from presto_tpu import compilecache as CC
+
+    inner = jax.jit(lambda y: y * 3.0 + 25.0)
+    outer = jax.jit(lambda x: inner(x) - 25.0)
+    base = CC.snapshot()
+    t0 = time.perf_counter()
+    outer(jnp.arange(8.0)).block_until_ready()
+    wall = time.perf_counter() - t0
+    d = CC.delta(base)
+    assert d["programs_traced"] >= 2 and d["programs_lowered"] >= 1
+    assert 0 < d["program_trace_wall_s"] <= wall
+    assert 0 < d["program_lower_wall_s"] <= wall
+    parts = (d["program_trace_wall_s"] + d["program_lower_wall_s"]
+             + d["program_retrieval_wall_s"] + d["compile_wall_s"])
+    assert parts <= wall + 0.005, (d, wall)
+
+
+def test_metrics_expose_what_the_benchmark_scrapes(traced_tpch):
+    from benchmarks.harness.serve import _METRIC_LINE
+    from presto_tpu.server.http_server import QueryManager
+
+    runner = LocalRunner({"tpch": TpchConnector(SF)}, page_rows=1 << 13)
+    runner.execute(QUERIES[6])
+    text = QueryManager(lambda s: runner).metrics_text(
+        1.0, executor=runner.executor)
+    scraped = {}
+    for line in text.splitlines():
+        m = _METRIC_LINE.match(line)
+        if m:
+            scraped[m.group(1)] = float(m.group(2))
+    assert scraped["device_launches"] == runner.executor.device_launches
+    assert scraped["device_launches"] >= scraped["program_launches"] >= 1
+    assert scraped["dispatch_wall_us"] > 0 and scraped["device_wait_us"] > 0
+    for name in ("program_trace_wall_s", "program_lower_wall_s",
+                 "program_retrieval_wall_s", "compile_wall_s"):
+        assert scraped[name] >= 0.0, name
+    assert scraped["program_trace_wall_s"] > 0
+    assert scraped["programs_traced"] >= scraped["programs_lowered"] >= 1
+    assert "process_programs_compiled" in scraped
+    assert "process_program_cache_hits" in scraped

@@ -40,8 +40,8 @@ counter plumbing fixes):
                   program depending on wall clock or identity breaks
                   canonicalization and the persistent compile cache).
   spans           every trace-span kind emitted anywhere (a constant
-                  first argument to a .begin(...)/.complete(...) span
-                  recorder call) is declared in obs.SPAN_KINDS, and
+                  first argument to a .begin(...)/.complete(...)/
+                  .phase(...) span recorder call) is declared in obs.SPAN_KINDS, and
                   every declared kind has an emission site — the
                   QUERY_COUNTERS discipline applied to the trace
                   vocabulary, so the QueryInfo tree, Chrome export,
@@ -675,7 +675,7 @@ def check_purity(paths=None) -> List[Finding]:
 # constant first argument IS an emission site; dynamic kinds (the
 # ingest path re-materializing remote spans) are invisible here by
 # design — every dynamic kind originates at some constant site.
-_SPAN_EMIT_METHODS = ("begin", "complete", "_new")
+_SPAN_EMIT_METHODS = ("begin", "complete", "phase", "_new")
 
 
 def check_spans(paths=None) -> List[Finding]:
