@@ -22,7 +22,9 @@ import dataclasses
 import functools
 import os
 import time
-from typing import Dict, Iterator, List, Optional, Sequence, Tuple
+from typing import (
+    Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -181,6 +183,24 @@ class _FoldBuffer:
     def final_merged(self):
         """All remaining state as one page (None if nothing was added)."""
         return self._merged()
+
+
+class AggSizing(NamedTuple):
+    """First-attempt sizes of one blocking grouped aggregation, decided
+    once by Executor._agg_sizing for the partition decision, the
+    compaction buffer and the single path (and for membudget.audit)."""
+
+    cap: int            # group capacity of the single path
+    compact_rows: int   # compaction accumulator slots; 0 = no compaction
+    parts: int          # hash-partition passes; 1 = the single path
+    # which bound decided: estimate | optimistic | boost for the
+    # capacity, spill_bytes | rows_cap | bytes_cap once parts > 1
+    sized_by: str
+
+    @property
+    def governed(self) -> bool:
+        """The budget model, not the spill threshold, set the passes."""
+        return self.sized_by in ("rows_cap", "bytes_cap")
 
 
 class MemoryBudgetExceeded(RuntimeError):
@@ -429,6 +449,9 @@ class Executor:
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
+        # _agg_sizing's decisions this attempt (the attempt span
+        # reports the costliest: most passes, then largest capacity)
+        self._agg_sizings: List[AggSizing] = []
         # blocking-aggregation sizing heuristics (session properties
         # agg_optimistic_rows / agg_compact_enabled): start group
         # capacities tight and densify join-sparse inputs, both guarded
@@ -2206,7 +2229,8 @@ class Executor:
                     # query's first-attempt shapes, so the retry reuses
                     # cached programs instead of minting fresh ones
                     if tr is not None:
-                        tr.end(att_span, outcome="overflow")
+                        tr.end(att_span, outcome="overflow",
+                               **self._agg_sizing_attrs())
                     self._capacity_boost = SH.next_boost(
                         self._capacity_boost)
                     self.capacity_boost_retries += 1
@@ -2215,7 +2239,8 @@ class Executor:
                 if tr is not None:
                     self._trace_operators(tr, att_span)
                     tr.end(att_span, outcome="ok", rows=len(rows),
-                           launches=dict(self._launches_by_label))
+                           launches=dict(self._launches_by_label),
+                           **self._agg_sizing_attrs())
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
                 self._publish_cache_pending()
@@ -2238,6 +2263,17 @@ class Executor:
             if own_stats:
                 self._collect_stats = None
 
+    def _agg_sizing_attrs(self) -> Dict[str, object]:
+        """What the attempt's grouped aggregation was sized to, for
+        the attempt span: of several aggregations the costliest (most
+        passes, then largest capacity). Empty without one."""
+        if not self._agg_sizings:
+            return {}
+        sz = max(self._agg_sizings, key=lambda z: (z.parts, z.cap))
+        return {"agg_parts": sz.parts, "agg_cap": sz.cap,
+                "agg_compact_rows": sz.compact_rows,
+                "agg_sized_by": sz.sized_by}
+
     def _begin_attempt(self) -> None:
         """Per-attempt reset shared by every overflow-ladder driver
         (execute(), stream_fragment()): deferred flags, materialized
@@ -2259,6 +2295,7 @@ class Executor:
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label = {}
+        self._agg_sizings = []
         self.splits_scanned = 0
         self.queries_per_launch = 0
         self.memory_chunked_pipelines = 0
@@ -2534,7 +2571,8 @@ class Executor:
                     continue
                 if not self._overflow_flagged():
                     if tr is not None:
-                        tr.end(att_span, outcome="ok", pages=len(out))
+                        tr.end(att_span, outcome="ok", pages=len(out),
+                               **self._agg_sizing_attrs())
                     # publication mirrors the emit discipline: only a
                     # completed overflow-free attempt's streams cache
                     self._publish_cache_pending()
@@ -2545,7 +2583,8 @@ class Executor:
                 # same shared-ladder re-entry as execute(): fragment
                 # retries land on rungs the cache already paid for
                 if tr is not None:
-                    tr.end(att_span, outcome="overflow")
+                    tr.end(att_span, outcome="overflow",
+                           **self._agg_sizing_attrs())
                 self._capacity_boost = SH.next_boost(self._capacity_boost)
                 self.capacity_boost_retries += 1
                 attempts += 1
@@ -2912,75 +2951,16 @@ class Executor:
             yield self._exec_global_agg(node, in_types, layouts)
             return
 
-        parts = 1
-        src_types = self.output_types(node.source)
-        can_partition = self._keys_partitionable(
-            src_types, node.group_channels
-        )
-        if can_partition:
-            est_rows = self.estimate_rows(node.source)
-            # boost-scaled: a fold-overflow retry (true cardinality
-            # past the planner estimate AND the governed fold cap,
-            # which is pinned under the fault line and cannot grow)
-            # must eventually cross INTO the partitioned path — the
-            # single path's only remaining escape
-            cap_est = _next_pow2(
-                max(node.capacity, 8) * self._capacity_boost
-            )
-            n_pages = max(-(-est_rows // max(self.page_rows, 1)), 1)
-            state_types = [src_types[c] for c in node.group_channels]
-            for spec, in_t in zip(node.aggregates, in_types):
-                state_types.extend(
-                    st.type for st in S.state_layout(spec.function, in_t)
-                )
-            merged_slots = min(est_rows, n_pages * cap_est)
-            if self._capacity_boost > 1:
-                # a boosted retry is EVIDENCE the estimates are low
-                # (something overflowed at the previous capacities):
-                # stop letting an under-estimated est_rows cap the
-                # partition decision, or the boost ladder can climb
-                # forever without the escape ever engaging
-                merged_slots = max(merged_slots, cap_est)
-            state_row_b = _row_bytes(state_types)
-            if self.spill_bytes is not None:
-                parts = self._spill_partitions(merged_slots * state_row_b)
-            # governed (membudget.py): aggregation state must fit its
-            # budget share regardless of the spill threshold — over
-            # budget, the aggregation runs in hash-partition passes.
-            # rows_cap = the single path's governed FOLD cap (fr>>2,
-            # see fold_cap below), not the raw fault line: a state the
-            # fold can never hold must partition, or boosted retries
-            # would never converge
-            budget = self._budget()
-            fr = self._fault_rows()
-            gparts = SH.parts_for(
-                merged_slots, state_row_b,
-                rows_cap=max(fr >> 2, 8192) if fr else None,
-                bytes_cap=(budget // MB.BUILD_SHARE_DIV
-                           if budget else None),
-            )
-            if gparts > parts:
-                parts = gparts
-                self.memory_chunked_pipelines += 1
-        if parts > 1:
+        sizing = self._agg_sizing(node)
+        self._agg_sizings.append(sizing)
+        if sizing.governed:
+            self.memory_chunked_pipelines += 1
+        if sizing.parts > 1:
             yield from self._exec_agg_partitioned(
-                node, parts, in_types, layouts
+                node, sizing.parts, in_types, layouts
             )
             return
-
-        # no global clamp: boosted retries must be able to grow past
-        # page_rows (join-output pages can exceed it); the per-page
-        # min(..., page.capacity) below bounds each launch
-        cap = _next_pow2(node.capacity * self._capacity_boost)
-        # optimistic clamp: the planner's capacity estimate has no
-        # selectivity model and routinely over-estimates 100x (Q3's
-        # 1.1M-orderkey estimate vs 11k real groups); every sort/
-        # scatter in the grouped path scales with capacity, so start
-        # tight — the boost ladder grows past it when real cardinality
-        # overflows (same escape as every capacity decision here)
-        if self.agg_optimistic_rows:
-            cap = min(cap, _next_pow2(
-                self.agg_optimistic_rows * self._capacity_boost))
+        cap = sizing.cap
         pallas_agg = self._pallas_agg_on()
         if pallas_agg:
             self.pallas_kernels_used += 1
@@ -3010,7 +2990,8 @@ class Executor:
         # spill is on, onto partitioned passes).
         fold_cap = min(cap, _next_pow2((1 << 20) * self._capacity_boost))
         fr = self._fault_rows()
-        if fr and can_partition:
+        if fr and self._keys_partitionable(
+                self.output_types(node.source), node.group_channels):
             # governed: acc + flush batch + one page stays under the
             # device fault line even at full boost — safe to PIN only
             # because true high-cardinality states have an escape (the
@@ -3087,10 +3068,108 @@ class Executor:
         self._pending_overflow.append(overflow)
         yield out
 
+    def _agg_sizing(self, node: P.Aggregation) -> AggSizing:
+        """First-attempt sizing of a blocking grouped aggregation: THE
+        one rule behind the partition decision, the compaction buffer
+        and the single path's group capacity, shared verbatim by the
+        static audit (membudget.audit) so prediction and execution
+        cannot drift.
+
+        The planner's capacity has no selectivity model and routinely
+        over-estimates 100x (Q3 SF1: a 4M-slot bound for 11k groups out
+        of 30k joined rows), and every sort, scatter and partition pass
+        of the grouped path is paid per SLOT. So the first attempt
+        (boost 1) sizes all three from what it assumes it will see —
+        min(planner capacity, agg_optimistic_rows) — and a statement
+        with more groups or valid rows than that flags overflow, pays
+        one cheap failed attempt, and re-enters boosted. A boosted
+        attempt is evidence the optimistic size was wrong: it sizes
+        from the planner's boost-scaled bounds again, where the
+        partitioned paths are the escape for a state the governed fold
+        (pinned under the fault line) can never hold."""
+        boost = self._capacity_boost
+        src_types = self.output_types(node.source)
+        keys = node.group_channels
+        cap = _next_pow2(node.capacity * boost)
+        sized_by = "estimate" if boost == 1 else "boost"
+        opt = self.agg_optimistic_rows
+        if opt and _next_pow2(opt * boost) < cap:
+            cap = _next_pow2(opt * boost)
+            if boost == 1:
+                sized_by = "optimistic"
+        budget = self._budget()
+        fr = self._fault_rows()
+
+        parts = 1
+        if self._keys_partitionable(src_types, keys):
+            est_rows = self.estimate_rows(node.source)
+            if boost == 1:
+                # the fold (or the compaction buffer) holds the single
+                # path's state to cap however many pages feed it
+                merged_slots = min(est_rows, cap)
+            else:
+                # unmerged partial pages of cap_est slots each (the
+                # planner's bound, boost-scaled); never under cap_est,
+                # or an under-estimated est_rows lets the ladder climb
+                # forever without the escape engaging
+                cap_est = _next_pow2(max(node.capacity, 8) * boost)
+                n_pages = max(-(-est_rows // max(self.page_rows, 1)), 1)
+                merged_slots = max(
+                    min(est_rows, n_pages * cap_est), cap_est)
+            state_types = [src_types[c] for c in keys]
+            for spec, in_t in zip(node.aggregates,
+                                  self._agg_in_types(node)):
+                state_types.extend(
+                    st.type for st in S.state_layout(spec.function, in_t)
+                )
+            state_row_b = _row_bytes(state_types)
+            if self.spill_bytes is not None:
+                parts = self._spill_partitions(merged_slots * state_row_b)
+                if parts > 1:
+                    sized_by = "spill_bytes"
+            # governed (membudget.py): the state must fit its budget
+            # share and the single path's governed FOLD cap (fr >> 2),
+            # not the raw fault line — a state the fold can never hold
+            # must partition, or boosted retries would never converge
+            rows_cap = max(fr >> 2, 8192) if fr else None
+            bytes_cap = budget // MB.BUILD_SHARE_DIV if budget else None
+            gparts = SH.parts_for(merged_slots, state_row_b,
+                                  rows_cap=rows_cap, bytes_cap=bytes_cap)
+            if gparts > parts:
+                by_rows = SH.parts_for(merged_slots, state_row_b,
+                                       rows_cap=rows_cap, bytes_cap=None)
+                sized_by = "rows_cap" if by_rows == gparts else "bytes_cap"
+                parts = gparts
+
+        # compaction pays where grouping cost is slot-proportional: the
+        # packed-argsort path _group_ids takes above the matmul limit
+        # for keys that are not all dictionary- or boolean-coded. Dense
+        # ids (Q5's n_name) cost near nothing per sparse page, and a
+        # compacting argsort over the page would cost more than it
+        # saves. An accumulator past the governed buffer ceiling (2M
+        # slots on the chip) or its budget share is not built: the
+        # partitioned and plain paths take dense streams.
+        compact_rows = 0
+        if (self.agg_compact and cap > A.MATMUL_AGG_MAX_GROUPS
+                and not all(T.is_string(src_types[c])
+                            or isinstance(src_types[c], T.BooleanType)
+                            for c in keys)
+                and _subtree_has_join(node.source)):
+            basis = cap if boost == 1 else _next_pow2(
+                max(node.capacity, 8))
+            rows = _next_pow2(
+                max(opt or (1 << 18), basis, 8192) * boost)
+            ceiling = MB.rows_cap(
+                _row_bytes(src_types), budget,
+                fr or SH.SAFE_BUFFER_ROWS, MB.BUILD_SHARE_DIV)
+            if rows <= ceiling:
+                compact_rows = rows
+        return AggSizing(cap, compact_rows, parts, sized_by)
+
     def _agg_source_pages(self, node: P.Aggregation) -> Iterator[Page]:
         """Aggregation input stream, densified through a rolling
-        compaction buffer when the source subtree contains a join: join
-        output pages keep probe capacity but are usually mostly-invalid
+        compaction buffer where _agg_sizing asks for one: join output
+        pages keep probe capacity but are usually mostly-invalid
         (build filters + match rate), and every sort/scatter in the
         blocking aggregation scales with SLOT count, not valid rows.
         Each input page merge-compacts into one accumulator page (a
@@ -3100,35 +3179,9 @@ class Executor:
         flag overflow and ride the boosted-retry ladder (reference
         analog: every Presto operator re-compacts via PageBuilder —
         pages are always dense there)."""
-        if node.capacity <= A.MATMUL_AGG_MAX_GROUPS:
-            # few groups: the aggregation runs on the dense/MXU paths
-            # whose per-page cost is already near-free — and at scale
-            # the accumulator could not hold a high-selectivity stream
-            # anyway (e.g. Q5 SF100's ~18M qualifying rows vs a <=2M
-            # buffer); stream straight through
+        C = self._agg_sizing(node).compact_rows
+        if not C:
             yield from self.pages(node.source)
-            return
-        yield from self._compacted_stream(node.source, node)
-
-    def _compacted_stream(self, src: P.PhysicalNode,
-                          key_node) -> Iterator[Page]:
-        if not self.agg_compact or not _subtree_has_join(src):
-            yield from self.pages(src)
-            return
-        # the planner's group-count estimate is a LOWER bound on the
-        # stream's valid rows — an accumulator smaller than it is
-        # guaranteed to overflow (observed: Q3 SF10's ~3M qualifying
-        # rows vs the 262k optimistic default), so size C to cover the
-        # estimate and skip compaction entirely when that can't fit
-        # under the >=4M-row fault line (the partitioned/plain
-        # paths handle dense streams without a rolling buffer)
-        est = _next_pow2(max(getattr(key_node, "capacity", 8), 8))
-        C = _next_pow2(
-            max(self.agg_optimistic_rows or (1 << 18), est, 8192)
-            * self._capacity_boost
-        )
-        if C > (1 << 21):
-            yield from self.pages(src)
             return
         # bare kernels: ONE canonical entry each serves every stream
         first = self._jit(
@@ -3140,11 +3193,18 @@ class Executor:
             static_argnums=(2,),
         )
         acc = None
-        for page in self.pages(src):
+        for page in self.pages(node.source):
+            if acc is None or page.capacity > C:
+                # a page wider than the accumulator compacts alone
+                # first: the merge's concat then never passes 2C slots
+                # (C <= 2M keeps it under the fault line), and both
+                # argsorts stay as small as their inputs allow
+                page, overflow = first(page, C)
+                self._pending_overflow.append(overflow)
             if acc is None:
-                acc, overflow = first(page, C)
-            else:
-                acc, overflow = merge(acc, page, C)
+                acc = page
+                continue
+            acc, overflow = merge(acc, page, C)
             self._pending_overflow.append(overflow)
         if acc is not None:
             yield acc

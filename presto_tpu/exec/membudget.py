@@ -243,10 +243,7 @@ def audit(ex, node) -> AuditReport:
         if isinstance(n, P.Aggregation):
             if not n.group_channels:
                 return SH.LADDER_MIN
-            cap = SH.bucket(max(n.capacity, 8))
-            if ex.agg_optimistic_rows:
-                cap = min(cap, SH.bucket(ex.agg_optimistic_rows))
-            return cap
+            return ex._agg_sizing(n).cap
         if isinstance(n, P.TopN):
             return SH.bucket(max(n.limit, 8))
         return None
@@ -318,9 +315,17 @@ def audit(ex, node) -> AuditReport:
             if not n.group_channels:
                 add("global agg state", SH.LADDER_MIN, row_b)
             else:
-                cap = SH.bucket(max(n.capacity, 8))
-                if ex.agg_optimistic_rows:
-                    cap = min(cap, SH.bucket(ex.agg_optimistic_rows))
+                # the executor's own first-attempt decision
+                # (Executor._agg_sizing): single path at sz.cap, or
+                # hash-partition passes of the planner's capacity
+                sz = ex._agg_sizing(n)
+                if sz.parts > 1:
+                    label = f"agg state (1/{sz.parts} pass)"
+                    cap = SH.chunk_bucket(
+                        SH.bucket(n.capacity * ex._capacity_boost),
+                        sz.parts)
+                else:
+                    label, cap = "agg state", sz.cap
                 # row ceiling = the executor's governed FOLD cap
                 # (fr>>2), the largest state the single path can hold
                 state_cap = rows_cap(
@@ -331,10 +336,14 @@ def audit(ex, node) -> AuditReport:
                 # the fold accumulator is a donated merge input when
                 # buffer donation is on — the chained merges reuse
                 # one allocation in place (executor agg_merge sites)
-                add("agg state", min(cap, state_cap) if state_cap
-                    else cap, row_b,
-                    chunked=bool(state_cap and cap > state_cap),
+                add(label, min(cap, state_cap) if state_cap else cap,
+                    row_b,
+                    chunked=sz.parts > 1
+                    or bool(state_cap and cap > state_cap),
                     donated=True)
+                if sz.compact_rows:
+                    add("agg compaction", sz.compact_rows,
+                        _row_bytes(ex.output_types(n.source)))
             walk(n.source)
             return
         if isinstance(n, (P.Sort, P.Window, P.MarkDistinct)):

@@ -147,7 +147,9 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "state buffers start at min(planner estimate, this) and grow "
             "on the overflow-retry ladder; sorts/scatters in the grouped "
             "path scale with capacity, so a tight start is much faster "
-            "when the planner over-estimates (0 = trust the estimate)",
+            "when the planner over-estimates (0 = trust the estimate). "
+            "The first attempt's hash-partition decision and join-output "
+            "compaction buffer are sized from the same number",
             int, 1 << 18,
         ),
         PropertyMetadata(

@@ -99,6 +99,17 @@ def _bigint_page(n, sharding, columns=2):
     )
 
 
+def _find(node, t):
+    """The first plan node of type ``t``, depth first."""
+    if isinstance(node, t):
+        return node
+    for c in node.children():
+        r = _find(c, t)
+        if r is not None:
+            return r
+    return None
+
+
 def test_dim_pallas_probe_lowers_through_mosaic(one_chip):
     from presto_tpu.ops import pallas_join as PJ
 
@@ -144,19 +155,10 @@ def test_fused_page_step_compiles(qnum, one_chip, tpu_branches):
     runner = LocalRunner({"tpch": conn}, page_rows=PAGE_ROWS)
     plan = runner.plan(QUERIES[qnum])
 
-    def find(node, t):
-        if isinstance(node, t):
-            return node
-        for c in node.children():
-            r = find(c, t)
-            if r is not None:
-                return r
-        return None
-
-    agg = find(plan, P.Aggregation)
+    agg = _find(plan, P.Aggregation)
     while isinstance(agg.source, P.Aggregation):
         agg = agg.source  # the partial step sits under the final one
-    filt, scan = find(plan, P.Filter), find(plan, P.TableScan)
+    filt, scan = _find(plan, P.Filter), _find(plan, P.TableScan)
     in_types = runner.executor._agg_in_types(agg)
     layouts = tuple(tuple(S.state_layout(s.function, t))
                     for s, t in zip(agg.aggregates, in_types))
@@ -208,6 +210,54 @@ def test_sort_join_probe_compiles_at_the_build_ceiling(one_chip):
             (0,), (0,), "inner", False, page, build, idx, PAGE_ROWS),
         _bigint_page(PAGE_ROWS, one_chip), _bigint_page(nb, one_chip),
         index)
+
+
+def test_q3_compacted_aggregation_compiles(one_chip, tpu_branches):
+    """Q3 at SF1 as Executor._agg_sizing sizes its first attempt
+    (ISSUE 26): a 2^22-slot join-output page (16 splits a launch)
+    compacts into the 262,144-row buffer (the merge of two buffers
+    is the same program at an eighth of the size: not compiled here),
+    and the three-key sorted partial aggregation runs once over the
+    dense page at a 262,144 group capacity."""
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import agg_states as S
+    from presto_tpu.exec import plan as P
+    from presto_tpu.exec.executor import (
+        _compact_with_flag,
+        _partial_agg_page,
+    )
+    from presto_tpu.runner import LocalRunner
+    from tests.tpch_queries import QUERIES
+
+    runner = LocalRunner({"tpch": TpchConnector(scale=1.0)},
+                         page_rows=1 << 18)
+    ex = runner.executor
+    ex.fault_rows = SH.SAFE_BUFFER_ROWS
+    ex.device_memory_budget = (16 << 30) * 7 // 8
+
+    agg = _find(runner.plan(QUERIES[3]), P.Aggregation)
+    sz = ex._agg_sizing(agg)
+    assert (sz.parts, sz.cap, sz.compact_rows) == (1, 1 << 18, 1 << 18)
+    types = ex.output_types(agg.source)
+    layouts = tuple(
+        tuple(S.state_layout(s.function, t))
+        for s, t in zip(agg.aggregates, ex._agg_in_types(agg)))
+
+    def page(n):
+        return Page(
+            blocks=tuple(
+                Block(data=_spec((n,), np.dtype(t.numpy_dtype), one_chip),
+                      type=t, nulls=None, dictionary=None)
+                for t in types),
+            valid=_spec((n,), jnp.bool_, one_chip))
+
+    C = sz.compact_rows
+    _compile(lambda pg: _compact_with_flag(pg, C),
+             page(SH.SPLIT_BATCH_ROWS_MAX))
+    _compile(
+        lambda pg: _partial_agg_page(
+            agg.group_channels, agg.aggregates, layouts, pg, sz.cap, 64),
+        page(C))
 
 
 def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
