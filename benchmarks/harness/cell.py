@@ -5,7 +5,9 @@ comparison with the plain reference and the metrics.
              programs (a checkout's first run), compile them in a child
              process that ends before this one touches the chip; start
              the coordinator from the cell's configuration; serve each
-             statement once, which loads its programs (serve.warm)
+             statement once, which loads its programs (serve.warm: all
+             at once on the concurrent server, in a fixed order on the
+             serial path)
     window   the cell's clients, closed loop, for --seconds (traffic.py);
              a traced run records the device for a shorter window, the
              traffic file's ``traced_seconds``
@@ -73,10 +75,9 @@ def _device_or_exit(cell, rehearse: bool):
     return devices, manifest.load_peaks(dev.device_kind)
 
 
-def _memory_peak(devices) -> int:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in devices]
-    return int(max(peaks))
+def _memory_peaks(devices) -> List[int]:
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 def _end_to_end(cell, samples: List, t_window: float, seconds: float
@@ -136,10 +137,11 @@ def _gap_labeller(recorder, samples):
 
 
 def _compare(samples, statements, catalogs, catalog_props, compiled,
-             control: bool) -> bool:
+             control: bool) -> Dict[str, Dict]:
     """After the window: every completed statement against the plain
-    reference (marks the wrong ones), each number compared printed
-    beside its limit. Returns ``correct``."""
+    reference (marks the wrong ones). Returns each number compared
+    beside its limit, ``ok`` where it holds: the run is correct where
+    all do."""
     cache_dir = os.path.join(WORK_DIR, "reference_cache")
     want = reference.answers(statements, catalogs, catalog_props,
                              cache_dir, log=log)
@@ -153,12 +155,19 @@ def _compare(samples, statements, catalogs, catalog_props, compiled,
             error=s.error, wrong=s.wrong)
     completed = [s for s in samples if s.error is None]
     wrong = [s for s in completed if s.wrong]
-    log(phase="check", check="statements_differing_from_reference",
-        value=len(wrong), limit=0, compared=len(completed))
-    log(phase="check", check="programs_compiled_in_window",
-        value=compiled["programs_compiled"], limit=0)
-    log(phase="check", check="statements_completed",
-        value=len(completed), at_least=1)
+    checks = {
+        "statements_differing_from_reference": {
+            "value": len(wrong), "limit": 0, "ok": not wrong,
+            "compared": len(completed)},
+        "programs_compiled_in_window": {
+            "value": compiled["programs_compiled"], "limit": 0,
+            "ok": compiled["programs_compiled"] == 0},
+        "statements_completed": {
+            "value": len(completed), "at_least": 1,
+            "ok": len(completed) >= 1},
+    }
+    for name, check in checks.items():
+        log(phase="check", check=name, **check)
     if control:
         ctl = reference.answers(statements, catalogs, catalog_props,
                                 cache_dir, control=True, log=log)
@@ -168,8 +177,7 @@ def _compare(samples, statements, catalogs, catalog_props, compiled,
             "broken, in the served rows' place: has to differ",
             statements=len(ctl), differing=differing, limit=0,
             control_correct=not differing)
-    return (not wrong and compiled["programs_compiled"] == 0
-            and len(completed) >= 1)
+    return checks
 
 
 def _traced(result: Dict, cell, work: str, recorder, samples, ok,
@@ -190,7 +198,8 @@ def _traced(result: Dict, cell, work: str, recorder, samples, ok,
             json.dump(tracing.describe(xplane), f, indent=1)
         log(phase="trace", kept=kept, bytes=os.path.getsize(kept))
     shutil.rmtree(recorder.out_dir, ignore_errors=True)
-    log(phase="trace", programs=reduced["programs"],
+    log(phase="trace", programs=reduced["programs"][:tracing.TOP_N],
+        programs_in_stretch=len(reduced["programs"]),
         op_events=reduced["op_events"],
         first_op_offset_s=reduced["first_op_offset_s"],
         busy_s_by_device=reduced["busy_s_by_device"])
@@ -208,8 +217,7 @@ def _traced(result: Dict, cell, work: str, recorder, samples, ok,
             ok, recorder.t_start, recorder.t_stop),
         "metrics_start": metrics_start, "metrics_end": metrics_end,
         "trace": reduced,
-        "concurrent": "query.max-memory-bytes"
-                      in cell.config["config_properties"],
+        "concurrent": cell.concurrent,
         "peaks": peaks, "scan_bytes": scan_bytes,
     }
     gather = metrics_end.get("batch_gather_wait_ms", 0.0) - \
@@ -242,9 +250,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     work = os.path.join(WORK_DIR, cell.name + ("_rehearse" * rehearse))
     catalog_props = serve.write_etc(
         os.path.join(work, "etc"), cell.config, rehearse)
-    every = [st for variants in cell.statements.values()
-             for st in variants]
-    uncompiled = serve.uncompiled(cell.config, every, rehearse)
+    uncompiled = serve.uncompiled(cell, rehearse)
     log(phase="start", cell=cell.name, seed=seed, seconds=seconds,
         trace=trace, rehearse=rehearse, work_dir=work,
         cache_dir=compilecache.cache_dir(),
@@ -264,7 +270,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             device_kind=dev.device_kind, devices=len(devices))
         plans = traffic.plan_clients(cell, seed)
         statements = traffic.statements_used(plans)
-        serve.warm(served, statements, log)
+        serve.warm(served, statements, cell.concurrent, log)
 
         recorder = None
         if trace:
@@ -294,7 +300,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             t.join()
         compiled = compilecache.delta(compiled_before)
         metrics_end = served.metrics()
-        memory_peak = _memory_peak(devices)
+        memory_peaks = _memory_peaks(devices)
+        if cell.chips > 1:
+            # that the statements ran over the mesh: exchanges compiled
+            # onto it, and any that fell back to the spool
+            log(phase="mesh", **{k: metrics_end.get(k) for k in (
+                "mesh_local_exchanges", "ici_exchanges",
+                "mesh_exchange_fallbacks")})
         if trace:
             for s in samples:
                 if s.query_id:
@@ -302,8 +314,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     finally:
         served.stop()
 
-    correct = _compare(samples, statements, served.catalogs,
-                       catalog_props, compiled, control)
+    checks = _compare(samples, statements, served.catalogs,
+                      catalog_props, compiled, control)
+    correct = all(check["ok"] for check in checks.values())
     failed = [s for s in samples if not s.ok]
     ok = [s for s in samples if s.ok]
     with open(os.path.join(work, "samples.jsonl"), "w") as f:
@@ -314,11 +327,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                 "latency_s": s.latency_s, "ok": s.ok}) + "\n")
     log(phase="window", window_s=window_end - t_window,
         asked_s=seconds, attempted=len(samples), failed=len(failed),
-        program_cache_hits_in_window=compiled["program_cache_hits"])
+        program_cache_hits_in_window=compiled["program_cache_hits"],
+        memory_peak_bytes_by_device=memory_peaks)
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(devices), "memory_peak_bytes": memory_peak}
+              "count": len(devices),
+              "memory_peak_bytes": max(memory_peaks)}
     result = {"correct": bool(correct), "attempted": len(samples),
-              "failed": len(failed), "metrics": {}, "device": device}
+              "failed": len(failed), "metrics": {}, "device": device,
+              "checks": checks}
     if rehearse:
         # a CPU run prints no time, rate or share under a metric's name
         log(phase="rehearsal", note="CPU rehearsal: counts only, no "
@@ -348,10 +364,7 @@ def compile_only(workload: str, work: str, rehearse: bool) -> None:
     parent wrote under ``work``, and prints no result."""
     cell = manifest.load_cell(workload)
     _device_or_exit(cell, rehearse)
-    every = [st for variants in cell.statements.values()
-             for st in variants]
-    serve.compile_phase(os.path.join(work, "etc"), cell.config, every,
-                        rehearse, log)
+    serve.compile_phase(os.path.join(work, "etc"), cell, rehearse, log)
 
 
 def main(argv, t_process: float) -> int:
@@ -380,6 +393,12 @@ def main(argv, t_process: float) -> int:
     result = run(args.workload, args.seed, args.seconds, bool(args.trace),
                  rehearse=args.rehearse, control=args.control,
                  keep_trace=args.keep_trace, t_process=t_process)
+    # what was compared, beside its limit: last in the result's line and
+    # the last lines of standard error
+    checks = result["checks"] = result.pop("checks")
     sys.stdout.flush()
+    for name, check in checks.items():
+        print(f"check {name}: {json.dumps(check)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

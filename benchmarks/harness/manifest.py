@@ -63,6 +63,18 @@ class Cell:
     per_layer: List[Dict]
     statements: Dict[str, List[Statement]]   # sid -> its variants
 
+    @property
+    def every(self) -> List[Statement]:
+        """Every variant of every statement of the traffic file."""
+        return [st for variants in self.statements.values()
+                for st in variants]
+
+    @property
+    def concurrent(self) -> bool:
+        """Whether the configuration selects the concurrent server
+        (per-query runners, several statements on the chip at once)."""
+        return "query.max-memory-bytes" in self.config["config_properties"]
+
 
 def _reports(metric: Dict, cell_name: str) -> bool:
     return "workloads" not in metric or cell_name in metric["workloads"]

@@ -107,17 +107,24 @@ class Served:
         self.server.stop()
 
 
-def compile_statements(catalogs: Dict, config: Dict, statements: List,
-                       log) -> None:
+def compile_statements(catalogs: Dict, cell, statements: List, log
+                       ) -> None:
     """Every statement's program set compiled (or loaded from the
-    persistent cache) on a runner and a thread of its own, all at once:
-    one cold TPU compile of one statement takes minutes and is
-    single-threaded. The runners get what the server gives every query
-    it runs: tracing on, and the deployment's session defaults."""
+    persistent cache) on a runner of its own. The runners get what the
+    server gives every query it runs: tracing on, the deployment's
+    session defaults and, for a cell on several chips, the mesh, so that
+    what compiles here is what the server will load. On one chip every
+    statement has a thread of its own, all at once: one cold TPU compile
+    of one statement takes minutes and is single-threaded."""
     from presto_tpu.config import ETC_SESSION_KEYS
     from presto_tpu.runner import LocalRunner
 
-    props = config["config_properties"]
+    mesh = {}
+    if cell.chips > 1:
+        from presto_tpu.dist.executor import make_mesh
+
+        mesh["mesh"] = make_mesh(cell.chips)
+    props = cell.config["config_properties"]
     page_rows = int(props.get("page-rows", str(1 << 18)))
     session = {"query_trace_enabled": True}
     for etc_key, prop in ETC_SESSION_KEYS.items():
@@ -126,14 +133,22 @@ def compile_statements(catalogs: Dict, config: Dict, statements: List,
 
     def prewarm(st):
         runner = LocalRunner(catalogs, default_catalog=st.catalog,
-                             page_rows=page_rows)
+                             page_rows=page_rows, **mesh)
         for k, v in session.items():
             runner.session.set(k, v)
         out = runner.prewarm(st.sql)
         log(phase="compile", statement=st.key,
             thread_wall_s=out["wall_s"])
 
-    _run_all(prewarm, statements, "compile phase")
+    if cell.chips > 1:
+        # prewarm executes the statement, and programs with collectives
+        # launched from two threads have no common dispatch order across
+        # the devices (DistExecutor._fenced puts them in one on the CPU
+        # only): over a mesh, one statement after another on one thread
+        for st in statements:
+            prewarm(st)
+    else:
+        _run_all(prewarm, statements, "compile phase")
 
 
 def _run_all(fn, statements, what: str) -> None:
@@ -156,10 +171,11 @@ def _run_all(fn, statements, what: str) -> None:
         raise RuntimeError(f"{what} failed: {failures}") from failures[0][1]
 
 
-def _program_hash(config: Dict) -> "hashlib._Hash":
-    """A hash of the program's source, JAX's version and the
-    configuration as served: what a compiled program depends on besides
-    the statement."""
+def _program_hash(cell) -> "hashlib._Hash":
+    """A hash of the program's source, JAX's version, the configuration
+    as served and the chips it is served over (a mesh's programs are not
+    one chip's): what a compiled program depends on besides the
+    statement."""
     import hashlib
 
     import jax
@@ -174,25 +190,28 @@ def _program_hash(config: Dict) -> "hashlib._Hash":
             if name.endswith(".py"):
                 with open(os.path.join(root, name), "rb") as f:
                     h.update(f.read())
-    h.update(json.dumps(config["config_properties"],
+    h.update(json.dumps(cell.config["config_properties"],
                         sort_keys=True).encode())
-    h.update(json.dumps(config["catalogs"], sort_keys=True).encode())
+    h.update(json.dumps(cell.config["catalogs"],
+                        sort_keys=True).encode())
+    h.update(f"chips={cell.chips}".encode())
     return h
 
 
-def _warm_markers(config: Dict, statements: List, rehearse: bool
+def _warm_markers(cell, statements: List, rehearse: bool
                   ) -> Dict[str, str]:
     """statement key -> a file in the persistent cache's directory that
     says: this source of the program, serving this statement under this
-    configuration, has compiled into this cache before. The file travels
+    configuration over this many chips, has compiled into this cache
+    before. The file travels
     with the cache, and a changed program or statement misses it. The
     directory is the one ``server_from_etc`` will enable: the same call
     is made here, ahead of it, which touches no device."""
     from presto_tpu import compilecache
 
     compilecache.enable_persistent_cache(
-        config["config_properties"].get("compile-cache.dir"))
-    base = _program_hash(config)
+        cell.config["config_properties"].get("compile-cache.dir"))
+    base = _program_hash(cell)
     base.update(b"rehearsal" if rehearse else b"chip")
     out = {}
     for st in statements:
@@ -204,31 +223,31 @@ def _warm_markers(config: Dict, statements: List, rehearse: bool
     return out
 
 
-def uncompiled(config: Dict, every: List, rehearse: bool) -> List:
+def uncompiled(cell, rehearse: bool) -> List:
     """Those of the cell's statements that the persistent cache is not
     known to hold."""
-    markers = _warm_markers(config, every, rehearse)
-    return [st for st in every if not os.path.exists(markers[st.key])]
+    markers = _warm_markers(cell, cell.every, rehearse)
+    return [st for st in cell.every
+            if not os.path.exists(markers[st.key])]
 
 
-def compile_phase(etc_dir: str, config: Dict, every: List,
-                  rehearse: bool, log) -> None:
-    """The child's work: compile what the cache is not known to hold,
-    side by side, on runners over the catalogs as served, and leave the
-    markers."""
+def compile_phase(etc_dir: str, cell, rehearse: bool, log) -> None:
+    """The child's work: compile what the cache is not known to hold, on
+    runners over the catalogs as served (and the mesh, where the cell
+    has one), and leave the markers."""
     from presto_tpu import compilecache
     from presto_tpu.config import load_catalogs
 
-    unknown = uncompiled(config, every, rehearse)
+    unknown = uncompiled(cell, rehearse)
     base = compilecache.snapshot()
     t0 = time.perf_counter()
-    compile_statements(load_catalogs(etc_dir), config, unknown, log)
+    compile_statements(load_catalogs(etc_dir), cell, unknown, log)
     cc = compilecache.delta(base)
     log(phase="compile", compiled_off_server=[st.key for st in unknown],
         wall_s=time.perf_counter() - t0,
         programs_compiled=cc["programs_compiled"],
         program_cache_hits=cc["program_cache_hits"])
-    markers = _warm_markers(config, unknown, rehearse)
+    markers = _warm_markers(cell, unknown, rehearse)
     for st in unknown:
         with open(markers[st.key], "w") as f:
             f.write("warm\n")
@@ -238,32 +257,49 @@ def compile_in_child(command: List[str], log) -> None:
     """Runs the compile phase as a process of its own and waits for its
     end. Called before this process has touched a device: one process
     holds the chip at a time. Whatever ends this process ends the child
-    first."""
+    first. A child that a signal ended is started once more: the TPU
+    compiler has died of a segmentation fault with eight statements
+    compiling side by side (PERF.md, PR 28), what had compiled by then
+    is in the persistent cache, and the second child goes on from
+    there."""
     def on_sigterm(signum, _frame):
         raise SystemExit(128 + signum)
 
     main = threading.current_thread() is threading.main_thread()
     old = signal.signal(signal.SIGTERM, on_sigterm) if main else None
-    t0 = time.perf_counter()
     try:
-        # subprocess.run kills the child and waits for it on any
-        # exception, the SystemExit above among them
-        rc = subprocess.run(command, stdin=subprocess.DEVNULL).returncode
+        for attempt in (1, 2):
+            t0 = time.perf_counter()
+            # subprocess.run kills the child and waits for it on any
+            # exception, the SystemExit above among them
+            rc = subprocess.run(
+                command, stdin=subprocess.DEVNULL).returncode
+            log(phase="compile_child", rc=rc, attempt=attempt,
+                wall_s=time.perf_counter() - t0)
+            if rc >= 0:
+                break
     finally:
         if main:
             signal.signal(signal.SIGTERM, old)
-    log(phase="compile_child", rc=rc, wall_s=time.perf_counter() - t0)
     if rc != 0:
         raise SystemExit(
             f"the compile phase's process ended with code {rc}; its "
             "standard error is above")
 
 
-def warm(served: Served, statements: List, log) -> Dict:
+def warm(served: Served, statements: List, side_by_side: bool, log
+         ) -> Dict:
     """Set-up after the server is up, every program in the persistent
-    cache: each of this run's statements is served once, all at once,
-    which loads its programs into the server's own jit cache. Returns
-    the compile counts of it."""
+    cache: each of this run's statements is served once, which loads its
+    programs into the server's own jit cache. Returns the compile counts
+    of it. The concurrent server is sent them all at once
+    (``side_by_side``). The serial path runs one statement at a time
+    whatever it is sent, so it is sent them one after another, in the
+    order given: which statement loads first decides where its programs'
+    buffers lie on the device, and with it the device time of every
+    later run of a statement (Q3 at SF1: 793 or 810 ms for the whole
+    run, PERF.md, PR 28); sent all at once, the order was a race between
+    two threads."""
     from presto_tpu import compilecache
 
     base = compilecache.snapshot()
@@ -278,7 +314,11 @@ def warm(served: Served, statements: List, log) -> Dict:
         log(phase="warm", statement=st.key,
             wall_s=time.perf_counter() - t1)
 
-    _run_all(serve_once, statements, "warm-up")
+    if side_by_side:
+        _run_all(serve_once, statements, "warm-up")
+    else:
+        for st in statements:
+            serve_once(st)
     cc = compilecache.delta(base)
     log(phase="warm", wall_s=time.perf_counter() - t0,
         programs_compiled=cc["programs_compiled"],

@@ -156,8 +156,10 @@ def reduce(path: str, lo: float, hi: float, label_gap=None) -> Dict:
 
     ``busy_s``: seconds in which an operation ran on the device, the
     union of the ``XLA Ops`` intervals, averaged over the chips in the
-    trace. ``device_ops`` and ``programs``: the operations and programs
-    that took most time, summed by name over the chips. ``idle_gaps``:
+    trace. ``device_ops``: the ``TOP_N`` operations that took most
+    time, summed by name over the chips. ``programs``: every program
+    with its time, summed likewise, most time first (a reader sums a
+    family's; the log line prints the first ``TOP_N``). ``idle_gaps``:
     the longest stretches of the first chip with no operation running,
     labelled by ``label_gap(start on the trace's clock, length)``.
     """
@@ -186,16 +188,16 @@ def reduce(path: str, lo: float, hi: float, label_gap=None) -> Dict:
             name = label_gap(a, b - a)
         idle.append([name, b - a])
 
-    def top(times):
+    def by_time(times):
         return [[n, s] for n, s in sorted(
-            times.items(), key=lambda kv: -kv[1])[:TOP_N]]
+            times.items(), key=lambda kv: -kv[1])]
 
     return {
         "busy_s": sum(busy) / len(busy),
         "busy_s_by_device": busy,
         "window_s": hi - lo,
-        "device_ops": top(op_time),
-        "programs": top(program_time),
+        "device_ops": by_time(op_time)[:TOP_N],
+        "programs": by_time(program_time),
         "idle_gaps": idle,
         "first_op_offset_s": origin,
         "op_events": sum(len(l.get(OPS_LINE, []))

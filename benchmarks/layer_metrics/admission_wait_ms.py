@@ -2,10 +2,9 @@
 (resource group, footprint arbiter or, on the serial path, the
 execution lock): the ``queue`` phase of the statement's own trace, as
 /v1/query/{id} reports it under ``phases`` (microseconds from
-submission). Median over the window's statements. ``queue_wait_ms``
-beside it is the older difference of two clocks' readings, which also
-holds parse, row encoding and bookkeeping. A program without the
-``queue`` span gives nothing to read."""
+submission). Median over the window's statements. The harness prints
+``batch_gather_wait_ms`` per statement from /metrics beside it. A
+program without the ``queue`` span gives nothing to read."""
 
 import statistics
 

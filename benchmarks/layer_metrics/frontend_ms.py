@@ -2,9 +2,8 @@
 plan, optimize, fragment, up to the instant the executor takes the
 plan): the ``parse`` and ``plan`` phases of the statement's own trace,
 as /v1/query/{id} reports them under ``phases`` (microseconds from
-submission). Median over the window's statements. ``plan_ms`` beside it
-is one whole-millisecond offset. A program without these spans gives
-nothing to read."""
+submission). Median over the window's statements. A program without
+these spans gives nothing to read."""
 
 import statistics
 

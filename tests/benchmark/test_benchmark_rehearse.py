@@ -1,10 +1,14 @@
 """The benchmark's command end to end under --rehearse (SF0.01, CPU):
-every cell, the refusal without a TPU, a cell added as files only, a
-four-device configuration, and the controls that have to come out as
-not correct. A rehearsal prints counts and no metric."""
+every cell of the manifest, the refusal without a TPU, a cell added as
+files only, the repository's own manifest grown by such a cell with no
+edit to a file that is there, a four-device configuration whose first
+run compiles over the mesh in its child, and the controls that have to
+come out as not correct. A rehearsal prints counts and no metric."""
 
+import dataclasses
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -12,10 +16,14 @@ import sys
 import pytest
 
 from benchmarks.harness import cell as cell_mod
-from benchmarks.harness import manifest, reference
+from benchmarks.harness import manifest, reference, serve
 
 ROOT = manifest.ROOT
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+# the driver's five, and last what was compared beside its limit
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
+               "checks"]
+CELL_CHIPS = {w["name"]: w["chips"] for w in manifest.load_json(
+    os.path.join(ROOT, "BENCHMARK.json"))["workloads"]}
 
 
 def run_cli(args, cwd=ROOT, devices=1, pythonpath=None, cache_dir=None):
@@ -36,14 +44,13 @@ def last_line(proc):
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("name", [
-    "scan_sf10_solo", "join_sf1_solo", "mixed_sf1_sf10_c8"])
+@pytest.mark.parametrize("name", list(CELL_CHIPS))
 def test_cell_rehearses(name):
     proc = run_cli(["--workload", name, "--seed", "3000000001",
                     "--seconds", "2", "--trace", "0", "--rehearse",
-                    "--control"])
+                    "--control"], devices=CELL_CHIPS[name])
     result = last_line(proc)
-    assert set(result) == RESULT_KEYS
+    assert list(result) == RESULT_KEYS
     assert result["correct"] is True
     assert result["attempted"] > 0 and result["failed"] == 0
     # a CPU run writes nothing under a metric's name
@@ -55,6 +62,13 @@ def test_cell_rehearses(name):
     assert checks["statements_differing_from_reference"]["compared"] == \
         result["attempted"]
     assert checks["programs_compiled_in_window"]["value"] == 0
+    # each number compared beside its limit: in the result's line, and
+    # the last lines of standard error
+    assert set(result["checks"]) == set(checks)
+    assert all(c["ok"] for c in result["checks"].values())
+    said = proc.stderr.strip().splitlines()[-len(checks):]
+    assert [l.split(":")[0] for l in said] == [
+        f"check {name}" for name in result["checks"]]
     # the control (float32 sums; a dropped grace partition) in the served
     # rows' place is not correct
     (control,) = [l for l in lines if l.get("phase") == "control"]
@@ -86,6 +100,31 @@ def test_a_first_run_compiles_in_a_child_process(tmp_path):
     assert second["start"]["compile_in_child"] == []
     assert "compile_child" not in second
     assert second["warm"]["programs_compiled"] == 0
+
+
+def test_a_child_that_a_signal_ended_is_started_once_more(tmp_path):
+    """The TPU compiler has segfaulted in a compile child (PERF.md,
+    PR 28): the child is started a second time, and only a second death
+    or a plain failure ends the run."""
+    flag = tmp_path / "died_once"
+    dies_once = (
+        "import os, signal, sys\n"
+        f"flag = {str(flag)!r}\n"
+        "if os.path.exists(flag):\n"
+        "    sys.exit(0)\n"
+        "open(flag, 'w').close()\n"
+        "os.kill(os.getpid(), signal.SIGKILL)\n")
+    lines = []
+    serve.compile_in_child([sys.executable, "-c", dies_once],
+                           lambda **kw: lines.append(kw))
+    assert [(l["attempt"], l["rc"]) for l in lines] == [(1, -9), (2, 0)]
+    for body, rcs in (("import sys; sys.exit(3)", [3]),
+                      ("import os; os.kill(os.getpid(), 9)", [-9, -9])):
+        lines.clear()
+        with pytest.raises(SystemExit, match=f"code {rcs[-1]}"):
+            serve.compile_in_child([sys.executable, "-c", body],
+                                   lambda **kw: lines.append(kw))
+        assert [l["rc"] for l in lines] == rcs
 
 
 def test_no_tpu_no_result():
@@ -155,20 +194,29 @@ NEW_FILES = {
 }
 
 
+def _add_the_new_cell(tmp_path, new_manifest, *more_dirs):
+    """A copy of ``benchmarks/`` (and of ``more_dirs``) under tmp_path
+    with NEW_FILES beside what was there and ``new_manifest`` as its
+    BENCHMARK.json; no copied file may have changed."""
+    ignore = shutil.ignore_patterns("__pycache__")
+    for rel in ("benchmarks",) + more_dirs:
+        shutil.copytree(os.path.join(ROOT, rel), tmp_path / rel,
+                        ignore=ignore)
+    before = {p: p.read_bytes() for p in tmp_path.rglob("*")
+              if p.is_file()}
+    for rel, body in NEW_FILES.items():
+        assert not (tmp_path / "benchmarks" / rel).exists()
+        (tmp_path / "benchmarks" / rel).write_text(body)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(new_manifest))
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
 def test_a_cell_is_added_as_files_only(tmp_path):
     """A configuration (four devices), a traffic mix, a statement, its
     reference and a per-layer metric are added to a copy of the
     benchmark as new files and entries; no file that was there changes,
     and the harness runs them."""
-    bench = tmp_path / "benchmarks"
-    shutil.copytree(os.path.join(ROOT, "benchmarks"), bench,
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
-    for rel, body in NEW_FILES.items():
-        assert not (bench / rel).exists()
-        (bench / rel).write_text(body)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(NEW_MANIFEST))
-    assert all(p.read_bytes() == b for p, b in before.items())
+    _add_the_new_cell(tmp_path, NEW_MANIFEST)
 
     proc = run_cli(["--workload", "count_mesh4", "--seed", "2200000001",
                     "--seconds", "2", "--trace", "0", "--rehearse"],
@@ -192,6 +240,116 @@ def test_a_cell_is_added_as_files_only(tmp_path):
                          timeout=120)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "'rows_per_query': {'value': 3.0, 'unit': 'rows'}" in out.stdout
+
+
+def _pytest_in(copy, *args):
+    """pytest over the copy's own tests/benchmark files, importing the
+    copy's ``benchmarks`` and the repository's program."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env.update(JAX_PLATFORMS="cpu",
+               PYTHONPATH=f"{copy}{os.pathsep}{ROOT}")
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", *args,
+         os.path.join("tests", "benchmark", "test_benchmark_harness.py"),
+         os.path.join("tests", "benchmark",
+                      "test_layer_metrics_program_spans.py")],
+        cwd=copy, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    return proc.stdout
+
+
+def test_the_manifest_grows_with_no_edit_to_a_file_that_is_there(tmp_path):
+    """What a later PR does: to the repository's own BENCHMARK.json it
+    appends a four-chip configuration, a cell on it, a traffic mix, a
+    statement with its reference and a per-layer metric, as entries and
+    new files. Every test of the manifest and of the readers passes on
+    the result, unedited, and tests the new cell too. A test that pins
+    the manifest's cells, their chips or the order of its metrics fails
+    here."""
+    grown = manifest.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    _add_the_new_cell(tmp_path, grown, "tests/benchmark")
+    collected = re.search(r"(\d+) tests? collected",
+                          _pytest_in(tmp_path, "--collect-only"))
+    for key in ("configs", "workloads", "per_layer"):
+        grown[key] += NEW_MANIFEST[key]
+    (cell,) = NEW_MANIFEST["workloads"]
+    next(e for e in grown["end_to_end"] if e["name"] ==
+         "query_geomean_ms")["workloads"].append(cell["name"])
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(grown))
+    passed = re.search(r"(\d+) passed", _pytest_in(tmp_path))
+    assert int(passed.group(1)) > int(collected.group(1))
+    found = manifest.load_json(str(tmp_path / "BENCHMARK.json"))
+    assert [w["name"] for w in found["workloads"]][-1] == cell["name"]
+
+
+def test_a_four_device_first_run_compiles_over_the_mesh_in_its_child(
+        tmp_path):
+    """The four-device cell with an empty persistent cache: the child
+    compiles what a server over the mesh loads, so the serving process
+    compiles nothing and finds every program in the cache; a mesh
+    program did run; the next run starts no child. (A child that builds
+    its runners without the mesh compiles one chip's programs, and the
+    server then compiles every mesh program itself.)"""
+    _add_the_new_cell(tmp_path, NEW_MANIFEST)
+    args = ["--workload", "count_mesh4", "--seed", "2800000007",
+            "--seconds", "1", "--trace", "0", "--rehearse"]
+    phases = []
+    for _ in range(2):
+        proc = run_cli(args, cwd=str(tmp_path), devices=4,
+                       pythonpath=ROOT, cache_dir=str(tmp_path / "cache"))
+        result = last_line(proc)
+        assert result["correct"] is True and result["device"]["count"] == 4
+        phases.append([json.loads(l) for l in
+                       proc.stdout.strip().splitlines()])
+    first, second = ({l["phase"]: l for l in lines if "phase" in l}
+                     for lines in phases)
+    assert first["start"]["compile_in_child"] == [
+        "big_orders#0", "big_orders#1"]
+    assert first["compile_child"]["rc"] == 0
+    assert first["compile"]["programs_compiled"] > 0
+    order = [l.get("phase") for l in phases[0]]
+    assert order.index("compile_child") < order.index("device")
+    assert first["warm"]["programs_compiled"] == 0
+    assert first["warm"]["program_cache_hits"] > 0
+    # the statements went over the mesh: an exchange compiled onto it
+    # ran, and none fell back to the spool
+    for run in (first, second):
+        assert run["mesh"]["mesh_local_exchanges"] + \
+            run["mesh"]["ici_exchanges"] > 0
+        assert run["mesh"]["mesh_exchange_fallbacks"] == 0
+    assert second["start"]["compile_in_child"] == []
+    assert "compile_child" not in second
+    assert second["warm"]["programs_compiled"] == 0
+
+
+def test_markers_tell_a_mesh_from_one_chip(tmp_path, monkeypatch):
+    """Two configurations equal but for their chips share no marker (the
+    second would otherwise find the first's and start no child); the
+    same configuration finds its own again."""
+    from presto_tpu import compilecache
+
+    monkeypatch.setattr(compilecache, "enable_persistent_cache",
+                        lambda *a, **kw: None)
+    monkeypatch.setattr(compilecache, "cache_dir", lambda: str(tmp_path))
+    one = manifest.load_cell("join_sf1_solo")
+    four = dataclasses.replace(one, chips=4)
+    every = one.every
+    at_one = serve._warm_markers(one, every, False)
+    at_four = serve._warm_markers(four, every, False)
+    assert set(at_one) == set(at_four) == {st.key for st in every}
+    assert len(set(at_one.values()) | set(at_four.values())) == \
+        2 * len(every)
+    assert serve._warm_markers(one, every, False) == at_one
+    assert all(os.path.dirname(p) == str(tmp_path)
+               for p in at_four.values())
+    # and the child is asked for exactly the statements without a marker
+    for path in list(at_four.values())[:2]:
+        with open(path, "w") as f:
+            f.write("warm\n")
+    assert serve.uncompiled(four, False) == every[2:]
+    assert serve.uncompiled(one, False) == every
 
 
 def test_a_broken_timed_path_is_not_correct(monkeypatch, tmp_path):

@@ -1,6 +1,8 @@
-"""The per-layer readers ISSUE 25 adds, each on a synthetic ``ctx``:
+"""The per-layer readers that read the program's own spans, counters and
+program names (ISSUE 25's, and ISSUE 28's ``agg_device_ms_per_query`` in
+the place of ``join_device_ms_per_query``), each on a synthetic ``ctx``:
 what it reads where the program has the span or counter, and that it
-gives nothing, without raising, where the program has not (the parent
+gives nothing, without raising, where the program has not (an older
 commit, on which the driver lays these files too)."""
 
 import os
@@ -16,7 +18,7 @@ NEW = {
     "device_launches_per_query": ("query_geomean_ms", None),
     "dispatch_ms_per_query": ("query_geomean_ms", None),
     "device_wait_ms_per_query": ("query_geomean_ms", None),
-    "join_device_ms_per_query": ("query_geomean_ms", ["join_sf1_solo"]),
+    "agg_device_ms_per_query": ("query_geomean_ms", ["join_sf1_solo"]),
     "program_build_s": ("setup_s", None),
     "program_fetch_s": ("setup_s", None),
 }
@@ -53,12 +55,18 @@ def _ctx(samples, concurrent=False, start=None, end=None, trace=None,
 
 
 def test_the_manifest_has_the_eight_readers_at_the_end():
+    """Each of these readers that the manifest still has is there with
+    its ``moves`` and its cells, wherever it stands: later PRs append
+    metrics, and may append a cell to a list."""
     m = manifest.load_json(os.path.join(manifest.ROOT, "BENCHMARK.json"))
-    tail = m["per_layer"][-len(NEW):]
-    assert [e["name"] for e in tail] == list(NEW)
-    for e in tail:
-        moves, workloads = NEW[e["name"]]
-        assert e["moves"] == moves and e.get("workloads") == workloads
+    entries = {e["name"]: e for e in m["per_layer"]}
+    assert len(entries) == len(m["per_layer"])
+    for name in set(NEW) & set(entries):
+        e = entries[name]
+        moves, workloads = NEW[name]
+        assert e["moves"] == moves
+        assert ("workloads" in e) == (workloads is not None)
+        assert set(workloads or ()) <= set(e.get("workloads", ()))
         assert set(e) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     cells = {w["name"]: {m_["name"] for m_ in
@@ -66,8 +74,8 @@ def test_the_manifest_has_the_eight_readers_at_the_end():
              for w in m["workloads"]}
     assert "admission_wait_ms" in cells["mixed_sf1_sf10_c8"]
     assert "admission_wait_ms" not in cells["scan_sf10_solo"]
-    assert "join_device_ms_per_query" in cells["join_sf1_solo"]
-    assert "join_device_ms_per_query" not in cells["scan_sf10_solo"]
+    assert "agg_device_ms_per_query" in cells["join_sf1_solo"]
+    assert "agg_device_ms_per_query" not in cells["scan_sf10_solo"]
     for name in ("scan_sf10_solo", "join_sf1_solo"):
         assert {"frontend_ms", "device_launches_per_query",
                 "dispatch_ms_per_query",
@@ -119,18 +127,52 @@ def test_counter_readers_concurrent_path_reads_the_total_over_n():
     assert _read("device_wait_ms_per_query", ctx) == pytest.approx(2000.0)
 
 
-def test_join_device_time_is_the_join_familys_programs():
+def test_agg_device_time_is_the_agg_familys_programs():
     trace = {"busy_s": 4.9, "window_s": 5.0, "programs": [
-        ["jit_join_probe(11)", 2.0], ["jit_join_build(12)", 1.0],
-        ["jit_partfilter(13)", 0.5], ["jit_fused_batch(14)", 0.9],
+        ["jit_fused_batch(14)", 3.0], ["jit_agg_final(11)", 0.3],
+        ["jit_agg_partial(12)", 0.25], ["jit_partfilter(13)", 0.5],
+        ["jit_gagg_final(17)", 0.05], ["jit_topn_local(18)", 0.1],
         ["jit_gather(15)", 0.2], ["jit__unknown(16)", 0.1]]}
     ctx = _ctx([_sample()], trace=trace, shares=(0.1, 0.15))
-    assert _read("join_device_ms_per_query", ctx) == \
-        pytest.approx(3500.0 / 0.25)
-    assert _read("join_device_ms_per_query",
+    assert _read("agg_device_ms_per_query", ctx) == \
+        pytest.approx(600.0 / 0.25)
+    assert _read("agg_device_ms_per_query",
                  _ctx([_sample()], trace=None, shares=(1.0,))) is None
-    assert _read("join_device_ms_per_query",
+    assert _read("agg_device_ms_per_query",
                  _ctx([_sample()], trace=trace, shares=())) is None
+    # a stretch in which no program of the family ran: nothing, not 0
+    scan_only = dict(trace, programs=trace["programs"][:1])
+    assert _read("agg_device_ms_per_query",
+                 _ctx([_sample()], trace=scan_only, shares=(1.0,))) is None
+
+
+def test_a_program_ranked_below_the_tenth_still_counts(monkeypatch):
+    """The reduction hands the readers every program of the stretch, not
+    the ten with most time (the retired ``join_device_ms_per_query`` saw
+    those only): aggregation programs that rank twelfth and thirteenth
+    are read, while the breakdown's lists stay at ten."""
+    from benchmarks.harness import trace as tracing
+
+    modules, t = [], 0.0
+    for i in range(11):             # eleven scan variants, 0.10-0.20 s
+        modules.append((f"jit_fused_batch({i})", t, t + 0.1 + i / 100))
+        t += 0.25
+    modules += [("jit_agg_final(99)", t, t + 0.002),
+                ("jit_agg_partial(98)", t + 0.01, t + 0.011)]
+    ops = [(f"%fusion.{i}", a, b) for i, (_n, a, b) in enumerate(modules)]
+    monkeypatch.setattr(
+        tracing, "read_planes",
+        lambda path: {0: {tracing.OPS_LINE: ops,
+                          tracing.MODULES_LINE: modules}})
+    red = tracing.reduce("recorded.xplane.pb", 0.0, 3.0)
+    assert len(red["programs"]) == 13 > tracing.TOP_N
+    seconds = [s for _n, s in red["programs"]]
+    assert seconds == sorted(seconds, reverse=True)
+    assert [n for n, _s in red["programs"][-2:]] == [
+        "jit_agg_final(99)", "jit_agg_partial(98)"]
+    assert len(red["device_ops"]) == tracing.TOP_N
+    ctx = _ctx([_sample()], trace=red, shares=(1.0, 0.5))
+    assert _read("agg_device_ms_per_query", ctx) == pytest.approx(2.0)
 
 
 def test_setup_split_reads_the_totals_at_the_windows_start():
@@ -158,7 +200,4 @@ def test_a_program_without_the_span_or_counter_gives_nothing(
                concurrent=concurrent, start=dict(older), end=dict(older),
                trace=trace, shares=(0.16,))
     value = _read(name, ctx)
-    if name == "join_device_ms_per_query":
-        assert value == 0.0  # the registry is here; its names are not
-    else:
-        assert value is None
+    assert value is None
