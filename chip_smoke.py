@@ -499,7 +499,10 @@ def four_chips(etc_dir: str) -> None:
     plan = mesh_runner.plan(QUERIES[3])
     node = next(repartitions(plan))
     page = next(ex.pages(node.source))
-    hlo = ex._repartition_fn(node.keys).lower(page).compile().as_text()
+    ex._repartition_fn(node.keys)  # made, and kept under its label
+    (program,) = [prog for key, prog in ex._jit_cache.items()
+                  if key[0] == "d_repartition"]
+    hlo = program.jitted.lower(page).compile().as_text()
     n_a2a = hlo.count("all-to-all")
     emit(phase="mesh_sf1", repartition_program="q3",
          all_to_all_ops=n_a2a, page_rows=page.capacity)

@@ -32,8 +32,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 from presto_tpu import types as T
 from presto_tpu.exec import agg_states as S
 from presto_tpu.exec import plan as P
+from presto_tpu.exec import programs as PG
 from presto_tpu.exec import xfer as XF
 from presto_tpu.exec.executor import (
+    AggSizing,
     Executor,
     _final_agg_page,
     _final_global_agg,
@@ -43,10 +45,12 @@ from presto_tpu.exec.executor import (
     _partial_global_agg,
     _probe_join_page,
     _semi_join_page,
+    _topn_merge,
 )
 from presto_tpu.ops import hashing as H
 from presto_tpu.ops import keys as K
 from presto_tpu.ops.compact import compact_indices, concat_all, scatter_column
+from presto_tpu.ops.sort import sort_page
 from presto_tpu.page import Block, Page
 
 SHARDED = "sharded"
@@ -101,23 +105,23 @@ class DistExecutor(Executor):
         return super()._budget() * self.D
 
     # -------------------------------------------- collective dispatch
-    def _fenced(self, fn):
+    @staticmethod
+    def _fenced(fn):
         """Serialize collective programs on the CPU backend.
 
         The in-process CPU runtime schedules enqueued executables by
         DATAFLOW READINESS, not dispatch order: two in-flight programs
-        that both contain cross-device collectives can start in
-        different orders on different virtual devices — device 0 enters
-        program B's all-reduce rendezvous while devices 1..7 wait in
-        program A's, and the rendezvous aborts after its timeout
-        (MULTICHIP_r05 rc=134: TPC-DS Q17's windowed generated-join
-        `psum` interleaved with the dim-join pipeline's gathers,
-        "Expected 8 threads to join the rendezvous, but only 1
-        arrived"). Blocking on each collective program's outputs before
-        the next one can be dispatched enforces ONE consistent
-        execution order across all devices. TPU per-device queues
-        execute strictly in dispatch order, so the fence is CPU-only
-        and costs hardware nothing — the deferred-sync discipline
+        that both contain cross-device collectives can start in different
+        orders on different virtual devices — device 0 enters program B's
+        all-reduce rendezvous while devices 1..7 wait in program A's, and
+        the rendezvous aborts after its timeout (MULTICHIP_r05 rc=134:
+        TPC-DS Q17's windowed generated-join `psum` interleaved with the
+        dim-join pipeline's gathers, "Expected 8 threads to join the
+        rendezvous, but only 1 arrived"). Blocking on each collective
+        program's outputs before the next one can be dispatched enforces
+        ONE consistent execution order across all devices. TPU per-device
+        queues execute strictly in dispatch order, so the fence is
+        CPU-only and costs hardware nothing — the deferred-sync discipline
         (Executor.__init__) is a TPU-runtime concern and unaffected."""
         if jax.default_backend() != "cpu":
             return fn
@@ -129,6 +133,20 @@ class DistExecutor(Executor):
             return out
 
         return fenced
+
+    def _mesh_jit(self, key, body, in_specs=(PS("d"),),
+                  out_specs=PS("d"), fenced=False):
+        """THE one place a mesh program is made: ``body`` under
+        shard_map over this mesh is the function Executor._jit jits
+        under the key's ``d_*`` label, so every call goes through
+        exec/programs.launch like a one-device program's (counted,
+        timed and annotated on the calling executor). The default
+        specs are a shard-local page -> page map; ``fenced`` marks a
+        body that holds a cross-device collective (see _fenced)."""
+        fn = self._jit(key, make=lambda: jax.shard_map(
+            body, mesh=self.mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=False))
+        return self._fenced(fn) if fenced else fn
 
     # ---------------------------------------------------------- dist tags
     def dist(self, node: P.PhysicalNode) -> str:
@@ -215,7 +233,7 @@ class DistExecutor(Executor):
         if isinstance(node, P.Filter):
             from presto_tpu.expr.eval import evaluate_filter
 
-            fn = self._shard_page_kernel(
+            fn = self._mesh_jit(
                 ("d_filter", node.predicate),
                 lambda page, _pred=node.predicate: evaluate_filter(
                     _pred, page, jnp
@@ -227,7 +245,7 @@ class DistExecutor(Executor):
         if isinstance(node, P.Project):
             from presto_tpu.exec.executor import _project_page
 
-            fn = self._shard_page_kernel(
+            fn = self._mesh_jit(
                 ("d_project", node.exprs),
                 functools.partial(_project_page, node.exprs),
             )
@@ -236,6 +254,9 @@ class DistExecutor(Executor):
             return
         if isinstance(node, P.Aggregation):
             yield from self._dist_aggregation(node)
+            return
+        if isinstance(node, P.TopN):
+            yield from self._dist_topn(node)
             return
         if isinstance(node, P.HashJoin):
             yield from self._dist_join(node)
@@ -251,7 +272,7 @@ class DistExecutor(Executor):
 
             for page in self.pages(node.source):
                 dic = page.block(node.array_channel).dictionary
-                fn = self._shard_page_kernel(
+                fn = self._mesh_jit(
                     ("d_unnest", node.array_channel, node.element_type,
                      node.with_ordinality, dic),
                     functools.partial(
@@ -265,7 +286,7 @@ class DistExecutor(Executor):
             from presto_tpu.exec.executor import _group_id_page
 
             fns = [
-                self._shard_page_kernel(
+                self._mesh_jit(
                     ("d_groupid", node.key_channels, mask, si),
                     functools.partial(_group_id_page,
                                       node.key_channels, mask, si),
@@ -302,16 +323,6 @@ class DistExecutor(Executor):
             )
         )
 
-    def _shard_page_kernel(self, key, fn):
-        """shard_map-wrap a pure page->page kernel (shard-local map)."""
-        if key not in self._jit_cache:
-            body = jax.shard_map(
-                fn, mesh=self.mesh, in_specs=(PS("d"),),
-                out_specs=PS("d"), check_vma=False,
-            )
-            self._jit_cache[key] = jax.jit(body)
-        return self._jit_cache[key]
-
     # -------------------------------------------------------------- scan
     def _scan_sharded(self, node: P.TableScan) -> Iterator[Page]:
         conn = self.catalogs[node.catalog]
@@ -336,13 +347,8 @@ class DistExecutor(Executor):
             ) < jnp.int64(total)
             return datas, valid & in_range
 
-        key = ("d_scan", node.catalog, node.table, names, n)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(jax.shard_map(
-                gen_local, mesh=self.mesh,
-                in_specs=(PS("d"),), out_specs=PS("d"), check_vma=False,
-            ))
-        fn = self._jit_cache[key]
+        fn = self._mesh_jit(
+            ("d_scan", node.catalog, node.table, names, n), gen_local)
 
         starts = [s.start_row for s in splits]
         spec = NamedSharding(self.mesh, PS("d"))
@@ -417,20 +423,15 @@ class DistExecutor(Executor):
         raise ValueError(f"unknown exchange kind {node.kind!r}")
 
     def _gather_fn(self):
-        key = ("d_gather",)
-        if key not in self._jit_cache:
-            def body(page):
-                return jax.tree.map(
-                    lambda x: jax.lax.all_gather(x, "d", tiled=True), page
-                )
+        def body(page):
+            return jax.tree.map(
+                lambda x: jax.lax.all_gather(x, "d", tiled=True), page
+            )
 
-            # check_vma=False: all_gather(tiled) output IS replicated but
-            # jax's varying-axis inference cannot prove it
-            self._jit_cache[key] = self._fenced(jax.jit(jax.shard_map(
-                body, mesh=self.mesh, in_specs=(PS("d"),), out_specs=PS(),
-                check_vma=False,
-            )))
-        return self._jit_cache[key]
+        # (check_vma=False in _mesh_jit: all_gather(tiled) output IS
+        # replicated but jax's varying-axis inference cannot prove it)
+        return self._mesh_jit(("d_gather",), body, out_specs=PS(),
+                              fenced=True)
 
     def _key_hash(self, page: Page, keys: Tuple[int, ...]) -> jnp.ndarray:
         blocks = [page.block(c) for c in keys]
@@ -508,13 +509,9 @@ class DistExecutor(Executor):
                 (num > out_cap).astype(jnp.int32), "d") > 0
             return out, overflow
 
-        key = ("d_repart", keys, self.D, P, boost)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = self._fenced(jax.jit(jax.shard_map(
-                body, mesh=self.mesh, in_specs=(PS("d"),),
-                out_specs=(PS("d"), PS()), check_vma=False,
-            )))
-        return self._jit_cache[key]
+        return self._mesh_jit(
+            ("d_repartition", keys, self.D, P, boost), body,
+            out_specs=(PS("d"), PS()), fenced=True)
 
     def _residue_fn(self, keys: Tuple[int, ...]):
         """Replicated -> sharded: device i keeps rows with
@@ -528,13 +525,9 @@ class DistExecutor(Executor):
             out = page.with_valid(page.valid & mine)
             return out, jnp.asarray(False)
 
-        key = ("d_residue", keys, self.D, P)
-        if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(jax.shard_map(
-                body, mesh=self.mesh, in_specs=(PS(),),
-                out_specs=(PS("d"), PS()), check_vma=False,
-            ))
-        return self._jit_cache[key]
+        return self._mesh_jit(
+            ("d_residue", keys, self.D, P), body, in_specs=(PS(),),
+            out_specs=(PS("d"), PS()))
 
     # ------------------------------------------------------- aggregation
     def _dist_aggregation(self, node: P.Aggregation) -> Iterator[Page]:
@@ -546,7 +539,7 @@ class DistExecutor(Executor):
                 for s, t in zip(node.aggregates, in_types)
             )
             if not node.group_channels:
-                fn = self._shard_page_kernel(
+                fn = self._mesh_jit(
                     ("d_gagg_partial", node.aggregates, layouts),
                     functools.partial(
                         _partial_global_agg, node.aggregates, layouts
@@ -555,11 +548,17 @@ class DistExecutor(Executor):
                 for page in self.pages(node.source):
                     yield fn(page)
                 return
-            cap = _next_pow2(node.capacity * self._capacity_boost)
+            # every chip may see every group: the rule's cap as it is
+            cap = self._mesh_agg_cap(node, sharded_state=False)
             max_iters = 64 * self._capacity_boost
 
-            def make(local_cap):
-                def body(page):
+            for page in self.pages(node.source):
+                # distinct groups <= rows: clip to the chip's rows
+                local_cap = min(
+                    cap, _next_pow2(page.capacity // self.D)
+                )
+
+                def body(page, local_cap=local_cap):
                     out, ovf = _partial_agg_page(
                         node.group_channels, node.aggregates, layouts,
                         page, local_cap, max_iters,
@@ -567,22 +566,13 @@ class DistExecutor(Executor):
                     return out, jax.lax.psum(
                         ovf.astype(jnp.int32), "d") > 0
 
-                return self._fenced(jax.jit(jax.shard_map(
-                    body, mesh=self.mesh, in_specs=(PS("d"),),
-                    out_specs=(PS("d"), PS()), check_vma=False,
-            )))
-
-            for page in self.pages(node.source):
-                local_cap = min(
-                    cap, _next_pow2(page.capacity // self.D)
-                )
                 # canonical: the estimate-bearing node stays OUT of the
                 # key (exec/shapes.py discipline — content only)
-                key = ("d_agg_partial", node.group_channels,
-                       node.aggregates, layouts, local_cap, max_iters)
-                if key not in self._jit_cache:
-                    self._jit_cache[key] = make(local_cap)
-                out, overflow = self._jit_cache[key](page)
+                fn = self._mesh_jit(
+                    ("d_agg_partial", node.group_channels,
+                     node.aggregates, layouts, local_cap, max_iters),
+                    body, out_specs=(PS("d"), PS()), fenced=True)
+                out, overflow = fn(page)
                 self._pending_overflow.append(overflow)
                 yield out
             return
@@ -599,8 +589,9 @@ class DistExecutor(Executor):
             if not pages:
                 return
             local_caps = tuple(p.capacity // self.D for p in pages)
+            # keys are co-located: a chip holds its 1/D of the groups
             fcap = min(
-                _next_pow2(node.capacity * self._capacity_boost),
+                self._mesh_agg_cap(origin, sharded_state=True),
                 _next_pow2(sum(local_caps)),
             )
             max_iters = 64 * self._capacity_boost
@@ -613,21 +604,61 @@ class DistExecutor(Executor):
                 )
                 return out, jax.lax.psum(ovf.astype(jnp.int32), "d") > 0
 
-            key = ("d_agg_final", node.group_channels, node.aggregates,
-                   layouts, tuple(in_types), local_caps, fcap,
-                   max_iters)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = self._fenced(jax.jit(jax.shard_map(
-                    body, mesh=self.mesh,
-                    in_specs=tuple(PS("d") for _ in pages),
-                    out_specs=(PS("d"), PS()), check_vma=False,
-            )))
-            out, overflow = self._jit_cache[key](*pages)
+            fn = self._mesh_jit(
+                ("d_agg_final", node.group_channels, node.aggregates,
+                 layouts, tuple(in_types), local_caps, fcap, max_iters),
+                body, in_specs=tuple(PS("d") for _ in pages),
+                out_specs=(PS("d"), PS()), fenced=True)
+            out, overflow = fn(*pages)
             self._pending_overflow.append(overflow)
             yield out
             return
         # replicated input: inherited single-stream paths
         yield from super()._exec_aggregation(node)
+
+    def _mesh_agg_cap(self, node: P.Aggregation,
+                      sharded_state: bool) -> int:
+        """Group capacity of one mesh aggregation step, from THE one
+        sizing rule (Executor._agg_sizing: the first attempt sized by
+        the rows it expects, a boosted retry from the planner's
+        boost-scaled bounds; the psum'd overflow flag re-enters the
+        ladder). Where the state is sharded by group key each chip
+        holds 1/D of the groups, so 1/D of the rule's capacity. The
+        decision is recorded for the attempt span as one chip records
+        it: one pass, no compaction buffer."""
+        sizing = self._agg_sizing(node)
+        cap = max(sizing.cap // self.D, 8) if sharded_state \
+            else sizing.cap
+        self._agg_sizings.append(
+            AggSizing(cap, 0, 1, sizing.sized_by))
+        return cap
+
+    # -------------------------------------------------------------- top-N
+    def _dist_topn(self, node: P.TopN) -> Iterator[Page]:
+        """Each chip's own top `limit` of a SHARDED stream (reference:
+        AddExchanges.visitTopN puts a TopNNode.Step.PARTIAL under the
+        gathering exchange): the one-device streaming top-N — per page
+        sort_page(limit), then the running _topn_merge — shard-local,
+        so the gather above carries D x limit rows instead of the
+        source's state and the replicated TopN above it finishes."""
+        running = None
+        for page in self.pages(node.source):
+            local = self._mesh_jit(
+                ("d_topn_local", node.keys, node.limit, page.capacity),
+                functools.partial(sort_page, sort_keys=node.keys,
+                                  limit=node.limit),
+            )(page)
+            if running is None:
+                running = local
+                continue
+            running = self._mesh_jit(
+                ("d_topn_merge", node.keys, node.limit,
+                 running.capacity, local.capacity),
+                functools.partial(_topn_merge, node.keys, node.limit),
+                in_specs=(PS("d"), PS("d")),
+            )(running, local)
+        if running is not None:
+            yield running
 
     # -------------------------------------------------------------- join
     def _dist_join(self, node: P.HashJoin) -> Iterator[Page]:
@@ -651,30 +682,23 @@ class DistExecutor(Executor):
         kern, windowed = self.generated_join_kernel(node, info)
         spec = PS("d") if dl == SHARDED else PS()
         if not windowed:
-            key = ("d_genjoin", node, dl)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = jax.jit(jax.shard_map(
-                    kern, mesh=self.mesh, in_specs=(spec,),
-                    out_specs=spec, check_vma=False,
-                ))
+            fn = self._mesh_jit(("d_genjoin", node, dl), kern,
+                                in_specs=(spec,), out_specs=spec)
             for page in self.pages(node.left):
-                yield self._jit_cache[key](page)
+                yield fn(page)
             return
 
         def win_body(page):
             out, multi = kern(page)
             return out, jax.lax.psum(multi.astype(jnp.int32), "d") > 0
 
-        key = ("d_genjoin_win", node, dl)
-        if key not in self._jit_cache:
-            # fenced: the windowed multi-match psum is THE collective
-            # whose free interleaving deadlocked MULTICHIP_r05 (Q17)
-            self._jit_cache[key] = self._fenced(jax.jit(jax.shard_map(
-                win_body, mesh=self.mesh, in_specs=(spec,),
-                out_specs=(spec, PS()), check_vma=False,
-            )))
+        # fenced: the windowed multi-match psum is THE collective
+        # whose free interleaving deadlocked MULTICHIP_r05 (Q17)
+        fn = self._mesh_jit(("d_genjoin_win", node, dl), win_body,
+                            in_specs=(spec,), out_specs=(spec, PS()),
+                            fenced=True)
         for page in self.pages(node.left):
-            out, multi = self._jit_cache[key](page)
+            out, multi = fn(page)
             self._pending_overflow.append(multi)
             yield out
 
@@ -701,15 +725,11 @@ class DistExecutor(Executor):
                     node.left_keys, node.right_keys, page, build
                 )
 
-            key = ("d_semi", node, build_all.capacity)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = jax.jit(jax.shard_map(
-                    semi_body, mesh=self.mesh,
-                    in_specs=(probe_spec, build_spec),
-                    out_specs=PS("d") if dl == SHARDED else PS(), check_vma=False,
-            ))
+            fn = self._mesh_jit(
+                ("d_semi", node, build_all.capacity), semi_body,
+                in_specs=(probe_spec, build_spec), out_specs=probe_spec)
             for page in self.pages(node.left):
-                yield self._jit_cache[key](page, build_all)
+                yield fn(page, build_all)
             return
 
         local_build_cap = (
@@ -746,19 +766,12 @@ class DistExecutor(Executor):
                         matched.astype(jnp.int32), "d") > 0
                 return out, matched, ovf
 
-            key = ("d_probe", node, page.capacity, build_all.capacity,
-                   oc, dl, dr)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = self._fenced(jax.jit(jax.shard_map(
-                    probe_body, mesh=self.mesh,
-                    in_specs=(probe_spec, build_spec),
-                    out_specs=(
-                        PS("d"),
-                        PS() if dr == REPLICATED else PS("d"),
-                        PS(),
-                    ), check_vma=False,
-            )))
-            out, matched, overflow = self._jit_cache[key](page, build_all)
+            fn = self._mesh_jit(
+                ("d_probe", node, page.capacity, build_all.capacity,
+                 oc, dl, dr), probe_body,
+                in_specs=(probe_spec, build_spec),
+                out_specs=(PS("d"), build_spec, PS()), fenced=True)
+            out, matched, overflow = fn(page, build_all)
             self._pending_overflow.append(overflow)
             matched_acc = (
                 matched if matched_acc is None else matched_acc | matched
@@ -786,14 +799,10 @@ class DistExecutor(Executor):
                 blocks=tuple(nulls) + build.blocks, valid=unmatched
             )
 
-        key = ("d_outer", node, build_all.capacity, dr)
-        if key not in self._jit_cache:
-            bspec = PS() if dr == REPLICATED else PS("d")
-            self._jit_cache[key] = jax.jit(jax.shard_map(
-                body, mesh=self.mesh, in_specs=(bspec, bspec),
-                out_specs=PS("d"), check_vma=False,
-            ))
-        return self._jit_cache[key](build_all, matched)
+        bspec = PS() if dr == REPLICATED else PS("d")
+        fn = self._mesh_jit(("d_outer", node, build_all.capacity, dr),
+                            body, in_specs=(bspec, bspec))
+        return fn(build_all, matched)
 
     def _dist_cross_join(self, node: P.CrossJoin) -> Iterator[Page]:
         from presto_tpu.exec.executor import _cross_join_page, compact_page
@@ -810,17 +819,11 @@ class DistExecutor(Executor):
         self._pending_overflow.append(build_all.num_rows() > bcap)
         build = compact_page(build_all, bcap)
 
-        def body(pg, b):
-            return _cross_join_page(pg, b)
-
         for page in self.pages(node.left):
-            key = ("d_cross", node, page.capacity, bcap)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = jax.jit(jax.shard_map(
-                    body, mesh=self.mesh, in_specs=(PS("d"), PS()),
-                    out_specs=PS("d"), check_vma=False,
-            ))
-            yield self._jit_cache[key](page, build)
+            fn = self._mesh_jit(
+                ("d_cross", node, page.capacity, bcap),
+                _cross_join_page, in_specs=(PS("d"), PS()))
+            yield fn(page, build)
 
     def _dist_unique_id(self, node: P.UniqueId) -> Iterator[Page]:
         # globally-unique bigint per row: device index in the high bits
@@ -837,13 +840,9 @@ class DistExecutor(Executor):
             return Page(blocks=page.blocks + (blk,), valid=page.valid)
 
         for page in self.pages(node.source):
-            key = ("d_uid", node, page.capacity)
-            if key not in self._jit_cache:
-                self._jit_cache[key] = jax.jit(jax.shard_map(
-                    body, mesh=self.mesh, in_specs=(PS("d"), PS()),
-                    out_specs=PS("d"), check_vma=False,
-            ))
-            yield self._jit_cache[key](page, jnp.int64(offset))
+            fn = self._mesh_jit(("d_uid", node, page.capacity), body,
+                                in_specs=(PS("d"), PS()))
+            yield fn(page, jnp.int64(offset))
             offset += page.capacity
 
 
@@ -962,8 +961,8 @@ def _ici_mesh(d: int) -> Mesh:
     return _ICI_MESHES[d]
 
 
-def _ici_program(ex, mesh: Mesh, keys: Tuple[int, ...], dicts,
-                 nluts: int, d: int, out_cap: int):
+def _ici_program(mesh: Mesh, keys: Tuple[int, ...], dicts,
+                 nluts: int, d: int, out_cap: int) -> PG.Program:
     """The per-page exchange collective: shard-local splitmix64
     routing + all_to_all + compaction to the ladder landing capacity.
     Mirrors DistExecutor._repartition_fn, with two deltas: the routing
@@ -1043,24 +1042,11 @@ def _ici_program(ex, mesh: Mesh, keys: Tuple[int, ...], dicts,
     key = (keys, d, out_cap,
            tuple(dct is not None for dct in dicts), nluts)
     if key not in _ICI_PROGRAMS:
-        fn = jax.jit(jax.shard_map(
+        _ICI_PROGRAMS[key] = PG.Program("d_ici_exchange", jax.shard_map(
             body, mesh=mesh,
             in_specs=(PS("d"),) + (PS(),) * nluts,
             out_specs=(PS("d"), PS()), check_vma=False,
         ))
-        if jax.default_backend() == "cpu":
-            # the CPU collective-rendezvous fence, same reasoning as
-            # DistExecutor._fenced (dataflow-readiness scheduling can
-            # interleave two in-flight collectives)
-            inner = fn
-
-            def fn(*args):
-                out = inner(*args)
-                # xfercheck: raw-ok - sync fence (no copy): pins
-                jax.block_until_ready(out)  # rendezvous order on CPU
-                return out
-
-        _ICI_PROGRAMS[key] = fn
     return _ICI_PROGRAMS[key]
 
 
@@ -1118,11 +1104,14 @@ def ici_exchange_pages(ex, pages, keys: Tuple[int, ...], nparts: int):
         for pg, dicts, luts in staged:
             out_cap = SH.exchange_partition_cap(
                 pg.capacity, nparts, boost)
-            fn = _ici_program(ex, mesh, keys, dicts,
-                              sum(1 for v in luts if v is not None),
-                              d, out_cap)
-            out, overflow = fn(pg, *[v for v in luts
-                                     if v is not None])
+            prog = _ici_program(mesh, keys, dicts,
+                                sum(1 for v in luts if v is not None),
+                                d, out_cap)
+            # called through THE launch point and counted on the
+            # calling executor, like every program _jit makes
+            run = DistExecutor._fenced(
+                functools.partial(PG.launch, ex, prog))
+            out, overflow = run(pg, *[v for v in luts if v is not None])
             outs.append((out, out_cap))
             if bool(overflow):
                 overflowed = True

@@ -204,7 +204,17 @@ def add_exchanges(
             if dr == SHARDED:
                 right = _gather(right)
             return P.CrossJoin(left, right), dl
-        if isinstance(n, (P.Sort, P.TopN, P.Limit, P.Output, P.Window,
+        if isinstance(n, P.TopN):
+            # each chip keeps its own top `limit` below the gather
+            # (reference: AddExchanges.visitTopN puts a
+            # TopNNode.Step.PARTIAL under the gathering exchange): a
+            # chip ships `limit` rows, not its source's state; the
+            # replicated TopN above the gather finishes
+            src, d = rewrite(n.source)
+            if d == SHARDED:
+                src = _gather(dataclasses.replace(n, source=src))
+            return dataclasses.replace(n, source=src), REPLICATED
+        if isinstance(n, (P.Sort, P.Limit, P.Output, P.Window,
                           P.MarkDistinct)):
             # MarkDistinct needs a global view of each key set (first-
             # occurrence marks are meaningless per shard) — conservative

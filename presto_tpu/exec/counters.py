@@ -50,6 +50,11 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "attempt, counted at the one launch point "
         "(exec/programs.launch) on the calling executor; "
         "program_launches is the fused-scan part of it"),
+    "exchange_launches": (
+        "gauge", "the part of device_launches whose program moves "
+        "rows between chips this attempt (family exchange in "
+        "exec/programs.PROGRAM_LABELS: all_to_all repartition, "
+        "all_gather, residue split); 0 on one device"),
     "dispatch_wall_us": (
         "gauge", "host microseconds inside those calls this attempt: "
         "trace-cache lookup, argument handling, enqueue (and a "

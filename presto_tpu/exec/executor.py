@@ -444,8 +444,11 @@ class Executor:
         # device_launches = calls, dispatch_wall_us = host time inside
         # them, device_wait_us = host time blocked on the device
         # (exec/xfer.py pulls, devsync.drain, the overflow-flag read);
-        # _launches_by_label feeds the attempt span while tracing
+        # _launches_by_label feeds the attempt span while tracing;
+        # exchange_launches = the calls among them whose program moves
+        # rows between chips (family "exchange", over a mesh)
         self.device_launches = 0
+        self.exchange_launches = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
@@ -1039,6 +1042,8 @@ class Executor:
         self.dispatch_wall_us += (wall_ns + 500) // 1000
         if prog.fused_scan:
             self.program_launches += 1
+        if prog.exchange:
+            self.exchange_launches += 1
         if prog.donates:
             self.buffers_donated += 1
         if self.trace is not None:
@@ -2240,6 +2245,7 @@ class Executor:
                     self._trace_operators(tr, att_span)
                     tr.end(att_span, outcome="ok", rows=len(rows),
                            launches=dict(self._launches_by_label),
+                           exchange_launches=self.exchange_launches,
                            **self._agg_sizing_attrs())
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
@@ -2292,6 +2298,7 @@ class Executor:
         self.fused_partial_aggs = 0
         self.program_launches = 0
         self.device_launches = 0
+        self.exchange_launches = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label = {}

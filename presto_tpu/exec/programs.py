@@ -1,8 +1,9 @@
 """THE device-program registry and launch point.
 
-Every XLA program the local executor makes is jitted under the LABEL
-its canonical key begins with (``("filter", ...)``, ``("agg_partial",
-...)``), so a device trace's ``XLA Modules`` line shows
+Every XLA program the executor makes, on one device or over a mesh
+(``d_*``: the ``shard_map`` programs of dist/executor.py), is jitted
+under the LABEL its canonical key begins with (``("filter", ...)``,
+``("agg_partial", ...)``), so a device trace's ``XLA Modules`` line shows
 ``jit_<label>(<fingerprint>)`` — the only handle on device time by
 engine operator: ``XLA Ops`` events carry no framework op name, so a
 ``jax.named_scope`` inside the program never reaches the trace.
@@ -32,7 +33,7 @@ from presto_tpu.obs.trace import annotation
 # operator families a label belongs to (benchmarks/layer_metrics reads
 # device time by family off the trace's program names)
 FAMILIES = ("scan", "filter_project", "join", "agg", "sort_topn",
-            "window", "other")
+            "window", "exchange", "other")
 
 # label (first element of the program's canonical key) -> family
 PROGRAM_LABELS: Dict[str, str] = {
@@ -75,6 +76,31 @@ PROGRAM_LABELS: Dict[str, str] = {
     "topn_merge": "sort_topn",
     "window": "window",
     "dev_repart": "other",
+    # over a mesh (dist/executor.py): shard-local operators fall into
+    # the families they have on one device; the programs that move
+    # rows between chips (all_to_all, all_gather, the residue split of
+    # a replicated page) are the family "exchange"
+    "d_scan": "scan",
+    "d_filter": "filter_project",
+    "d_project": "filter_project",
+    "d_unnest": "filter_project",
+    "d_groupid": "filter_project",
+    "d_uid": "filter_project",
+    "d_genjoin": "join",
+    "d_genjoin_win": "join",
+    "d_semi": "join",
+    "d_probe": "join",
+    "d_outer": "join",
+    "d_cross": "join",
+    "d_agg_partial": "agg",
+    "d_agg_final": "agg",
+    "d_gagg_partial": "agg",
+    "d_topn_local": "sort_topn",
+    "d_topn_merge": "sort_topn",
+    "d_repartition": "exchange",
+    "d_residue": "exchange",
+    "d_gather": "exchange",
+    "d_ici_exchange": "exchange",
 }
 
 
@@ -92,7 +118,7 @@ def family_of(program_name: str) -> Optional[str]:
     """The family of a program as a device trace names it,
     ``jit_<label>(<fingerprint>)`` or ``jit_<label>``; None for a
     program this registry did not name (an eager ``jnp`` call's
-    ``jit_gather``, a mesh program)."""
+    ``jit_gather``)."""
     name = program_name.split("(", 1)[0]
     if not name.startswith("jit_"):
         return None
@@ -103,7 +129,8 @@ class Program:
     """One jitted program under its label. Holds no executor: the jit
     cache that keeps it may be shared between executors."""
 
-    __slots__ = ("label", "note", "jitted", "donates", "fused_scan")
+    __slots__ = ("label", "note", "jitted", "donates", "fused_scan",
+                 "exchange")
 
     def __init__(self, label: str, fn, donates: bool = False,
                  **jit_kwargs):
@@ -120,6 +147,8 @@ class Program:
         self.donates = donates
         # the launches program_launches counts (split-batched scans)
         self.fused_scan = label in FUSED_SCAN_LABELS
+        # the launches exchange_launches counts (rows change chips)
+        self.exchange = PROGRAM_LABELS.get(label) == "exchange"
 
 
 def launch(sink, program: Program, *args, **kwargs):

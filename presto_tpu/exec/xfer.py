@@ -232,10 +232,6 @@ TRANSFER_REGISTRY: Dict[str, Tuple[str, str, str]] = {
         "d2h", "data",
         "CPU-only collective fence: blocks on program outputs to "
         "serialize rendezvous order — a sync, not a copy"),
-    "dist.executor._ici_program": (
-        "d2h", "data",
-        "ICI exchange collective's CPU-only rendezvous fence (ISSUE "
-        "18), same sync-not-copy shape as DistExecutor._fenced"),
     "dist.executor.ici_exchange_pages": (
         "h2d", "data",
         "ICI exchange staging: spooled producer pages commit onto "

@@ -268,8 +268,9 @@ def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
     assert d == 4
     rows = d * (1 << 18)
     out_cap = SH.exchange_partition_cap(rows, d, 1)
-    program = DX._ici_program(None, mesh, (0,), (None,), 0, d, out_cap)
-    compiled = program.lower(
+    program = DX._ici_program(mesh, (0,), (None,), 0, d, out_cap)
+    assert program.label == "d_ici_exchange" and program.exchange
+    compiled = program.jitted.lower(
         _bigint_page(rows, NamedSharding(mesh, PS("d")))).compile()
     assert "all-to-all" in compiled.as_text()
     # each device holds its shard, not the whole page

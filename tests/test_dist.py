@@ -113,6 +113,62 @@ def test_fragmenter_inserts_expected_exchanges(dist_repart):
     assert "step=partial" in txt and "step=final" in txt
 
 
+def _topn_stages(node, under_gather=False, out=None):
+    """(source is sharded below a gather, node) of every TopN."""
+    out = [] if out is None else out
+    if isinstance(node, P.TopN):
+        out.append((under_gather, node))
+    for child in node.children():
+        _topn_stages(
+            child, isinstance(node, P.Exchange) and node.kind == "gather",
+            out)
+    return out
+
+
+@pytest.mark.parametrize("sql,stages", [
+    # sharded source (the final aggregation over repartitioned state):
+    # a top-N on every chip below the gather, the final one above it
+    (3, 2),
+    ("select l_orderkey, l_extendedprice from lineitem "
+     "order by l_extendedprice desc, l_orderkey limit 5", 2),
+    # replicated source (inline rows): left alone, no gather
+    ("values", 1),
+], ids=["q3_final_agg", "scan", "replicated_values"])
+def test_fragmenter_puts_topn_under_the_gather(sql, stages, dist_repart):
+    from presto_tpu import types as T
+    from presto_tpu.ops.sort import SortKey
+    from tests.test_sql_tpch import ENGINE_SQL
+
+    if sql == "values":
+        plan, out_dist = add_exchanges(P.TopN(
+            P.Values((T.BIGINT,), ((3,), (1,), (2,))),
+            (SortKey(0),), 2), dist_repart.catalogs)
+        assert out_dist == "replicated"
+    else:
+        plan = dist_repart.plan(ENGINE_SQL.get(sql, sql))
+    found = _topn_stages(plan)
+    assert len(found) == stages, explain_text(plan)
+    final = found[0][1]
+    assert not found[0][0]
+    if stages == 1:
+        assert "Exchange" not in explain_text(plan)
+        return
+    (under, partial), = found[1:]
+    assert under, "the per-chip top-N has to sit right under the gather"
+    assert isinstance(final.source, P.Exchange)
+    assert final.source.kind == "gather" and final.source.source is partial
+    assert (partial.keys, partial.limit) == (final.keys, final.limit)
+    ex = dist_repart.executor
+    assert ex.dist(partial) == "sharded" and ex.dist(final) == "replicated"
+    # Sort keeps its whole-input gather: no sort stage below it
+    sort_plan = dist_repart.plan(
+        "select l_orderkey from lineitem where l_orderkey < 40 "
+        "order by l_orderkey")
+    txt = explain_text(sort_plan)
+    assert txt.count("Sort[") == 1 and "TopN" not in txt
+    assert txt.index("Sort[") < txt.index("Exchange[gather]")
+
+
 def test_fragmenter_broadcast_small_build(dist):
     # nation/region builds are far below the broadcast threshold
     txt = explain_text(dist.plan(QUERIES[5]))

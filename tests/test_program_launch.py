@@ -21,18 +21,27 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SF = 0.01
 
 
+ENGINE_FILES = sorted(glob.glob(
+    os.path.join(REPO, "presto_tpu", "**", "*.py"), recursive=True))
+# the two ways a program is made: Executor._jit, and over a mesh
+# DistExecutor._mesh_jit, which hands its key on to _jit
+JIT_CALLS = ("_jit", "_mesh_jit")
+
+
 def _jit_key_labels():
-    """(file, line, label) of every ``<x>._jit(key, ...)`` call in the
-    engine whose key is a tuple literal beginning with a string, or a
-    name bound to one in the same function."""
+    """(file, line, label) of every ``<x>._jit(key, ...)`` and
+    ``<x>._mesh_jit(key, ...)`` call in the engine whose key is a tuple
+    literal beginning with a string, or a name bound to one in the same
+    function. A helper that hands its own ``key`` parameter on is no
+    site: its callers are."""
     out = []
-    for path in glob.glob(
-            os.path.join(REPO, "presto_tpu", "**", "*.py"), recursive=True):
+    for path in ENGINE_FILES:
         with open(path) as f:
             tree = ast.parse(f.read())
         for fn in ast.walk(tree):
             if not isinstance(fn, (ast.FunctionDef, ast.Lambda)):
                 continue
+            params = {a.arg for a in fn.args.args}
             bound = {
                 n.targets[0].id: n.value for n in ast.walk(fn)
                 if isinstance(n, ast.Assign) and len(n.targets) == 1
@@ -40,9 +49,12 @@ def _jit_key_labels():
             for call in ast.walk(fn):
                 if not (isinstance(call, ast.Call)
                         and isinstance(call.func, ast.Attribute)
-                        and call.func.attr == "_jit" and call.args):
+                        and call.func.attr in JIT_CALLS and call.args):
                     continue
                 key = call.args[0]
+                if isinstance(key, ast.Name) and key.id in params \
+                        and key.id not in bound:
+                    continue
                 if isinstance(key, ast.Name):
                     key = bound.get(key.id, key)
                 first = key.elts[0] if isinstance(key, ast.Tuple) else key
@@ -55,15 +67,56 @@ def _jit_key_labels():
     return out
 
 
+def _program_labels():
+    """Labels of the programs made without a jit cache of an executor:
+    ``Program("<label>", ...)`` (the connector's generator program, the
+    process-level ICI exchange program)."""
+    out = set()
+    for path in ENGINE_FILES:
+        if path.endswith(os.path.join("exec", "executor.py")):
+            continue  # _jit itself: the label comes from the key
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for call in ast.walk(tree):
+            if (isinstance(call, ast.Call)
+                    and getattr(call.func, "attr", None) == "Program"
+                    and call.args
+                    and isinstance(call.args[0], ast.Constant)):
+                out.add(call.args[0].value)
+    return out
+
+
+def test_jax_jit_is_called_in_one_place():
+    """exec/programs.Program is the only caller of jax.jit in the
+    engine, the mesh executor's shard_map programs included: a program
+    jitted anywhere else has no label, no family, no count."""
+    offenders = []
+    for path in ENGINE_FILES:
+        rel = os.path.relpath(path, REPO)
+        if rel == os.path.join("presto_tpu", "exec", "programs.py"):
+            continue
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                if "jax.jit(" in line.split("#", 1)[0]:
+                    offenders.append(f"{rel}:{n}")
+    assert not offenders, offenders
+
+
 def test_every_jit_key_label_is_declared():
     sites = _jit_key_labels()
     assert len({(f, n) for f, n, _ in sites}) >= 45
+    mesh_sites = {lab for f, _n, lab in sites
+                  if f == os.path.join("presto_tpu", "dist", "executor.py")}
+    assert len(mesh_sites) >= 18 and all(
+        lab.startswith("d_") for lab in mesh_sites), sorted(mesh_sites)
     undeclared = sorted({(f, n, lab) for f, n, lab in sites
                          if lab not in PG.PROGRAM_LABELS})
     assert not undeclared, (
         "a _jit key's label is missing from "
         f"exec/programs.PROGRAM_LABELS: {undeclared}")
-    used = {lab for _f, _n, lab in sites} | {"scan_gen"}
+    direct = _program_labels()
+    assert {"scan_gen", "d_ici_exchange"} <= direct
+    used = {lab for _f, _n, lab in sites} | direct
     assert set(PG.PROGRAM_LABELS) == used, (
         "stale labels", sorted(set(PG.PROGRAM_LABELS) - used))
     assert set(PG.PROGRAM_LABELS.values()) <= set(PG.FAMILIES)
@@ -78,6 +131,19 @@ def test_family_of_reads_a_trace_program_name():
     assert PG.family_of("jit__unknown(5456584955919556897)") is None
     assert PG.family_of("jit_sort(3)") is None
     assert PG.label_of(("agg_merge", 1, 2)) == "agg_merge"
+    # over a mesh: the programs that move rows between chips are a
+    # family of their own, the shard-local ones fall into their own
+    for label in ("d_repartition", "d_residue", "d_gather",
+                  "d_ici_exchange"):
+        assert PG.family_of(f"jit_{label}(5456584955919556897)") == \
+            "exchange", label
+    assert PG.family_of("jit_d_scan(1)") == "scan"
+    assert PG.family_of("jit_d_agg_final(1)") == "agg"
+    assert PG.family_of("jit_d_topn_local(1)") == "sort_topn"
+    assert PG.family_of("jit_d_genjoin(1)") == "join"
+    assert {lab: fam for lab, fam in PG.PROGRAM_LABELS.items()
+            if fam == "exchange"}.keys() == {
+        "d_repartition", "d_residue", "d_gather", "d_ici_exchange"}
     assert PG.label_of((("agg_merge", 1), "donate")) == "program"
 
 
@@ -269,19 +335,21 @@ def test_a_real_first_call_is_split_into_its_parts():
     assert parts <= wall + 0.005, (d, wall)
 
 
-def test_metrics_expose_what_the_benchmark_scrapes(traced_tpch):
+def _scraped(runner):
+    """/metrics of a server over ``runner``, as the benchmark reads it."""
     from benchmarks.harness.serve import _METRIC_LINE
     from presto_tpu.server.http_server import QueryManager
 
-    runner = LocalRunner({"tpch": TpchConnector(SF)}, page_rows=1 << 13)
-    runner.execute(QUERIES[6])
     text = QueryManager(lambda s: runner).metrics_text(
         1.0, executor=runner.executor)
-    scraped = {}
-    for line in text.splitlines():
-        m = _METRIC_LINE.match(line)
-        if m:
-            scraped[m.group(1)] = float(m.group(2))
+    matches = (_METRIC_LINE.match(line) for line in text.splitlines())
+    return {m.group(1): float(m.group(2)) for m in matches if m}
+
+
+def test_metrics_expose_what_the_benchmark_scrapes(traced_tpch):
+    runner = LocalRunner({"tpch": TpchConnector(SF)}, page_rows=1 << 13)
+    runner.execute(QUERIES[6])
+    scraped = _scraped(runner)
     assert scraped["device_launches"] == runner.executor.device_launches
     assert scraped["device_launches"] >= scraped["program_launches"] >= 1
     assert scraped["dispatch_wall_us"] > 0 and scraped["device_wait_us"] > 0
@@ -292,3 +360,91 @@ def test_metrics_expose_what_the_benchmark_scrapes(traced_tpch):
     assert scraped["programs_traced"] >= scraped["programs_lowered"] >= 1
     assert "process_programs_compiled" in scraped
     assert "process_program_cache_hits" in scraped
+
+
+# ------------------------------------------------------------ the mesh
+@pytest.fixture(scope="module")
+def mesh_runner():
+    from presto_tpu.dist.executor import make_mesh
+
+    runner = LocalRunner(
+        {"tpch": TpchConnector(SF)}, page_rows=1 << 13,
+        mesh=make_mesh(4), dist_options=dict(gather_capacity=16))
+    runner.session.set("query_trace_enabled", True)
+    runner.execute(QUERIES[3])  # compiled before any recording
+    return runner
+
+
+def test_a_mesh_statement_counts_every_program_it_launches(
+        mesh_runner, tmp_path):
+    """Q3 over four devices: every program the mesh executor calls goes
+    through the one launch point, so device_launches is the number of
+    launch: annotations of the statement on the profiler's host plane,
+    and the programs that move rows between chips are counted apart."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        mesh_runner.execute(QUERIES[3])
+    finally:
+        jax.profiler.stop_trace()
+    ex = mesh_runner.executor
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = next(p for p in ProfileData.from_file(path).planes
+                if p.name == "/host:CPU")
+    noted = [e.name for line in host.lines for e in line.events
+             if e.name.startswith("launch:")]
+    assert len(noted) == ex.device_launches > ex.program_launches >= 1
+    (attempt,) = [sp for sp in mesh_runner.last_trace.spans()
+                  if sp.kind == "attempt"]
+    by_label = attempt.attrs["launches"]
+    assert sum(by_label.values()) == ex.device_launches
+    assert set(by_label) <= set(PG.PROGRAM_LABELS), by_label
+    assert {f"launch:{lab}" for lab in by_label} == set(noted)
+    exchange = sum(n for lab, n in by_label.items()
+                   if PG.PROGRAM_LABELS[lab] == "exchange")
+    assert ex.exchange_launches == exchange >= 2  # repartition + gather
+    assert attempt.attrs["exchange_launches"] == exchange
+    assert {"d_scan", "d_genjoin", "d_agg_partial", "d_repartition",
+            "d_agg_final", "d_topn_local", "d_gather",
+            "topn_local"} <= set(by_label)
+    assert QUERY_COUNTERS["exchange_launches"][0] == "gauge"
+
+
+def test_exchange_launches_count_on_the_calling_executor():
+    import jax.numpy as jnp
+
+    from presto_tpu.dist.executor import DistExecutor, make_mesh
+
+    catalogs, mesh = {"tpch": TpchConnector(SF)}, make_mesh(4)
+    builder = DistExecutor(catalogs, mesh)
+    caller = DistExecutor(catalogs, mesh)
+    caller._jit_cache = builder._jit_cache
+    builder._gather_fn()(jnp.arange(8))
+    assert (builder.device_launches, builder.exchange_launches) == (1, 1)
+    out = caller._gather_fn()(jnp.arange(8))
+    assert len(builder._jit_cache) == 1, "the program was built twice"
+    assert out.tolist() == list(range(8))
+    assert (caller.device_launches, caller.exchange_launches) == (1, 1)
+    assert (builder.device_launches, builder.exchange_launches) == (1, 1)
+    # a shard-local program is a launch and no exchange
+    caller._mesh_jit(("d_filter", "t"), lambda x: x + 1)(jnp.arange(8))
+    assert (caller.device_launches, caller.exchange_launches) == (2, 1)
+    caller._begin_attempt()
+    assert (caller.device_launches, caller.exchange_launches) == (0, 0)
+
+
+def test_metrics_expose_exchange_launches(mesh_runner):
+    from presto_tpu.server.http_server import QueryManager
+
+    mesh_runner.execute(QUERIES[3])
+    scraped = _scraped(mesh_runner)
+    ex = mesh_runner.executor
+    assert scraped["exchange_launches"] == ex.exchange_launches >= 2
+    assert scraped["device_launches"] == ex.device_launches
+    assert scraped["device_launches"] > scraped["exchange_launches"]
+    assert "exchange_launches" in QueryManager._EXEC_TOTAL_SUMS
