@@ -35,10 +35,11 @@ from presto_tpu.exec import plan as P
 from presto_tpu.exec import programs as PG
 from presto_tpu.exec import xfer as XF
 from presto_tpu.exec.executor import (
-    AggSizing,
     Executor,
+    _compact_with_flag,
     _final_agg_page,
     _final_global_agg,
+    _merge_compact_flag,
     _next_pow2,
     _null_blocks,
     _partial_agg_page,
@@ -552,7 +553,14 @@ class DistExecutor(Executor):
             cap = self._mesh_agg_cap(node, sharded_state=False)
             max_iters = 64 * self._capacity_boost
 
-            for page in self.pages(node.source):
+            # where the rule asks for a compaction buffer (sparse join
+            # output under a slot-proportional grouping sort: Q3) the
+            # chips fold their pages into it first, so this step, the
+            # repartition above it and the final step run ONCE a
+            # statement on a row-sized page; where it does not (dense
+            # dictionary keys: Q5; a boosted attempt above the buffer
+            # ceiling) they run once a source page
+            for page in self._agg_source_pages(node):
                 # distinct groups <= rows: clip to the chip's rows
                 local_cap = min(
                     cap, _next_pow2(page.capacity // self.D)
@@ -625,13 +633,41 @@ class DistExecutor(Executor):
         ladder). Where the state is sharded by group key each chip
         holds 1/D of the groups, so 1/D of the rule's capacity. The
         decision is recorded for the attempt span as one chip records
-        it: one pass, no compaction buffer."""
+        it: one pass, and the rule's compaction buffer as the partial
+        step uses it (_agg_source_pages; 0 = one step a source page)."""
         sizing = self._agg_sizing(node)
         cap = max(sizing.cap // self.D, 8) if sharded_state \
             else sizing.cap
-        self._agg_sizings.append(
-            AggSizing(cap, 0, 1, sizing.sized_by))
+        self._agg_sizings.append(sizing._replace(cap=cap, parts=1))
         return cap
+
+    def _stream_compact_fns(self, node: P.Aggregation, C: int):
+        """Over a SHARDED source the rolling compaction buffer is
+        shard-local: the rule's C slots are shared over the chips as a
+        key-sharded state's capacity is, C // D each. The scan's
+        splits go round-robin, so a chip's share of the valid rows is
+        1/D in expectation; a chip whose rows pass its share flags
+        overflow through the psum and the statement re-enters boosted.
+        Correctness never depends on balance."""
+        if self.dist(node.source) != SHARDED:
+            return super()._stream_compact_fns(node, C)
+        c = C // self.D
+
+        def flagged(kernel):
+            def body(*pages):
+                out, dropped = kernel(*pages, c)
+                return out, jax.lax.psum(
+                    dropped.astype(jnp.int32), "d") > 0
+            return body
+
+        first = self._mesh_jit(
+            ("d_stream_compact1", c), flagged(_compact_with_flag),
+            out_specs=(PS("d"), PS()), fenced=True)
+        merge = self._mesh_jit(
+            ("d_stream_compact2", c), flagged(_merge_compact_flag),
+            in_specs=(PS("d"), PS("d")), out_specs=(PS("d"), PS()),
+            fenced=True)
+        return first, merge
 
     # -------------------------------------------------------------- top-N
     def _dist_topn(self, node: P.TopN) -> Iterator[Page]:

@@ -3190,7 +3190,30 @@ class Executor:
         if not C:
             yield from self.pages(node.source)
             return
-        # bare kernels: ONE canonical entry each serves every stream
+        first, merge = self._stream_compact_fns(node, C)
+        acc = None
+        for page in self.pages(node.source):
+            if acc is None or page.capacity > C:
+                # a page wider than the accumulator compacts alone
+                # first: the merge's concat then never passes 2C slots
+                # (C <= 2M keeps it under the fault line), and both
+                # argsorts stay as small as their inputs allow
+                page, overflow = first(page)
+                self._pending_overflow.append(overflow)
+            if acc is None:
+                acc = page
+                continue
+            acc, overflow = merge(acc, page)
+            self._pending_overflow.append(overflow)
+        if acc is not None:
+            yield acc
+
+    def _stream_compact_fns(self, node: P.Aggregation, C: int):
+        """The two kernels of _agg_source_pages' rolling buffer with
+        its capacity bound in: ``first(page)`` and ``merge(acc, page)``
+        each return the dense page and its dropped-rows flag. How they
+        are made is the one thing an executor over a mesh overrides.
+        Bare kernels: ONE canonical entry each serves every stream."""
         first = self._jit(
             ("stream_compact1",), _compact_with_flag,
             static_argnums=(1,),
@@ -3199,22 +3222,8 @@ class Executor:
             ("stream_compact2",), _merge_compact_flag,
             static_argnums=(2,),
         )
-        acc = None
-        for page in self.pages(node.source):
-            if acc is None or page.capacity > C:
-                # a page wider than the accumulator compacts alone
-                # first: the merge's concat then never passes 2C slots
-                # (C <= 2M keeps it under the fault line), and both
-                # argsorts stay as small as their inputs allow
-                page, overflow = first(page, C)
-                self._pending_overflow.append(overflow)
-            if acc is None:
-                acc = page
-                continue
-            acc, overflow = merge(acc, page, C)
-            self._pending_overflow.append(overflow)
-        if acc is not None:
-            yield acc
+        return (lambda page: first(page, C),
+                lambda acc, page: merge(acc, page, C))
 
     def _exec_agg_partitioned(
         self, node: P.Aggregation, parts: int, in_types, layouts

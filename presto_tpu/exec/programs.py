@@ -86,6 +86,8 @@ PROGRAM_LABELS: Dict[str, str] = {
     "d_unnest": "filter_project",
     "d_groupid": "filter_project",
     "d_uid": "filter_project",
+    "d_stream_compact1": "filter_project",
+    "d_stream_compact2": "filter_project",
     "d_genjoin": "join",
     "d_genjoin_win": "join",
     "d_semi": "join",

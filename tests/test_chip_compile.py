@@ -260,6 +260,47 @@ def test_q3_compacted_aggregation_compiles(one_chip, tpu_branches):
         page(C))
 
 
+def test_mesh_q3_compaction_compiles_shard_local(topo, tpu_branches):
+    """Q3 at SF1 over four chips (ISSUE 30): a scan round's join output
+    (262,144 slots a chip) compacts into each chip's 65,536-slot share
+    of the rule's 262,144-row buffer. Shard-local: the only collective
+    is the overflow flag's all-reduce. (The merge of two shares is the
+    same kernel at half the size: not compiled here.)"""
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import plan as P
+    from presto_tpu.runner import LocalRunner
+    from tests.tpch_queries import QUERIES
+
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    runner = LocalRunner({"tpch": TpchConnector(scale=1.0)},
+                         page_rows=1 << 18, mesh=mesh)
+    ex = runner.executor
+    ex.fault_rows = SH.SAFE_BUFFER_ROWS
+    ex.device_memory_budget = (16 << 30) * 7 // 8
+
+    agg = _find(runner.plan(QUERIES[3]), P.Aggregation)
+    while agg.step != "partial":
+        agg = _find(agg.source, P.Aggregation)
+    sz = ex._agg_sizing(agg)
+    assert (sz.cap, sz.compact_rows) == (1 << 18, 1 << 18)
+    share = sz.compact_rows // mesh.devices.size
+    ex._stream_compact_fns(agg, sz.compact_rows)
+    sharded = NamedSharding(mesh, PS("d"))
+    page = Page(
+        blocks=tuple(
+            Block(data=_spec((4 << 18,), np.dtype(t.numpy_dtype), sharded),
+                  type=t, nulls=None, dictionary=None)
+            for t in ex.output_types(agg.source)),
+        valid=_spec((4 << 18,), jnp.bool_, sharded))
+    compiled = ex._jit_cache[("d_stream_compact1", share)].jitted.lower(
+        page).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert "all-to-all" not in text and "all-gather" not in text
+    out_page, _flag = compiled.output_shardings
+    assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
+
+
 def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
     from presto_tpu.dist import executor as DX
 

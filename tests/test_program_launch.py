@@ -147,6 +147,101 @@ def test_family_of_reads_a_trace_program_name():
     assert PG.label_of((("agg_merge", 1), "donate")) == "program"
 
 
+COMPACTIONS = ("stream_compact1", "stream_compact2")
+
+
+@pytest.mark.parametrize("label", [
+    lab for one_chip in COMPACTIONS for lab in (one_chip, "d_" + one_chip)])
+def test_compaction_is_filter_project_on_one_chip_and_over_a_mesh(label):
+    """The compaction before the aggregation moves no row between
+    chips: over a mesh it is no exchange, and exchange_launches and the
+    exchange family's device time must not count it."""
+    assert PG.family_of(f"jit_{label}(1)") == "filter_project"
+    assert not PG.Program(label, lambda x: x).exchange
+
+
+def test_the_lint_finds_the_mesh_compactions():
+    sites = {(f, lab) for f, _n, lab in _jit_key_labels()}
+    mesh = os.path.join("presto_tpu", "dist", "executor.py")
+    one = os.path.join("presto_tpu", "exec", "executor.py")
+    for label in COMPACTIONS:
+        assert (mesh, "d_" + label) in sites and (one, label) in sites
+
+
+def _compaction_page(valid):
+    import jax.numpy as jnp
+
+    from presto_tpu import types as T
+    from presto_tpu.page import Block, Page
+
+    return Page(
+        blocks=(Block(data=jnp.arange(len(valid), dtype=jnp.int64),
+                      type=T.BIGINT, nulls=None, dictionary=None),),
+        valid=jnp.asarray(valid))
+
+
+def test_one_chip_compaction_keeps_its_two_canonical_programs():
+    """The rolling buffer's programs stay the bare kernels with a
+    static capacity under the keys ("stream_compact1",) and
+    ("stream_compact2",): what the one-chip cells' traces name and
+    fingerprint them by cannot drift with how the loop gets them."""
+    from presto_tpu.exec.executor import (
+        _compact_with_flag, _merge_compact_flag)
+
+    ex = Executor({"tpch": TpchConnector(SF)})
+    first, merge = ex._stream_compact_fns(None, 4)
+    assert set(ex._jit_cache) == {(lab,) for lab in COMPACTIONS}
+    page = _compaction_page([True, False] * 4)
+    acc, dropped = first(page)
+    assert acc.block(0).data.tolist() == [0, 2, 4, 6] and not dropped
+    both, dropped = merge(acc, page)
+    assert both.block(0).data.tolist() == [0, 2, 4, 6] and dropped
+    assert ex.device_launches == 2
+    for label, kernel, args, static in (
+            ("stream_compact1", _compact_with_flag, (page, 4), (1,)),
+            ("stream_compact2", _merge_compact_flag, (acc, page, 4),
+             (2,))):
+        bare = PG.Program(label, kernel, static_argnums=static)
+        assert ex._jit_cache[(label,)].jitted.lower(*args).as_text() \
+            == bare.jitted.lower(*args).as_text()
+
+
+def test_mesh_compaction_is_shard_local_with_one_flag():
+    """Over four devices each chip compacts into its C // D slots and
+    no row changes chips; one chip past its share raises the one
+    replicated flag."""
+    import types
+
+    from presto_tpu.dist.executor import DistExecutor, make_mesh
+    from presto_tpu.exec import plan as P
+
+    ex = DistExecutor({"tpch": TpchConnector(SF)}, make_mesh(4))
+    node = types.SimpleNamespace(
+        source=P.TableScan("tpch", "lineitem", ("l_orderkey",)))
+    first, merge = ex._stream_compact_fns(node, 8)  # 2 slots a chip
+    assert {k[0] for k in ex._jit_cache} == {
+        "d_" + lab for lab in COMPACTIONS}
+    # chip 0 holds rows 0..3, chip 1 rows 4..7, ...
+    page = _compaction_page(
+        [False, True, False, False] + [True, False, False, True]
+        + [False] * 4 + [False, False, True, True])
+    acc, dropped = first(page)
+    assert acc.capacity == 8 and not bool(dropped)
+    assert acc.valid.tolist() == [True, False, True, True,
+                                  False, False, True, True]
+    assert acc.block(0).data.tolist()[:4] == [1, 0, 4, 7]
+    assert acc.block(0).data.tolist()[6:] == [14, 15]
+    both, dropped = merge(acc, page)  # chip 0: 2 rows, the others more
+    assert bool(dropped)
+    assert both.block(0).data.tolist()[:2] == [1, 1]
+    assert (ex.device_launches, ex.exchange_launches) == (2, 0)
+    # a replicated source takes the one-chip pair
+    ex._stream_compact_fns(
+        types.SimpleNamespace(source=P.Values((), ())), 8)
+    assert {("stream_compact1",), ("stream_compact2",)} <= set(
+        ex._jit_cache)
+
+
 @pytest.fixture(scope="module")
 def traced_tpch():
     """Q1/Q3/Q5/Q6 at SF0.01 on a fresh runner with the fused paths the
