@@ -4,7 +4,7 @@ Reference: Presto amortizes per-query codegen with compiled-artifact
 caches (ExpressionCompiler's LRU, the coordinator reusing plans across
 queries). The JAX-native analog is jax's persistent compilation cache:
 programs compile once per canonical shape PER MACHINE, not per process
-— repeated bench rungs, repeated tier-1 runs, and worker restarts all
+— repeated benchmark runs, repeated tier-1 runs, and worker restarts all
 reload compiled executables from disk instead of re-invoking XLA (on
 an earlier TPU toolchain a partitioned-join program set costs 40+ min
 fresh; warm it is seconds). The other half of the bargain — making the
@@ -14,9 +14,9 @@ Observability: jax.monitoring hooks below count real XLA backend
 compiles (`programs_compiled`, `compile_wall_s`) and persistent-cache
 hits/misses (`program_cache_hits` / `persistent_cache_misses`)
 process-wide; the executor snapshots them around each query and
-EXPLAIN ANALYZE / tools/analyze_rung.py / tools/compile_stats.py /
-bench.py report the deltas. A persistent-cache HIT does not count as a
-compile — `programs_compiled == 0` on a warmed run is the contract.
+EXPLAIN ANALYZE reports the deltas (/metrics the process totals). A
+persistent-cache HIT does not count as a compile —
+`programs_compiled == 0` on a warmed run is the contract.
 
 Program load, split (ISSUE 25): what a program's first call costs in a
 process has four parts, and the hooks below keep a process total of
@@ -38,7 +38,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 from presto_tpu.obs.sanitizer import make_lock
 
@@ -73,11 +73,6 @@ _raw: Dict[str, float] = {
 # (start, duration) in completion order, the newest few only
 _tls = threading.local()
 _MAX_OPEN_TRACES = 256
-# recent per-request walls (tools/compile_stats.py's per-program
-# breakdown; a persistent-cache hit's wall is its retrieval time);
-# bounded so a long-lived server can't grow it
-_MAX_WALLS = 4096
-_compile_walls: List[float] = []
 _installed = False
 _cache_dir: Optional[str] = None
 
@@ -111,8 +106,6 @@ def _on_duration(event: str, duration: float, **kw) -> None:
         with _lock:
             _raw["requests"] += 1
             _raw["request_wall_s"] += duration
-            if len(_compile_walls) < _MAX_WALLS:
-                _compile_walls.append(duration)
     elif event == _CACHE_RETRIEVAL:
         with _lock:
             _raw["retrieval_wall_s"] += duration
@@ -174,12 +167,6 @@ def delta(since: Dict[str, float]) -> Dict[str, float]:
     for k in _WALLS:
         out[k] = round(max(out[k], 0.0), 3)
     return out
-
-
-def compile_walls() -> List[float]:
-    """Recent individual backend-compile walls (seconds), compile order."""
-    with _lock:
-        return list(_compile_walls)
 
 
 def cache_dir() -> Optional[str]:

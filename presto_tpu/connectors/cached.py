@@ -4,12 +4,11 @@ Reference: presto-memory MemoryPagesStore — pages held resident on the
 worker so a scan is a memory read, not a recomputation. The TPU analog
 keeps the materialized page list in HBM: the first scan of a (table,
 columns, page-size, constraint, snapshot) combination streams and
-retains the pages; every later scan re-yields them. Used by the bench
-harness to separate "generate the data" from "run the query" (the
-reference's benchmarks scan stored tables; our generator connectors
-otherwise fuse dbgen-style generation into every scan, SURVEY §8.2.6),
-and usable as a session-level table cache for any repeated-scan
-workload.
+retains the pages; every later scan re-yields them. It separates
+"generate the data" from "run the query" (the reference's benchmarks
+scan stored tables; our generator connectors otherwise fuse dbgen-style
+generation into every scan, SURVEY §8.2.6), and is usable as a
+session-level table cache for any repeated-scan workload.
 
 Key discipline (ISSUE 10 fix): constraints are keyed by their
 CANONICAL structural encoding (`obs/profile.structural_encode` — the

@@ -1,12 +1,11 @@
 """Forced device synchronization for honest timing.
 
-Round-4 discovery (see bench.py docstring; not re-verified on the
-current chip, see ROADMAP A2): on an earlier TPU runtime
-``jax.block_until_ready`` returned at dispatch — it did NOT wait for
-device completion, and queued work drained only when a device->host
-read forced it. Every timing path in the tree (bench group children, the
-executor's EXPLAIN ANALYZE stats_drain mode, tools/microbench.py) must
-use THIS helper so a future protocol correction lands in one place.
+Not re-verified on the current chip (ROADMAP C3): on an earlier TPU
+runtime ``jax.block_until_ready`` returned at dispatch — it did NOT
+wait for device completion, and queued work drained only when a
+device->host read forced it. Every timing path in the tree (the
+executor's EXPLAIN ANALYZE stats_drain mode) must use THIS helper so a
+future protocol correction lands in one place.
 """
 
 from __future__ import annotations

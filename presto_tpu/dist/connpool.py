@@ -227,7 +227,7 @@ def request(url: str, *, method: str = "GET",
 
 def pool_totals() -> dict:
     """Process-lifetime reuse/failover totals, for the /metrics
-    overlay and loadbench deltas."""
+    overlay."""
     return dict(_TOTALS)
 
 

@@ -509,7 +509,7 @@ class DcnRunner:
         skips are counted, not swallowed silently — on the executor's
         registry counter (exec/counters.py), the one copy every
         surface (EXPLAIN ANALYZE, /metrics, system.metrics,
-        analyze_rung, DcnRunner.release_skips) reads. THE one release
+        DcnRunner.release_skips) reads. THE one release
         site for both the legacy cuts and the stage-DAG scheduler."""
         try:
             with CONNPOOL.request(

@@ -115,15 +115,16 @@ class DistExecutor(Executor):
         that both contain cross-device collectives can start in different
         orders on different virtual devices — device 0 enters program B's
         all-reduce rendezvous while devices 1..7 wait in program A's, and
-        the rendezvous aborts after its timeout (MULTICHIP_r05 rc=134:
-        TPC-DS Q17's windowed generated-join `psum` interleaved with the
-        dim-join pipeline's gathers, "Expected 8 threads to join the
-        rendezvous, but only 1 arrived"). Blocking on each collective
-        program's outputs before the next one can be dispatched enforces
-        ONE consistent execution order across all devices. TPU per-device
-        queues execute strictly in dispatch order, so the fence is
-        CPU-only and costs hardware nothing — the deferred-sync discipline
-        (Executor.__init__) is a TPU-runtime concern and unaffected."""
+        the rendezvous aborts after its timeout (seen as rc=134 on
+        TPC-DS Q17: its windowed generated-join `psum` interleaved
+        with the dim-join pipeline's gathers, "Expected 8 threads to
+        join the rendezvous, but only 1 arrived"). Blocking on each
+        collective program's outputs before the next one can be
+        dispatched enforces ONE consistent execution order across all
+        devices. TPU per-device queues execute strictly in dispatch
+        order, so the fence is CPU-only and costs hardware nothing — the
+        deferred-sync discipline (Executor.__init__) is a TPU-runtime
+        concern and unaffected."""
         if jax.default_backend() != "cpu":
             return fn
 
@@ -729,7 +730,7 @@ class DistExecutor(Executor):
             return out, jax.lax.psum(multi.astype(jnp.int32), "d") > 0
 
         # fenced: the windowed multi-match psum is THE collective
-        # whose free interleaving deadlocked MULTICHIP_r05 (Q17)
+        # whose free interleaving deadlocked TPC-DS Q17
         fn = self._mesh_jit(("d_genjoin_win", node, dl), win_body,
                             in_specs=(spec,), out_specs=(spec, PS()),
                             fenced=True)

@@ -125,9 +125,9 @@ _ZLIB_MIN_BYTES = 64
 _MODE = "full"
 
 # process-lifetime wire totals (the exec/xfer.py `_totals` pattern:
-# monotonically increasing ints, GIL-atomic +=, read by /metrics and
-# loadbench for fleet grading where per-query executor gauges from
-# worker task threads never surface)
+# monotonically increasing ints, GIL-atomic +=, read by /metrics,
+# where per-query executor gauges from worker task threads never
+# surface)
 _TOTALS = {"exchange_wire_bytes": 0, "exchange_raw_bytes": 0}
 
 
@@ -150,7 +150,7 @@ def wire_fingerprint() -> str:
 
 def set_wire_mode(mode: str) -> str:
     """Select the wire codec mode ("full" | "zlib" | "raw"); returns
-    the previous mode. Test/bench surface for A/B wire-bytes grading
+    the previous mode. Test surface for A/B wire-bytes grading
     — production runs stay on "full"."""
     global _MODE
     if mode not in ("full", "zlib", "raw"):
@@ -161,7 +161,7 @@ def set_wire_mode(mode: str) -> str:
 
 def wire_totals() -> dict:
     """Process-lifetime wire byte totals (serialize side), for the
-    /metrics + system.metrics overlay and loadbench deltas."""
+    /metrics + system.metrics overlay."""
     return dict(_TOTALS)
 
 

@@ -18,13 +18,10 @@ a jitted kernel and compacts each partition to a ladder-bucket
 capacity on device — pages never cross to host at the exchange, and
 the worker spool holds device Pages that materialize to host bytes
 LAZILY (`spool_blob`) only when a replay or a DCN-remote consumer
-actually fetches over HTTP. A Pallas partition-id variant engages only
-on explicit pallas_join_enabled=true (session-distributed, so every
-producer of one exchange resolves it identically — a per-process
-backend auto-probe could disagree across a mixed pool). Parity between
-the tiers is test-pinned per key type incl. the NULL sentinel
-(tests/test_device_exchange.py); skew still rides the boosted-retry
-ladder — a partition overflowing its bucket raises the deferred flag.
+actually fetches over HTTP. Parity between the tiers is test-pinned
+per key type incl. the NULL sentinel (tests/test_device_exchange.py);
+skew still rides the boosted-retry ladder — a partition overflowing
+its bucket raises the deferred flag.
 
 Client split (deliberate, not drift): `fetch_spool_blobs` below is the
 WORKER-side exchange client — plain token-dedupe fetch between stage
@@ -240,47 +237,6 @@ def device_row_hash_u64(page: Page, keys: Sequence[int],
     return _mix64_dev(h)
 
 
-def _pallas_part_ids(page: Page, keys: Sequence[int], dict_luts,
-                     nparts: int, *, interpret: bool) -> jnp.ndarray:
-    """Pallas partition-id variant: the 64-bit value encodings split
-    into 32-bit words (Mosaic has no uint64 lanes — the pallas_join
-    discipline) and mix through the fmix32 finalizer inside one VPU
-    kernel. NOT hash-compatible with the splitmix64 tier — partition
-    routing needs only SELF-consistency across one exchange's
-    producers, which is why the gate is the session-distributed
-    pallas_join_enabled=true, never a per-process backend probe."""
-    from jax.experimental import pallas as pl
-
-    from presto_tpu.ops.pallas_join import _mix32, _split64
-
-    luts = tuple(dict_luts) or (None,) * len(keys)
-    los, his = [], []
-    for k, vh in zip(keys, luts):
-        blk = page.block(k)
-        enc = _block_value_u64_dev(blk, vh)
-        if blk.nulls is not None:
-            enc = jnp.where(blk.nulls, jnp.uint64(_NULL_SENTINEL), enc)
-        lo, hi = _split64(enc)
-        los.append(lo)
-        his.append(hi)
-    lo2 = jnp.stack(los)  # [C, N] int32
-    hi2 = jnp.stack(his)
-
-    def kernel(lo_ref, hi_ref, out_ref):
-        acc = jnp.zeros(lo_ref.shape[1:], dtype=jnp.uint32)
-        for c in range(lo_ref.shape[0]):
-            acc = acc * jnp.uint32(31) + _mix32(lo_ref[c], hi_ref[c])
-        acc = _mix32(acc.astype(jnp.int32),
-                     jnp.zeros_like(acc).astype(jnp.int32))
-        out_ref[...] = (acc % jnp.uint32(nparts)).astype(jnp.int32)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(lo2.shape[1:], jnp.int32),
-        interpret=interpret,
-    )(lo2, hi2)
-
-
 def device_partition_pages(
     ex, page: Page, keys: Sequence[int], nparts: int,
     with_counts: bool = False,
@@ -322,21 +278,13 @@ def device_partition_pages(
     )
     boost = ex._capacity_boost
     cap = SH.exchange_partition_cap(cap_in, nparts, boost)
-    use_pallas = ex._pallas_exchange_on()
-    if use_pallas:
-        ex.pallas_kernels_used += 1
 
     def body(pg: Page, *vhs):
         vh_by_key = iter(vhs)
         full = tuple(next(vh_by_key) if d is not None else None
                      for d in dicts)
-        if use_pallas:
-            part = _pallas_part_ids(
-                pg, keys, full, nparts,
-                interpret=jax.default_backend() != "tpu")
-        else:
-            h = device_row_hash_u64(pg, keys, full)
-            part = (h % jnp.uint64(nparts)).astype(jnp.int32)
+        h = device_row_hash_u64(pg, keys, full)
+        part = (h % jnp.uint64(nparts)).astype(jnp.int32)
         outs = []
         nums = []
         overflow = jnp.asarray(False)
@@ -362,7 +310,7 @@ def device_partition_pages(
 
     fn = ex._jit(
         ("dev_repart", tuple(keys), nparts, cap, cap_in, dicts,
-         use_pallas, with_counts),
+         with_counts),
         body,
     )
     out = fn(page, *[v for v in luts if v is not None])

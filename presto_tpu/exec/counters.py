@@ -3,11 +3,7 @@
 Reference: presto-main OperatorStats/QueryStats — every runtime counter
 the engine maintains is declared once and every surfacing layer
 renders the same declared set (JMX beans enumerate the declared stats;
-nothing is hand-listed per endpoint). Before this registry each
-counter was wired by hand into EXPLAIN ANALYZE, /metrics,
-system.metrics, and analyze_rung separately — and PR after PR the
-wiring drifted (split_batch_fallbacks and the spill counters never
-reached /metrics at all). Now:
+nothing is hand-listed per endpoint):
 
   - QUERY_COUNTERS declares every integer counter the Executor (and
     the DCN coordinator, via mirrored attributes) maintains;
@@ -16,8 +12,6 @@ reached /metrics at all). Now:
     COMPUTED_COUNTERS);
   - the HTTP server's /metrics exposition and system.metrics table
     iterate the registry;
-  - tools/analyze_rung.py prints every key of the stats dict, so
-    registry membership IS analyze_rung coverage;
   - tools/lint's `counters` rule fails the build when a `self.x += 1`
     counter in exec/ or dist/ is missing from the registry.
 
@@ -76,11 +70,6 @@ QUERY_COUNTERS: Dict[str, tuple] = {
     "pallas_joins_used": (
         "counter", "Pallas join kernel engagements (lifetime; EXPLAIN "
         "ANALYZE reports the per-query delta)"),
-    "pallas_kernels_used": (
-        "counter", "Pallas kernel engagements of ANY kind — join "
-        "probes, segmented-reduction aggregations, partition-id "
-        "exchange hashing (lifetime; the device-native kernel tier's "
-        "overall engagement gauge)"),
     "ici_exchanges": (
         "counter", "repartition exchanges lowered to an in-program "
         "lax.all_to_all over the co-resident mesh instead of the "

@@ -372,10 +372,6 @@ class Executor:
         # property); pallas_joins_used is observability for tests
         self.pallas_join = False
         self.pallas_joins_used = 0
-        # every Pallas kernel engagement (joins, segmented-reduction
-        # aggregation, partition-id exchange hashing) — the device-
-        # native tier's overall gauge (ISSUE 18)
-        self.pallas_kernels_used = 0
         # mesh all_to_all exchange plane (dist/scheduler.py; mirrored
         # onto the coordinator): exchanges lowered onto the ICI mesh,
         # their send-buffer bytes, and loud fallbacks to the spool plane
@@ -491,8 +487,8 @@ class Executor:
         # an int forces the ceiling (tests, the static audit).
         self.fault_rows: Optional[int] = None
         # memory_chunked_pipelines: governed rewrites this attempt
-        # (reset in _begin_attempt, reported in EXPLAIN ANALYZE and
-        # BENCH_DETAILS alongside peak_device_bytes)
+        # (reset in _begin_attempt, reported in EXPLAIN ANALYZE
+        # alongside peak_device_bytes)
         self.memory_chunked_pipelines = 0
         # ---- fault tolerance (ISSUE 5: task retry + deadlines + OOM
         # degradation). query_deadline: absolute time.monotonic()
@@ -525,9 +521,8 @@ class Executor:
         self.workers_excluded = 0
         # release_skips = dead-worker page-buffer DELETE releases
         # skipped (DcnRunner mirrors its own count here so every
-        # counter surface — EXPLAIN ANALYZE, /metrics, system.metrics,
-        # analyze_rung — reads one registry off one object;
-        # exec/counters.py)
+        # counter surface — EXPLAIN ANALYZE, /metrics, system.metrics
+        # — reads one registry off one object; exec/counters.py)
         self.release_skips = 0
         # Coordinator HA (ISSUE 20, dist/checkpoint.py), lifetime-
         # cumulative on the coordinator's executor: journal records
@@ -559,8 +554,9 @@ class Executor:
         # plan_check (exec/plan_check.py): pre-compile verification of
         # the physical plan — schema-consistent edges, ladder/fault-line
         # capacities, canonical jit-key material, split determinism.
-        # "auto" = on under pytest and bench --prewarm (the build/test
-        # surface), off on the hot serving path; True/False force.
+        # "auto" = on under pytest or PRESTO_TPU_PLAN_CHECK=1 (the
+        # build/test surface), off on the hot serving path; True/False
+        # force.
         self.plan_check = "auto"
         # ---- query-lifecycle tracing (ISSUE 9, presto_tpu/obs/).
         # trace: the active obs.QueryTrace, attached per query by the
@@ -691,8 +687,8 @@ class Executor:
         # ---- streaming subsystem (ISSUE 14, presto_tpu/streaming/ +
         # connectors/stream.py): lifetime counters mirrored onto the
         # executor so every surface (EXPLAIN ANALYZE, /metrics,
-        # system.metrics, analyze_rung, loadbench) renders refresh
-        # activity. delta_pages_folded = delta partial-state pages an
+        # system.metrics) renders refresh activity.
+        # delta_pages_folded = delta partial-state pages an
         # IVM refresh folded into persisted view state (O(new rows)
         # work); ivm_refreshes = incremental refreshes completed;
         # ivm_full_recomputes = refreshes that fell back to a full
@@ -817,8 +813,8 @@ class Executor:
         self.result_cache_invalidations += n
 
     # The four streaming sinks below may be hit from CONCURRENT
-    # threads (tail-cursor polls on protocol handler threads, the
-    # loadbench writer pool) sharing one bootstrap executor: the
+    # threads (tail-cursor polls on protocol handler threads, writer
+    # threads) sharing one bootstrap executor: the
     # increments are plain GIL-guarded adds, so a lost increment
     # under contention is an acceptable METRIC error, never a
     # correctness one — the exec/xfer.py process-totals stance.
@@ -919,8 +915,8 @@ class Executor:
                     or env in ("1", "true", "on"))
 
     def _verify_plan(self, node: P.PhysicalNode) -> None:
-        """Run the pre-compile plan verifier when enabled (auto = test
-        and prewarm surfaces only — the serving path pays nothing).
+        """Run the pre-compile plan verifier when enabled (auto = the
+        test surface only — the serving path pays nothing).
         A clean verdict is memoized per (plan object, sizing knobs) —
         retry ladders and repeated executions of one plan re-verify
         nothing; the held references keep id() stable."""
@@ -965,39 +961,6 @@ class Executor:
         programs cost real CPU compile time for copies the CPU
         backend barely pays."""
         return self._tristate_on(self.device_exchange)
-
-    def _pallas_exchange_on(self) -> bool:
-        """Pallas partition-id variant of the device repartition
-        kernel, behind the pallas_join_enabled knob — but engaged
-        ONLY when explicitly forced, never on "auto": the variant's
-        hash is deliberately not splitmix64-compatible, and exchange
-        routing must agree across every producer of one exchange. A
-        per-process backend probe could disagree on a mixed
-        CPU+TPU worker pool and silently mis-route co-partitioned
-        join keys; "true"/"force" is session-distributed to every
-        task payload, so it resolves identically fleet-wide."""
-        return self.pallas_join in (True, "force")
-
-    def _pallas_agg_on(self) -> bool:
-        """Segmented-reduction Pallas aggregation (ops/pallas_agg.py),
-        behind the pallas_join_enabled tri-state. Engaged only when
-        explicitly forced, and then in interpret mode — the CPU test
-        path: the kernel's in-kernel one-hot dot is unvalidated on
-        hardware (pallas_agg.agg_lowers_on_tpu), so forcing it on a
-        TPU raises instead of interpreting, matching the radix join
-        probe's posture. "auto" keeps the jnp segment-op path, which
-        computes identical results."""
-        if self.pallas_join not in (True, "force"):
-            return False
-        from presto_tpu.ops import pallas_agg as PA
-
-        if jax.default_backend() == "tpu" and not PA.agg_lowers_on_tpu():
-            raise NotImplementedError(
-                "pallas_join_enabled=force: the segmented-reduction "
-                "Pallas aggregation does not lower on TPU; use "
-                "pallas_join_enabled=auto"
-            )
-        return True
 
     def _jit(self, key, fn=None, static_argnums=(), donate_argnums=(),
              make=None):
@@ -1822,13 +1785,9 @@ class Executor:
         if (node.capacity > A.MATMUL_AGG_MAX_GROUPS
                 and _subtree_has_join(node.source)):
             return None
-        pallas = self._pallas_agg_on()
-        if pallas:
-            self.pallas_kernels_used += 1
         raw = functools.partial(
             _partial_agg_page, node.group_channels, node.aggregates,
             layouts_t, collect_k=self._collect_k_eff,
-            pallas_agg=pallas,
         )
         merge_raw = functools.partial(
             _merge_partials_page, node.aggregates, layouts_t,
@@ -2149,8 +2108,8 @@ class Executor:
         oom_left = self.device_oom_attempts
         # pre-compile plan verification (exec/plan_check.py): schema-
         # consistent edges, ladder/fault-line capacities, canonical
-        # jit-key material — auto-on under pytest and bench --prewarm,
-        # off on the hot serving path (plan_check session property)
+        # jit-key material — auto-on under pytest, off on the hot
+        # serving path (plan_check session property)
         self._verify_plan(node)
         # result-cache points (presto_tpu/cache/): pages() serves the
         # selected subtrees from the shared store; a whole-plan hit
@@ -2665,9 +2624,9 @@ class Executor:
         # execute() took.
         base_gen, base_pal = getattr(self, "_joins_counter_base", (0, 0))
         # registry-driven (exec/counters.py): every declared counter
-        # surfaces here — and therefore in EXPLAIN ANALYZE text and
-        # analyze_rung, which render all keys — with no per-counter
-        # hand wiring. The lifetime-cumulative join counters override
+        # surfaces here — and therefore in EXPLAIN ANALYZE text,
+        # which renders all keys — with no per-counter hand wiring.
+        # The lifetime-cumulative join counters override
         # to THIS query's delta over the snapshot execute() took.
         ctr = CTRS.snapshot(self)
         ctr["generated_joins_used"] = self.generated_joins_used - base_gen
@@ -2755,8 +2714,7 @@ class Executor:
                 key_extra=("partial", node.group_channels,
                            node.aggregates, pcap,
                            64 * self._capacity_boost,
-                           self._collect_k_eff,
-                           self._pallas_agg_on()),
+                           self._collect_k_eff),
             )
             if fused is not None:
                 yield from fused
@@ -2775,17 +2733,13 @@ class Executor:
             return
         cap = _next_pow2(node.capacity * self._capacity_boost)
         max_iters = 64 * self._capacity_boost
-        pallas_agg = self._pallas_agg_on()
-        if pallas_agg:
-            self.pallas_kernels_used += 1
         fn = self._jit(
             ("agg_partial", node.group_channels, node.aggregates,
-             tuple(tuple(l) for l in layouts), self._collect_k_eff,
-             pallas_agg),
+             tuple(tuple(l) for l in layouts), self._collect_k_eff),
             functools.partial(
                 _partial_agg_page, node.group_channels, node.aggregates,
                 tuple(tuple(l) for l in layouts),
-                collect_k=self._collect_k_eff, pallas_agg=pallas_agg,
+                collect_k=self._collect_k_eff,
             ),
             static_argnums=(1, 2),
         )
@@ -2968,17 +2922,13 @@ class Executor:
             )
             return
         cap = sizing.cap
-        pallas_agg = self._pallas_agg_on()
-        if pallas_agg:
-            self.pallas_kernels_used += 1
         partial_fn = self._jit(
             ("agg_partial", node.group_channels, node.aggregates,
-             tuple(tuple(l) for l in layouts), self._collect_k_eff,
-             pallas_agg),
+             tuple(tuple(l) for l in layouts), self._collect_k_eff),
             functools.partial(
                 _partial_agg_page, node.group_channels, node.aggregates,
                 tuple(tuple(l) for l in layouts),
-                collect_k=self._collect_k_eff, pallas_agg=pallas_agg,
+                collect_k=self._collect_k_eff,
             ),
             static_argnums=(1, 2),
         )
@@ -3033,8 +2983,7 @@ class Executor:
                 node.source, agg_tail=tail,
                 key_extra=("single", node.group_channels,
                            node.aggregates, cap, max_iters,
-                           self._collect_k_eff,
-                           self._pallas_agg_on()),
+                           self._collect_k_eff),
             )
             if tail is not None and node.group_channels else None
         )
@@ -3264,17 +3213,13 @@ class Executor:
         cap = _next_pow2(node.capacity * self._capacity_boost)
         pcap = SH.chunk_bucket(cap, parts)
         max_iters = 64 * self._capacity_boost
-        pallas_agg = self._pallas_agg_on()
-        if pallas_agg:
-            self.pallas_kernels_used += 1
         partial_fn = self._jit(
             ("agg_partial", node.group_channels, node.aggregates,
-             tuple(tuple(l) for l in layouts), self._collect_k_eff,
-             pallas_agg),
+             tuple(tuple(l) for l in layouts), self._collect_k_eff),
             functools.partial(
                 _partial_agg_page, node.group_channels, node.aggregates,
                 tuple(tuple(l) for l in layouts),
-                collect_k=self._collect_k_eff, pallas_agg=pallas_agg,
+                collect_k=self._collect_k_eff,
             ),
             static_argnums=(1, 2),
         )
@@ -3344,17 +3289,13 @@ class Executor:
         cap = _next_pow2(node.capacity * self._capacity_boost)
         pcap = SH.chunk_bucket(cap, parts)
         max_iters = 64 * self._capacity_boost
-        pallas_agg = self._pallas_agg_on()
-        if pallas_agg:
-            self.pallas_kernels_used += 1
         partial_fn = self._jit(
             ("agg_partial", node.group_channels, node.aggregates,
-             tuple(tuple(l) for l in layouts), self._collect_k_eff,
-             pallas_agg),
+             tuple(tuple(l) for l in layouts), self._collect_k_eff),
             functools.partial(
                 _partial_agg_page, node.group_channels, node.aggregates,
                 tuple(tuple(l) for l in layouts),
-                collect_k=self._collect_k_eff, pallas_agg=pallas_agg,
+                collect_k=self._collect_k_eff,
             ),
             static_argnums=(1, 2),
         )
@@ -3967,41 +3908,18 @@ class Executor:
         return fn(item.reduced, *[s.build for s in item.sides])
 
     # ---------------------------------------------------- Pallas paths
-    def _pallas_mode_allows(self, layout) -> bool:
-        """pallas_join_enabled semantics: "off" never; "force" always
-        (on CPU the kernels run in interpret mode — the test path; on
-        TPU a layout that does not lower raises, _pallas_interpret);
-        "auto" only layouts whose kernel REALLY lowers through Mosaic,
-        and only on TPU (the interpreted kernels exist for testing,
-        not speed)."""
-        from presto_tpu.ops import pallas_join as PJ
-
-        mode = self.pallas_join
-        if mode in (False, None, "off"):
-            return False
-        if mode in (True, "force"):
-            return True
-        return (
-            jax.default_backend() == "tpu"
-            and PJ.layout_lowers_on_tpu(layout)
-        )
+    def _pallas_mode_allows(self) -> bool:
+        """pallas_join_enabled: "off" never; "force" always (on a CPU
+        the kernel runs in interpret mode — the test path); "auto" on
+        a TPU only, where the kernel lowers through Mosaic (the
+        interpreted kernel exists for testing, not speed)."""
+        return self._tristate_on(self.pallas_join)
 
     @staticmethod
-    def _pallas_interpret(layout) -> bool:
-        """Interpret mode is the CPU test path only. On a TPU a kernel
-        either lowers through Mosaic or the (forced) layout is an
-        error — never a silently interpreted kernel."""
-        from presto_tpu.ops import pallas_join as PJ
-
-        if jax.default_backend() != "tpu":
-            return True
-        if not PJ.layout_lowers_on_tpu(layout):
-            raise NotImplementedError(
-                f"pallas_join_enabled=force: the {layout[0]!r} join "
-                "layout does not lower on TPU (only 'dim' does); use "
-                "pallas_join_enabled=auto"
-            )
-        return False
+    def _pallas_interpret() -> bool:
+        """Interpret mode is the CPU test path only: on a TPU the dim
+        probe lowers through Mosaic."""
+        return jax.default_backend() != "tpu"
 
     def _pallas_join_eligible(self, node, build: Page, left_types,
                               right_types) -> bool:
@@ -4030,31 +3948,29 @@ class Executor:
                 # long decimals encode as (hi, lo) limb pairs — one u64
                 # key cannot carry them
                 return False
-        if build.capacity > PJ.RADIX_MAX_BUILD:
+        if build.capacity > PJ.DIM_MAX_BUILD:
             return False
-        if not self._pallas_mode_allows(PJ.plan_layout(build.capacity)):
+        if not self._pallas_mode_allows():
             return False
         return self._scan_column_unique(node.right, node.right_keys[0])
 
     def _radix_join_eligible(self, node, build: Page) -> bool:
-        """The radix-partitioned Pallas join (ops/pallas_join.py) as the
-        general range finder for inner/left/right/full equi-joins: any
-        key count/types, duplicate build keys. On TPU (auto) it engages
-        for layouts whose kernel really lowers (the dim layout — star-
-        schema dimension builds); forced mode additionally runs the
-        bucketed radix kernel in interpret mode up to RADIX_MAX_BUILD
-        rows (the CPU test path). Boosted retries fall back to the sort
-        join — the overflow may have been a bucket overfull in the
-        Pallas table build."""
+        """The Pallas dim probe (ops/pallas_join.py) as the general
+        range finder for inner/left/right/full equi-joins: any key
+        count/types, duplicate build keys, builds of up to
+        DIM_MAX_BUILD rows (star-schema dimension builds); above that
+        the sort join. Boosted retries fall back to the sort join —
+        the overflow may have been a tile overfull in the Pallas table
+        build."""
         if self._capacity_boost > 1:
             return False
         if node.join_type not in ("inner", "left", "right", "full"):
             return False
         from presto_tpu.ops import pallas_join as PJ
 
-        if build.capacity > PJ.RADIX_MAX_BUILD:
+        if build.capacity > PJ.DIM_MAX_BUILD:
             return False
-        return self._pallas_mode_allows(PJ.plan_layout(build.capacity))
+        return self._pallas_mode_allows()
 
     def _scan_column_unique(self, n: P.PhysicalNode, ch: int) -> bool:
         """Whether channel ch of node n provably carries a unique table
@@ -4067,9 +3983,8 @@ class Executor:
         from presto_tpu.ops import pallas_join as PJ
 
         self.pallas_joins_used += 1
-        self.pallas_kernels_used += 1
         layout = PJ.plan_layout(build.capacity)
-        interpret = self._pallas_interpret(layout)
+        interpret = self._pallas_interpret()
         index, build_ovf = self._jit(
             ("pallas_ubuild", node.right_keys[0], build.capacity),
             functools.partial(
@@ -4257,18 +4172,17 @@ class Executor:
                 yield fn(page, build)
             return
 
-        # Radix Pallas path: same verified match expansion, but the
-        # candidate ranges come from the bucketed open-addressing kernel
-        # instead of searchsorted (north-star's radix-partitioned join)
+        # Pallas path: same verified match expansion, but the
+        # candidate ranges come from the open-addressing dim kernel
+        # instead of searchsorted
         use_radix = self._radix_join_eligible(node, build)
         layout = interpret = None
         if use_radix:
             from presto_tpu.ops import pallas_join as PJ
 
             self.pallas_joins_used += 1
-            self.pallas_kernels_used += 1
             layout = PJ.plan_layout(build.capacity)
-            interpret = self._pallas_interpret(layout)
+            interpret = self._pallas_interpret()
         use_unique = (
             not use_radix and unique_build
             and node.join_type in ("inner", "left")
@@ -4284,7 +4198,7 @@ class Executor:
                     ("radix_probe", node.right_keys, node.join_type,
                      build.capacity, interpret, pkeys, defer_item),
                     functools.partial(
-                        _probe_radix_join_page, pkeys,
+                        _probe_pallas_join_page, pkeys,
                         node.right_keys, node.join_type, layout,
                         interpret, defer_item,
                     ),
@@ -4353,11 +4267,11 @@ class Executor:
                         ("radix_build", node.right_keys,
                          build.capacity, sig),
                         functools.partial(
-                            _build_radix_join_index, pkeys,
+                            _build_pallas_join_index, pkeys,
                             node.right_keys, layout,
                         ),
                     )(page, build)
-                    # bucket-overfull escape: boosted retries fall back
+                    # tile-overfull escape: boosted retries fall back
                     # to the sort join (eligibility checks the boost)
                     self._pending_overflow.append(b_ovf)
                 else:
@@ -4924,18 +4838,7 @@ def _collect_finalize_block(spec, in_t, extra_t, state_blocks) -> Block:
 
 
 def _partial_agg_page(group_channels, aggregates, layouts, page: Page,
-                      cap: int, max_iters: int = 64, collect_k: int = 1024,
-                      pallas_agg: bool = False):
-    # segmented-reduction Pallas tier (ops/pallas_agg.py, ISSUE 18):
-    # same SQL semantics, group totals from the blocked one-hot-matmul
-    # kernel; unsupported kinds delegate back to the jnp path inside
-    # PA.aggregate, so one dispatch covers the whole layout
-    if pallas_agg:
-        from presto_tpu.ops import pallas_agg as PA
-
-        agg_fn = functools.partial(PA.aggregate, interpret=True)
-    else:
-        agg_fn = A.aggregate
+                      cap: int, max_iters: int = 64, collect_k: int = 1024):
     groups = _group_ids(group_channels, page, cap, max_iters)
     # dense fast path may size output below cap (see _group_ids)
     out_cap = groups.group_valid.shape[0]
@@ -4963,7 +4866,7 @@ def _partial_agg_page(group_channels, aggregates, layouts, page: Page,
         for st in layout:
             vals, out_nulls, dic = _state_reduce(
                 st, blk, st.input_kind, True,
-                lambda data, nulls, k=st.input_kind: agg_fn(
+                lambda data, nulls, k=st.input_kind: A.aggregate(
                     groups, k, out_cap, data, nulls
                 ),
             )
@@ -5493,7 +5396,7 @@ def _probe_join_page_unique(left_keys, right_keys, join_type, defer,
     return out, matched, collision
 
 
-def _build_radix_join_index(left_keys, right_keys, layout, page: Page,
+def _build_pallas_join_index(left_keys, right_keys, layout, page: Page,
                             build: Page):
     """Pallas join index (kernel): hash-sorted build order + the
     layout-shaped per-unique-hash (start, count) tables. The probe page
@@ -5510,7 +5413,7 @@ def _build_radix_join_index(left_keys, right_keys, layout, page: Page,
     return (tuple(bcols), bvalid, perm, tables), overflow
 
 
-def _probe_radix_join_page(left_keys, right_keys, join_type, layout,
+def _probe_pallas_join_page(left_keys, right_keys, join_type, layout,
                            interpret, defer, page: Page, build: Page,
                            index, out_cap: int):
     """Probe one page through the Pallas range kernel, then the shared

@@ -27,11 +27,11 @@ enforced only by whichever test happened to trip:
      columns.
 
 Wiring: `Executor._verify_plan` runs `verify` when the `plan_check`
-session property enables it — "auto" is ON under pytest and
-`bench.py --prewarm`, OFF on the hot serving path (the check is
-pre-compile and costs ~1ms on bench-rung plans, but the serving path
-pays nothing by default). `tools/plan_audit.py` sweeps every bench
-rung and the TPC-H/TPC-DS test corpus through the same verifier and
+session property enables it — "auto" is ON under pytest or
+`PRESTO_TPU_PLAN_CHECK=1`, OFF on the hot serving path (the check is
+pre-compile and costs ~1ms on TPC-H plans, but the serving path pays
+nothing by default). `tools/plan_audit.py` sweeps the plans at served
+scale and the TPC-H/TPC-DS test corpus through the same verifier and
 exits nonzero on any violation.
 
 Violations raise PlanCheckError with POINTED messages: which node,
@@ -465,8 +465,8 @@ def check_buffers(report, violations: List[str],
     labels): they have NO chunked rewrite yet, the audit deliberately
     over-estimates them, and a test-forced tiny budget/fault line must
     not fail a query the engine executes correctly. strict=True (the
-    plan_audit CLI and bench --prewarm, which run against REAL
-    budgets) enforces every buffer."""
+    plan_audit CLI, which runs against REAL budgets) enforces every
+    buffer."""
     for b in report.buffers:
         if b.rows != SH.bucket(b.rows):
             violations.append(

@@ -7,9 +7,8 @@ tier by construction — Pages move between operators in process memory
 and cross a boundary only at the serialized exchange. The TPU build
 has a second, sneakier boundary: host RAM <-> HBM, crossed by
 `jax.device_put` / `jax.device_get` / numpy coercions on device
-values — and before this registry those crossings were scattered,
-unmetered, and invisible to the bench ladder ROADMAP item 6 wants to
-drive toward zero.
+values — and before this registry those crossings were scattered and
+unmetered.
 
 Two sides, one discipline (the QUERY_COUNTERS / LOCK_REGISTRY model):
 
@@ -26,8 +25,8 @@ Two sides, one discipline (the QUERY_COUNTERS / LOCK_REGISTRY model):
            registry counters (h2d_bytes / d2h_bytes / h2d_transfers /
            d2h_transfers + the computed transfer_wall_s), and emit an
            `xfer` span (obs.SPAN_KINDS) when that executor is traced,
-           so Chrome traces and critical_path() show copy time as its
-           own phase. A d2h pull is also host time BLOCKED ON THE
+           so Chrome traces show copy time as its own phase. A d2h
+           pull is also host time BLOCKED ON THE
            DEVICE (the value is ready when the programs that make it
            have run): it counts on `device_wait_us` with the two waits
            that cross no page, `devsync.drain` and the executor's
@@ -250,8 +249,8 @@ TRANSFER_REGISTRY: Dict[str, Tuple[str, str, str]] = {
     # ---- diagnostics / timing
     "devsync.drain": (
         "d2h", "control",
-        "forced-completion fence for honest timing (bench, "
-        "stats_drain): reads ONE element of the last leaf"),
+        "forced-completion fence for honest timing "
+        "(stats_drain): reads ONE element of the last leaf"),
     # ---- trace-time LUT embedding (jnp coercions of host arrays in
     # kernel builders: constant folding sized by dictionary/identity
     # cardinality, never by query data volume)
@@ -311,8 +310,8 @@ DATA_PLANE_MODULES = frozenset({
 
 # ------------------------------------------------------ process totals
 class _Totals:
-    """Process-lifetime transfer tallies (the /metrics, system.metrics
-    and loadbench overlay — per-query executors come and go on the
+    """Process-lifetime transfer tallies (the /metrics and
+    system.metrics overlay — per-query executors come and go on the
     concurrent server path, the process truth lives here)."""
 
     __slots__ = ("h2d_bytes", "d2h_bytes", "h2d_transfers",

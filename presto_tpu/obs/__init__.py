@@ -8,9 +8,8 @@ histograms behind JMX. Ours is one package with three surfaces:
   trace.py    the span recorder: query -> stage -> task -> attempt ->
               operator spans on ONE monotonic clock with ONE wall
               anchor per query, exported as a live QueryInfo tree
-              (/v1/query/{id}, system.runtime_tasks), a Chrome-trace
-              (Perfetto-loadable) JSON file, and a critical-path
-              summary (tools/analyze_rung.py).
+              (/v1/query/{id}, system.runtime_tasks) and a Chrome-trace
+              (Perfetto-loadable) JSON file.
   histo.py    log-bucketed latency histograms with Prometheus
               exposition — the p50/p95/p99 surface the concurrent-load
               benchmark (ROADMAP item 1) reads from /metrics.
@@ -38,7 +37,7 @@ from __future__ import annotations
 import os
 from typing import Dict, Optional
 
-from presto_tpu.obs.trace import QueryTrace, critical_path  # noqa: F401
+from presto_tpu.obs.trace import QueryTrace  # noqa: F401
 
 # span kind -> help text (rendered nowhere yet; the declaration is the
 # contract the lint enforces, exactly like QUERY_COUNTERS' help column)

@@ -23,8 +23,8 @@ happens at runtime:
 Zero-cost when off: `make_lock`/`make_condition` return plain
 `threading` primitives and `register_owner` is a no-op boolean check,
 so the serving path pays nothing. Armed (env
-`PRESTO_TPU_LOCK_SANITIZER=1`, the tier-1 conftest, `tools/loadbench
---sanitize`, `tools/chaos.py --sanitize`), every engine lock is a
+`PRESTO_TPU_LOCK_SANITIZER=1`, the tier-1 conftest,
+`tools/chaos.py --sanitize`), every engine lock is a
 `_SanitizedLock` and every registered owner's class is swapped for an
 instrumented subclass whose `__setattr__` checks the lock contract.
 Violations accumulate in a process-wide list (they never raise except

@@ -409,35 +409,3 @@ class QueryTrace:
         with open(path, "w") as f:
             json.dump(self.to_chrome(), f, indent=1, default=str)
         return path
-
-
-def critical_path(trace: QueryTrace) -> dict:
-    """The slowest chain through the span tree plus a per-kind wall
-    split (queue-vs-run-vs-fetch for distributed queries; attempt/
-    operator locally) — tools/analyze_rung.py's summary input."""
-    spans = trace.spans()
-    now = trace.now()
-    children: Dict[int, List[Span]] = {}
-    for sp in spans:
-        if sp.parent_id is not None:
-            children.setdefault(sp.parent_id, []).append(sp)
-    chain, cur = [], trace.root
-    while True:
-        kids = children.get(cur.span_id)
-        if not kids:
-            break
-        cur = max(kids, key=lambda s: s.dur(now))
-        chain.append({
-            "kind": cur.kind, "name": cur.name,
-            "ms": int(round(cur.dur(now) * 1000)),
-        })
-    by_kind: Dict[str, float] = {}
-    for sp in spans:
-        if sp.span_id == trace.root.span_id:
-            continue
-        by_kind[sp.kind] = by_kind.get(sp.kind, 0.0) + sp.dur(now)
-    return {
-        "chain": chain,
-        "by_kind_ms": {k: int(round(v * 1000))
-                       for k, v in sorted(by_kind.items())},
-    }

@@ -420,7 +420,7 @@ _MM_BACKEND: Optional[bool] = None
 def _mm_backend_ok() -> bool:
     """One-hot matmul aggregation only where the compiler fuses the
     n x G one-hot into the dot (MXU path). XLA:CPU materializes it —
-    gigabytes at bench shapes — so CPU (tests, oracle children) keeps
+    gigabytes at served shapes — so CPU (tests, oracle children) keeps
     the scatter path, which computes identical results.
     PRESTO_TPU_MM_AGG=1/0 overrides (CPU parity tests force it on
     tiny shapes)."""

@@ -22,11 +22,12 @@ outer-row emission (LEFT/RIGHT/FULL) and semi joins assemble from the match
 statistics returned here (reference: LookupJoinOperators factories,
 HashSemiJoinOperator).
 
-The Pallas radix-partitioned kernels in presto_tpu/ops/pallas_join.py
-(north-star requirement) replace the searchsorted range finder on TPU —
-they produce the same per-probe-row [lo, lo+count) candidate ranges and
-share expand_matches() below for verified expansion. The executor picks
-per join (pallas_join_enabled=auto: Pallas on TPU, sort elsewhere).
+The Pallas dim probe in presto_tpu/ops/pallas_join.py replaces the
+searchsorted range finder on TPU for builds of at most 2,048 rows — it
+produces the same per-probe-row [lo, lo+count) candidate ranges and
+shares expand_matches() below for verified expansion. The executor
+picks per join (pallas_join_enabled=auto: Pallas on TPU for such
+builds, sort elsewhere).
 """
 
 from __future__ import annotations
@@ -131,7 +132,7 @@ def hash_join_match(
 
     # method="sort" lowers to a concat-sort rank computation instead
     # of a log2(n)-iteration gather loop — measured 13x faster on TPU
-    # (64ms vs 1.06s for lo+hi at 2M x 1M; round-4 microbench)
+    # (64ms vs 1.06s for lo+hi at 2M x 1M; an earlier TPU runtime)
     lo = jnp.searchsorted(sorted_hash, phash, side="left", method="sort")
     hi = jnp.searchsorted(sorted_hash, phash, side="right", method="sort")
     counts = (hi - lo).astype(jnp.int64)
@@ -154,7 +155,7 @@ def expand_matches(
     """Flatten per-probe-row candidate ranges [lo, lo+counts) over the
     hash-sorted build order `perm` into a fixed-capacity match list,
     verifying true key equality per slot. Shared tail of the sort join
-    (searchsorted ranges) and the Pallas radix join (kernel-probed
+    (searchsorted ranges) and the Pallas dim probe (kernel-probed
     ranges) — the range *finder* is the only thing that differs."""
     build_cap = bvalid.shape[0]
     probe_cap = pvalid.shape[0]

@@ -1,40 +1,24 @@
-"""Pallas TPU hash-join kernels (north-star: "hash join as a Pallas
-radix-partitioned join", SURVEY §8.2.2).
+"""Pallas TPU hash-join kernel (SURVEY §8.2.2).
 
 The join contract is shared with the sort join (ops/join.py): an index
 over the HASH-SORTED build side where equal-hash rows form contiguous
 segments, and a probe that returns, per probe row, the segment range
 (start, count) of equal-hash build rows. ops/join.expand_matches then
 flattens ranges into verified matches identically for every range
-finder — searchsorted (sort join) or the open-addressing tables here.
+finder — searchsorted (sort join) or the open-addressing table here.
 
-Two table layouts, picked by plan_layout(build_capacity):
-
-1. **"dim"** — dimension-table layout, up to DIM_MAX_BUILD build rows.
-   The table is T radix tiles of 128 entries; each tile is replicated
-   across the 8 sublanes, so a probe block gathers entries with the ONE
-   per-lane gather this Mosaic toolchain lowers: jnp.take_along_axis on
-   an (8, 128) value along the lane axis (verified on hardware; every
-   wider/per-ref gather form crashes the tpu_compile_helper). Collision
-   chains stay inside a tile's 128 lanes. This is the REAL compiled
-   kernel and the default on TPU (pallas_join_enabled=auto) — it serves
-   the broadcast-side joins of star schemas (region/nation in Q5).
-
-2. **"radix"** — general bucketed layout up to RADIX_MAX_BUILD rows:
-   VMEM-sized buckets addressed by the hash's top bits, one (hash,
-   start, count) entry per unique hash. The probe is a true radix-
-   partitioned pass (ISSUE 18): a host-side partition-id pass bucket-
-   sorts the probe rows, then a 1-D grid probes each padded block
-   against the ONE bucket slice it belongs to, the block -> bucket map
-   riding in as a scalar-prefetch operand — O(N) HBM traffic instead
-   of the old (bucket x block) cross-product's O(buckets * N). The
-   kernel is correct and covered by the CPU suite in interpret mode,
-   but its per-lane table gather exceeds what this Mosaic version can
-   lower, so on TPU it runs only when forced (pallas_join_enabled=true)
-   and then in interpret mode (XLA-emulated grid). The blueprint is
-   written for the day the toolchain grows vector gather; until then
-   big builds default to the sort join, which is the better TPU
-   program anyway.
+One table layout, the **"dim"** (dimension-table) layout, for builds of
+up to DIM_MAX_BUILD rows (plan_layout answers None above that and the
+executor takes the sort join). The table is T radix tiles of 128
+entries; each tile is replicated across the 8 sublanes, so a probe
+block gathers entries with the ONE per-lane gather this Mosaic
+toolchain lowers: jnp.take_along_axis on an (8, 128) value along the
+lane axis (verified on hardware; every wider/per-ref gather form
+crashes the tpu_compile_helper). Collision chains stay inside a tile's
+128 lanes. This is the compiled kernel pallas_join_enabled=auto selects
+on a TPU — it serves the broadcast-side joins of star schemas
+(region/nation in Q5); off a TPU it runs in interpret mode, the test
+path.
 
 Reference: presto-main operator/{PagesIndex,JoinHash}.java — the
 address-sorted PagesIndex plus an open-addressing hash over row
@@ -62,11 +46,6 @@ DIM_MAX_BUILD = DIM_TILES_MAX * 128 // 2  # 2048 rows
 # per-step fixed cost)
 _DIM_GROUPS = 16
 
-# radix layout: buckets of 2^14 entries (4 x int32 arrays = 256 KB per
-# bucket slice)
-BUCKET_CAP = 1 << 14
-RADIX_MAX_BUILD = 1 << 20
-
 _MAX_ITERS = 64
 
 
@@ -91,14 +70,13 @@ def _mix32(lo: jnp.ndarray, hi: jnp.ndarray) -> jnp.ndarray:
 
 
 def plan_layout(build_cap: int):
-    """Static layout choice for a build of `build_cap` rows:
-    ("dim", tiles) or ("radix", (num_buckets, bucket_cap)). Hashable —
-    executors put it in jit cache keys."""
-    if build_cap <= DIM_MAX_BUILD:
-        total = max(128, 1 << (2 * build_cap - 1).bit_length())
-        return ("dim", total // 128)
-    total = max(BUCKET_CAP, 1 << (2 * build_cap - 1).bit_length())
-    return ("radix", (total // BUCKET_CAP, BUCKET_CAP))
+    """Static layout for a build of `build_cap` rows: ("dim", tiles),
+    or None above DIM_MAX_BUILD (the sort join's). Hashable —
+    executors bind it into jitted kernels."""
+    if build_cap > DIM_MAX_BUILD:
+        return None
+    total = max(128, 1 << (2 * build_cap - 1).bit_length())
+    return ("dim", total // 128)
 
 
 # ----------------------------------------------------------- index build
@@ -190,9 +168,8 @@ def build_index(bhash: jnp.ndarray, bvalid: jnp.ndarray, layout):
     """Build the (start, count) range index for `layout` (plan_layout).
 
     Returns (tables, perm, overflow): `perm` is the hash-sorted build
-    order that start/count ranges refer to; `tables` is layout-shaped:
-      dim:   4 x int32[T, 8, 128] (row-replicated tiles)
-      radix: 4 x int32[num_buckets * bucket_cap] (flat bucketed)
+    order that start/count ranges refer to; `tables` is
+    4 x int32[T, 8, 128] (row-replicated tiles).
     """
     # a VALID row whose hash equals the poison value would interleave
     # with poisoned invalid rows inside the max-hash segment and lose
@@ -206,31 +183,18 @@ def build_index(bhash: jnp.ndarray, bvalid: jnp.ndarray, layout):
     perm, sorted_h, entry, vstart, vcnt = _sorted_segments(bhash, bvalid)
     lo, hi = _split64(sorted_h)
     h32 = _mix32(lo, hi)
-    kind, spec = layout
-    if kind == "dim":
-        tiles = spec
-        tile = (
-            ((h32 >> jnp.uint32(7))
-             & jnp.uint32(tiles - 1)).astype(jnp.int32)
-            if tiles > 1 else jnp.zeros(h32.shape, jnp.int32)
-        )
-        tabs, overflow = _insert(
-            sorted_h, entry, vstart, vcnt, tile * 128, 128, tiles * 128
-        )
-        tabs = tuple(
-            jnp.broadcast_to(t.reshape(tiles, 1, 128), (tiles, 8, 128))
-            for t in tabs
-        )
-        return tabs, perm, overflow | poison_conflict
-    num_buckets, bucket_cap = spec
-    log2b = (num_buckets - 1).bit_length() if num_buckets > 1 else 0
-    bucket = (
-        (h32 >> jnp.uint32(32 - log2b)).astype(jnp.int32)
-        if log2b else jnp.zeros(h32.shape, jnp.int32)
+    _, tiles = layout
+    tile = (
+        ((h32 >> jnp.uint32(7))
+         & jnp.uint32(tiles - 1)).astype(jnp.int32)
+        if tiles > 1 else jnp.zeros(h32.shape, jnp.int32)
     )
     tabs, overflow = _insert(
-        sorted_h, entry, vstart, vcnt, bucket * bucket_cap, bucket_cap,
-        num_buckets * bucket_cap,
+        sorted_h, entry, vstart, vcnt, tile * 128, 128, tiles * 128
+    )
+    tabs = tuple(
+        jnp.broadcast_to(t.reshape(tiles, 1, 128), (tiles, 8, 128))
+        for t in tabs
     )
     return tabs, perm, overflow | poison_conflict
 
@@ -365,172 +329,12 @@ def _probe_dim(probe_hash, tables, tiles, *, interpret,
     return start.reshape(-1)[:n], cnt.reshape(-1)[:n]
 
 
-# ---------------------------------------------------------- radix probe
-
-
-def _radix_kernel(plo_ref, phi_ref, tlo_ref, thi_ref, tstart_ref,
-                  tcnt_ref, start_ref, cnt_ref, *, bucket_cap: int,
-                  log2b: int, max_probes: int):
-    from jax.experimental import pallas as pl
-
-    b = pl.program_id(0)
-    plo = plo_ref[:]
-    phi = phi_ref[:]
-    h32 = _mix32(plo, phi)
-    if log2b:
-        live0 = (
-            (h32 >> jnp.uint32(32 - log2b)).astype(jnp.int32) == b
-        )
-    else:
-        live0 = jnp.ones(plo.shape, jnp.bool_)
-    mask = jnp.uint32(bucket_cap - 1)
-    slot = (h32 & mask).astype(jnp.int32)
-    start = jnp.full(plo.shape, -1, dtype=jnp.int32)
-    cnt = jnp.zeros(plo.shape, dtype=jnp.int32)
-    live = live0.astype(jnp.int32)
-
-    def body(_i, carry):
-        slot, start, cnt, live = carry
-        live_b = live > 0
-        tlo = tlo_ref[slot]
-        thi = thi_ref[slot]
-        tc = tcnt_ref[slot]
-        occupied = tc > 0
-        hit = live_b & occupied & (tlo == plo) & (thi == phi)
-        start = jnp.where(hit, tstart_ref[slot], start)
-        cnt = jnp.where(hit, tc, cnt)
-        live = jnp.where(hit | ~occupied, jnp.int32(0), live)
-        nxt = (slot.astype(jnp.uint32) + jnp.uint32(1)) & mask
-        slot = jnp.where(live > 0, nxt.astype(jnp.int32), slot)
-        return slot, start, cnt, live
-
-    slot, start, cnt, live = jax.lax.fori_loop(
-        0, max_probes, body, (slot, start, cnt, live)
-    )
-    start_ref[:] = start
-    cnt_ref[:] = cnt
-
-
-def _probe_radix(probe_hash, tables, num_buckets, bucket_cap, *,
-                 interpret, block_rows: int = 2048,
-                 max_probes: int = _MAX_ITERS + 1):
-    """Partition-id pass + per-bucket probe (ISSUE 18).
-
-    The old shape ran a (num_buckets, nblocks) cross-product grid —
-    every probe block re-read against EVERY bucket slice, O(B * N)
-    HBM traffic with each row live in exactly one step. Now a host-
-    side partition-id pass buckets the rows first: sort probe rows by
-    their hash's bucket id, pad each bucket's run to a block_rows
-    multiple (<= num_buckets * (block_rows - 1) pad rows, static
-    bound), and run a 1-D (nblocks,) grid where each block probes
-    exactly the ONE bucket slice it belongs to. The block -> bucket
-    map is data-dependent, so it rides in as a scalar-prefetch operand
-    driving the table BlockSpec index_map — the Pallas radix-join
-    shape from the north-star (partition pass, then per-partition
-    build/probe with grid-blocked HBM tiling).
-
-    Pad slots carry hash 0 and probe like real rows (bounded by
-    max_probes), but their results are never gathered back."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    log2b = (num_buckets - 1).bit_length() if num_buckets > 1 else 0
-    n = probe_hash.shape[0]
-    plo, phi = _split64(probe_hash)
-    h32 = _mix32(plo, phi)
-    bucket = (
-        (h32 >> jnp.uint32(32 - log2b)).astype(jnp.int32)
-        if log2b else jnp.zeros(h32.shape, jnp.int32)
-    )
-    # partition-id pass: stable bucket sort + padded per-bucket runs
-    perm = jnp.argsort(bucket)
-    sbucket = bucket[perm]
-    counts = jnp.zeros((num_buckets,), jnp.int32).at[bucket].add(
-        jnp.int32(1)
-    )
-    padded = (
-        (counts + jnp.int32(block_rows - 1)) // jnp.int32(block_rows)
-    ) * jnp.int32(block_rows)
-    off = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts).astype(jnp.int32)]
-    )
-    pad_off = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(padded).astype(jnp.int32)]
-    )
-    idx = jnp.arange(n, dtype=jnp.int32)
-    # padded position of sorted row i: bucket base + rank within bucket
-    ppos = pad_off[sbucket] + (idx - off[sbucket])
-    # static ceiling: every bucket pads by < block_rows
-    npad = -(-(n + num_buckets * (block_rows - 1)) // block_rows)
-    npad *= block_rows
-    nblocks = npad // block_rows
-    plo_p = jnp.zeros((npad,), jnp.int32).at[ppos].set(
-        plo[perm], mode="drop")
-    phi_p = jnp.zeros((npad,), jnp.int32).at[ppos].set(
-        phi[perm], mode="drop")
-    # block -> bucket map (scalar prefetch): block k serves the bucket
-    # whose padded run covers row k * block_rows; trailing blocks past
-    # the last padded row clip to the final bucket and probe pad slots
-    bstarts = jnp.arange(nblocks, dtype=jnp.int32) * jnp.int32(
-        block_rows)
-    bmap = jnp.clip(
-        jnp.searchsorted(pad_off[1:], bstarts, side="right").astype(
-            jnp.int32),
-        0, num_buckets - 1,
-    )
-    pblk = pl.BlockSpec((block_rows,), lambda j, bmap: (j,))
-    tblk = pl.BlockSpec((bucket_cap,), lambda j, bmap: (bmap[j],))
-    # in-bucket rows need no bucket-id filter (log2b=0 => all live):
-    # the partition pass already routed each block to its one bucket
-    inner = functools.partial(
-        _radix_kernel, bucket_cap=bucket_cap, log2b=0,
-        max_probes=max_probes,
-    )
-
-    def kernel(bmap_ref, *refs):
-        # the scalar-prefetch operand only drives the index_maps; the
-        # probe body never reads it
-        inner(*refs)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nblocks,),
-        in_specs=[pblk, pblk, tblk, tblk, tblk, tblk],
-        out_specs=(pblk, pblk),
-    )
-    start_p, cnt_p = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
-            jax.ShapeDtypeStruct((npad,), jnp.int32),
-        ),
-        interpret=interpret,
-    )(bmap, plo_p, phi_p, *tables)
-    # gather each row's result from its padded slot, then undo the
-    # bucket sort
-    start = jnp.full((n,), -1, jnp.int32).at[perm].set(start_p[ppos])
-    cnt = jnp.zeros((n,), jnp.int32).at[perm].set(cnt_p[ppos])
-    return start, cnt
-
-
 def probe_index(probe_hash: jnp.ndarray, tables, layout, *,
                 interpret: bool = False):
     """Per probe row, the hash-sorted build segment (start, count) of
     equal-hash valid build rows ((-1, 0) when none)."""
-    kind, spec = layout
-    if kind == "dim":
-        return _probe_dim(probe_hash, tables, spec, interpret=interpret)
-    nb, bc = spec
-    return _probe_radix(probe_hash, tables, nb, bc, interpret=interpret)
-
-
-def layout_lowers_on_tpu(layout) -> bool:
-    """Whether this layout's probe kernel actually lowers through
-    Mosaic on the current toolchain (the dim kernel does; the radix
-    kernel's per-lane table gather does not and must run interpreted —
-    see module docstring)."""
-    return layout[0] == "dim"
+    _, tiles = layout
+    return _probe_dim(probe_hash, tables, tiles, interpret=interpret)
 
 
 # ------------------------------------------------------- unique wrapper

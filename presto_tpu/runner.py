@@ -198,7 +198,7 @@ class LocalRunner:
         """Session properties -> live executor knobs. The ONE wiring
         site (reference: SystemSessionProperties consumption) — every
         driver of the executor (execute() below, the DCN worker/
-        coordinator, the bench tools) must call this rather than copy
+        coordinator, the tools) must call this rather than copy
         the mapping, so the knob set cannot drift between drivers."""
         ex = self.executor
         ex.use_jit = bool(self.session.get("tpu_offload_enabled"))
@@ -224,7 +224,7 @@ class LocalRunner:
             self.session.get("device_memory_budget")
         )
         # pre-compile plan verification (exec/plan_check.py): "auto"
-        # resolves inside the executor (on under pytest / prewarm)
+        # resolves inside the executor (on under pytest)
         ex.plan_check = self.session.get("plan_check")
         # devices receiving repartitioned rows (0 = whole mesh);
         # consumed by DistExecutor._route_devices — harmless no-op on

@@ -443,7 +443,7 @@ class MemoryArbiter:
 def _xfer_totals():
     """Process-total transfer tallies (exec/xfer.py choke points)
     under the registry counter names — per-query executors come and
-    go on the concurrent path; the copy-tax truth loadbench reads is
+    go on the concurrent path; the copy-tax truth /metrics reports is
     the process accumulation."""
     from presto_tpu.exec import xfer as XFER
 
@@ -455,7 +455,7 @@ def _wire_totals():
     dist/connpool.py reuse) under the registry counter names — same
     rationale as _xfer_totals: worker task executors never surface
     on the scrape path, the process accumulation is the fleet truth
-    loadbench grades wire efficiency from."""
+    for wire efficiency."""
     from presto_tpu.dist import connpool as CONNPOOL
     from presto_tpu.dist import serde as SERDE
 
@@ -501,7 +501,7 @@ class QueryManager:
     # launch/batch counters accumulated across the concurrent path's
     # per-query executors at completion (ISSUE 17): those executors
     # are discarded per query, so the PROCESS aggregate — the number
-    # the loadbench launches-per-query A/B reads — lives here and
+    # /metrics reports — lives here and
     # overlays the registry snapshot on /metrics + system.metrics
     # (the _result_cache_totals rationale applied to dispatch)
     _EXEC_TOTAL_SUMS = (
@@ -559,9 +559,8 @@ class QueryManager:
         self.exec_counter_totals: Dict[str, int] = {}
         # admission queue depth (ISSUE 17): queries currently waiting
         # for admission (resource-group slot / memory reservation /
-        # the serial exec lock) and the lifetime peak — the number the
-        # cache-bypass loadbench assertion reads: replays must never
-        # inflate this line
+        # the serial exec lock) and the lifetime peak (/metrics):
+        # cache replays must never inflate this line
         self.queued_now = 0
         self.peak_queued = 0
         # latency histograms (obs/histo.py): bucketed query wall and
@@ -1003,8 +1002,8 @@ class QueryManager:
             # not the bootstrap executor: on the concurrent path each
             # query runs its own executor whose counters are
             # discarded, while the store the queries actually shared
-            # keeps the fleet truth (the hit-rate surface
-            # tools/loadbench.py scrapes)
+            # keeps the fleet truth (the hit-rate surface /metrics
+            # serves)
             snap.update(_result_cache_totals())
             # transfer counters overlay the same way (exec/xfer.py
             # process totals — the aggregate copy tax next to QPS/p99)
@@ -1019,7 +1018,7 @@ class QueryManager:
             # path's discarded per-query executors (ISSUE 17): sums
             # ADD to the bootstrap executor's own counts (zero when
             # idle), the width gauge takes the max — the aggregate
-            # launches-per-query truth the loadbench A/B reads
+            # launches-per-query truth
             with self._lock:
                 for name in self._EXEC_TOTAL_SUMS:
                     snap[name] = snap.get(name, 0) + \
@@ -1059,8 +1058,8 @@ class QueryManager:
             lines += [f"# TYPE presto_tpu_{name}_total counter",
                       f"presto_tpu_{name}_total {cc[key]}"]
         # cache-aware admission (ISSUE 17): replays that never took a
-        # resource-group slot — next to the hit-rate so loadbench can
-        # assert near-zero-cost hits stop occupying the queue
+        # resource-group slot — next to the hit-rate, so a reader sees
+        # that near-zero-cost hits stop occupying the queue
         with self._lock:
             bypasses = self.cache_admission_bypasses
             peak_q = self.peak_queued

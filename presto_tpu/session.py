@@ -108,10 +108,12 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
         ),
         PropertyMetadata(
             "pallas_join_enabled",
-            "use the Pallas join kernels (radix-partitioned general "
-            "join + unique-key fast path) for eligible joins; auto = "
-            "on when running on TPU, off elsewhere (the interpreted "
-            "kernels exist for CPU testing, not speed)",
+            "use the Pallas dim probe (ops/pallas_join.py) as the "
+            "range finder of eligible joins whose build side holds at "
+            "most 2,048 rows (general equi-join + unique-key fast "
+            "path); larger builds take the sort join. auto = on a TPU "
+            "only; true = also off a TPU, where the kernel runs "
+            "interpreted (the test path, not speed); false = never",
             str, "auto",
             validate=lambda v: v in ("auto", "true", "false"),
         ),
@@ -296,10 +298,10 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "schema-consistent operator/fragment edges, ladder-"
             "quantized capacities under the device fault line, "
             "canonical jit-cache key material, deterministic split "
-            "assignment fields. auto = on under pytest and bench "
-            "--prewarm, off on the hot serving path; true/false "
-            "force. Violations fail the query BEFORE compile with a "
-            "pointed PlanCheckError",
+            "assignment fields. auto = on under pytest or "
+            "PRESTO_TPU_PLAN_CHECK=1, off on the hot serving path; "
+            "true/false force. Violations fail the query BEFORE "
+            "compile with a pointed PlanCheckError",
             str, "auto",
             validate=lambda v: v in ("auto", "true", "false"),
         ),

@@ -114,22 +114,12 @@ def test_dim_pallas_probe_lowers_through_mosaic(one_chip):
     from presto_tpu.ops import pallas_join as PJ
 
     layout = PJ.plan_layout(1800)
-    assert layout[0] == "dim" and PJ.layout_lowers_on_tpu(layout)
+    assert layout[0] == "dim"
     tables = tuple(_spec((layout[1], 8, 128), jnp.int32, one_chip)
                    for _ in range(4))
     compiled = _compile(
         lambda ph, t: PJ.probe_index(ph, t, layout, interpret=False),
         _spec((100352,), jnp.uint64, one_chip), tables)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_partition_ids_lower_through_mosaic(one_chip):
-    from presto_tpu.dist import spool as SPOOL
-
-    compiled = _compile(
-        lambda pg: SPOOL._pallas_part_ids(
-            pg, (0, 1), (None, None), 8, interpret=False),
-        _bigint_page(PAGE_ROWS, one_chip))
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -8,8 +8,7 @@ server" prerequisite turned into a test: before the cache can default
 on, concurrent clients hammering the shared store must produce
 IDENTICAL rows per statement and ZERO sanitizer violations (no
 lock-order inversion, no unlocked shared-attr write anywhere in the
-engine while the race runs). tools/loadbench.py --sanitize is the
-same gate at benchmark scale.
+engine while the race runs).
 
 ISSUE 17 extends the suite to the multi-tenant dispatch plane:
 cross-query launch batching (batched vs solo vs sqlite-oracle row

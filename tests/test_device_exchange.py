@@ -5,9 +5,8 @@ Covers the three tentpole layers plus the satellites:
     splitmix64 path — same partition assignment per key type (int /
     float incl. -0.0 and NaN / bool / short+long decimal / dictionary)
     including the NULL sentinel;
-  - ladder-bucket compaction + the skew->overflow-flag contract, and
-    the Pallas partition-id variant (interpret mode, the CPU test
-    path: self-consistent, in-range, partition-complete);
+  - ladder-bucket compaction + the skew->overflow-flag contract;
+    exchange routing has ONE hash, whatever pallas_join_enabled says;
   - the acceptance pin: a forced-partitioned distributed q3-family
     query over same-process workers completes its EXCHANGE PHASE with
     zero h2d/d2h process-total deltas (measured at the last stage
@@ -102,13 +101,17 @@ def test_device_hash_parity_per_key_type(keys):
         assert np.array_equal(host % nparts, dev % nparts)
 
 
-def test_device_partition_matches_host_partition():
-    """Row multisets per partition agree between the tiers (device
-    emits every partition incl. empties; host skips empties)."""
+@pytest.mark.parametrize("pallas_join", ["auto", "force"])
+def test_device_partition_matches_host_partition(pallas_join):
+    """Row multisets per partition agree between the tiers for every
+    key type (device emits every partition incl. empties; host skips
+    empties) — also under pallas_join_enabled=true: exchange routing
+    has one hash, so a mixed pool co-partitions."""
     page = _key_page()
     ex = Executor({"tpch": TpchConnector(SF)})
     ex.device_exchange = "true"
-    for keys in ((0,), (3,), (0, 1)):
+    ex.pallas_join = pallas_join
+    for keys in ((0,), (1,), (2,), (3,), (4,), (5,), (0, 1)):
         dev = {
             p: sorted(map(repr, pp.to_pylist()))
             for p, pp in SPOOL.device_partition_pages(ex, page, keys, 4)
@@ -143,25 +146,6 @@ def test_device_partition_caps_ride_the_ladder():
     ex2._capacity_boost = 4
     parts2 = SPOOL.device_partition_pages(ex2, page, (0,), 8)
     assert all(pp.capacity == 4 * cap for _, pp in parts2)
-
-
-def test_pallas_partition_variant_interpret():
-    """pallas_join_enabled=force runs the Pallas partition-id variant
-    in interpret mode (the CPU test path): deterministic,
-    partition-complete, and parity with itself across calls. It is
-    NOT hash-compatible with the splitmix64 tier by design — routing
-    needs only self-consistency within one exchange."""
-    page = _key_page()
-    ex = Executor({"tpch": TpchConnector(SF)})
-    ex.device_exchange = "true"
-    ex.pallas_join = "force"
-    a = SPOOL.device_partition_pages(ex, page, (0, 1), 4)
-    b = SPOOL.device_partition_pages(ex, page, (0, 1), 4)
-    rows_a = [sorted(map(repr, pp.to_pylist())) for _, pp in a]
-    rows_b = [sorted(map(repr, pp.to_pylist())) for _, pp in b]
-    assert rows_a == rows_b
-    total = sum(len(r) for r in rows_a)
-    assert total == len(page.to_pylist())
 
 
 # ------------------------------------------- acceptance: zero-crossing

@@ -134,7 +134,7 @@ def test_fault_rows_ceiling_chunks_everything(conn, base):
 
 
 def test_sqlite_oracle_parity_under_tiny_budget(conn):
-    """BASELINE.md's correctness gate against the forced-chunked
+    """The sqlite correctness gate against the forced-chunked
     engine: sqlite computes the same join-aggregate."""
     from tests.oracle import load_sqlite
 

@@ -1,6 +1,6 @@
 """Pallas hash-join probe kernel: correctness vs a numpy oracle in
 interpret mode (runs on the CPU CI mesh; the real-TPU lowering is
-exercised by bench.py's join microbench)."""
+compiled by tests/test_chip_compile.py)."""
 
 import numpy as np
 import pytest
@@ -67,20 +67,22 @@ def _ranges_oracle(bhash, bvalid, phash):
 
 
 @pytest.mark.parametrize(
-    "layout", [("radix", (1, 4096)), ("radix", (4, 1024)), ("dim", 16)]
+    "layout,universe", [(("dim", 1), 48), (("dim", 16), 500),
+                        (("dim", 32), 1000)]
 )
-def test_ranges_match_oracle(layout):
+def test_ranges_match_oracle(layout, universe):
     # duplicate keys: draws from a small universe so hash segments have
-    # length > 1; multi-bucket/multi-tile layouts exercise the
-    # partitioned tables
+    # length > 1 (the table holds one entry a distinct hash, at most
+    # half of tiles * 128); multi-tile layouts exercise the partitioned
+    # tables
     rng = np.random.default_rng(3)
     nb, np_ = 1500, 4096
-    bhash = rng.choice(500, size=nb).astype(np.uint64) * np.uint64(
+    bhash = rng.choice(universe, size=nb).astype(np.uint64) * np.uint64(
         0x9E3779B97F4A7C15
     )
     bvalid = rng.random(nb) < 0.9
     phash = np.concatenate([
-        rng.choice(500, size=np_ - 64).astype(np.uint64)
+        rng.choice(universe, size=np_ - 64).astype(np.uint64)
         * np.uint64(0x9E3779B97F4A7C15),
         rng.integers(1, 2**63, size=64, dtype=np.uint64),  # misses
     ])
@@ -113,7 +115,7 @@ def test_poison_hash_conflict_raises_overflow():
     MAXH = np.uint64(0xFFFFFFFFFFFFFFFF)
     bhash = np.array([MAXH, 5, MAXH, 7], dtype=np.uint64)
     bvalid = np.array([True, False, True, True])
-    layout = ("radix", (1, 64))
+    layout = ("dim", 1)
     tabs, perm, overflow = PJ.build_index(
         jnp.asarray(bhash), jnp.asarray(bvalid), layout
     )

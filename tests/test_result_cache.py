@@ -270,7 +270,7 @@ def test_ttl_expiry(runner):
 
 # ------------------------------------------------------ oracle parity
 def test_oracle_parity_on_hits(runner, conn):
-    """BASELINE.md's correctness gate applied to REPLAYED results: the
+    """The sqlite correctness gate applied to REPLAYED results: the
     hit rows match sqlite over the same generated data."""
     from tests.oracle import load_sqlite
 

@@ -270,36 +270,33 @@ def test_tpu_budget_needs_the_device_memory_limit(monkeypatch):
     assert MB.resolve_budget(0, "tpu") == (16 << 30) * 7 // 8
 
 
-def test_forced_pallas_layout_raises_on_tpu(monkeypatch):
-    """pallas_join_enabled=force on a TPU backend never falls back to
-    interpret mode: a layout (or the Pallas aggregation) that does not
-    lower raises, naming it. The CPU keeps interpret mode."""
+def test_pallas_join_policy_by_backend(monkeypatch):
+    """pallas_join_enabled: auto allows the dim probe on a TPU only;
+    true allows it everywhere, interpreted off a TPU (the test path)
+    and compiled on one; builds above DIM_MAX_BUILD have no layout
+    (the sort join's). Nothing raises."""
     import jax
 
     from presto_tpu.exec.executor import Executor
     from presto_tpu.ops import pallas_join as PJ
 
-    radix = PJ.plan_layout(1 << 16)
-    dim = PJ.plan_layout(1000)
-    assert radix[0] == "radix" and dim[0] == "dim"
-    assert Executor._pallas_interpret(radix) is True  # CPU: interpret
-    assert Executor._pallas_interpret(dim) is True
+    assert PJ.plan_layout(1000) == ("dim", 16)
+    assert PJ.plan_layout(PJ.DIM_MAX_BUILD) == ("dim", PJ.DIM_TILES_MAX)
+    assert PJ.plan_layout(PJ.DIM_MAX_BUILD + 1) is None
 
     ex = Executor({"tpch": TpchConnector(scale=0.001)})
-    ex.pallas_join = "force"
-    assert ex._pallas_agg_on() is True
+    on_cpu = {"off": False, "auto": False, "force": True}
+    for mode, allowed in on_cpu.items():
+        ex.pallas_join = mode
+        assert ex._pallas_mode_allows() is allowed, mode
+    assert Executor._pallas_interpret() is True
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert Executor._pallas_interpret(dim) is False  # lowers for real
-    with pytest.raises(NotImplementedError, match="'radix'"):
-        Executor._pallas_interpret(radix)
-    with pytest.raises(NotImplementedError,
-                       match="Pallas aggregation"):
-        ex._pallas_agg_on()
-    ex.pallas_join = "auto"
-    assert ex._pallas_agg_on() is False
-    assert ex._pallas_mode_allows(dim) is True
-    assert ex._pallas_mode_allows(radix) is False
+    on_tpu = {"off": False, "auto": True, "force": True}
+    for mode, allowed in on_tpu.items():
+        ex.pallas_join = mode
+        assert ex._pallas_mode_allows() is allowed, mode
+    assert Executor._pallas_interpret() is False  # lowers for real
 
 
 def test_explain_analyze_reports_compile_counters(conn):

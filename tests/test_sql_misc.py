@@ -113,7 +113,7 @@ class TestMisc:
 
 
 class TestAdviceRound1Regressions:
-    """Regressions for the round-1 advisor findings (ADVICE.md)."""
+    """Regressions for the round-1 advisor findings."""
 
     def test_case_mixing_two_dictionary_columns(self, runner):
         # CASE selecting between two differently-coded string columns must

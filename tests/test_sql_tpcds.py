@@ -125,13 +125,6 @@ _DS_ORACLE = {
 }
 
 
-def ds_oracle(qid: int):
-    """(oracle sql, float-tolerance column set) per TPC-DS query —
-    consumed by bench.py's oracle cross-check and sqlite baseline."""
-    sql, float_cols, _round_cols = _DS_ORACLE[qid]
-    return sql, float_cols
-
-
 def _norm(row, float_cols, round_cols=frozenset()):
     out = []
     for j, v in enumerate(row):

@@ -224,9 +224,98 @@ def test_every_registry_counter_reaches_metrics_surfaces(tiny_runner):
         list(CTRS.snapshot(tiny_runner.executor).items())
     )
     assert set(CTRS.QUERY_COUNTERS) <= set(rows)
-    # analyze_rung prints every key of the stats counters dict
-    # (sorted(ctr) in tools/analyze_rung.py), so the EXPLAIN ANALYZE
-    # contract above IS the analyze_rung contract.
+
+
+# ------------------------------------------------------ tools and docs
+def _repo_text(rel: str) -> str:
+    import os
+
+    from tools.lint import REPO
+
+    with open(os.path.join(REPO, rel)) as f:
+        return f.read()
+
+
+def _section(text: str, start: str, stop: str) -> str:
+    """``text`` from the heading line starting with ``start`` up to
+    the next line starting with ``stop`` (or the end)."""
+    import re
+
+    m = re.search(rf"^{re.escape(start)}.*$", text, re.M)
+    assert m, f"no heading {start!r}"
+    rest = text[m.end():]
+    n = re.search(rf"^{re.escape(stop)}", rest, re.M)
+    return rest[:n.start()] if n else rest
+
+
+def test_every_tool_has_a_caller_or_a_readme_line():
+    """A program under tools/ is run by tools/ci_static.sh, imported
+    by a tier-1 test, or named in README's Tools section — a tool
+    nothing runs and nothing documents is the debt ISSUE 31 removed —
+    and every file ci_static.sh names exists."""
+    import glob
+    import os
+    import re
+
+    from tools.lint import REPO
+
+    ci = _repo_text("tools/ci_static.sh")
+    for rel in set(re.findall(r"\b(?:tools|tests)/[\w/]+\.py\b", ci)):
+        assert os.path.isfile(os.path.join(REPO, rel)), rel
+    ran = set(re.findall(r"\btools/(\w+)\.py\b", ci))
+    for mod in re.findall(r"-m tools\.(\w+)", ci):
+        assert os.path.exists(os.path.join(REPO, "tools", mod)) or \
+            os.path.isfile(os.path.join(REPO, "tools", mod + ".py")), mod
+    imported = set()
+    for path in glob.glob(os.path.join(REPO, "tests", "*.py")):
+        with open(path) as f:
+            imported |= set(re.findall(
+                r"^\s*from tools\.(\w+) import", f.read(), re.M))
+    documented = _section(_repo_text("README.md"), "## Tools", "## ")
+    for path in glob.glob(os.path.join(REPO, "tools", "*.py")):
+        name = os.path.basename(path)[:-3]
+        if name == "__init__":
+            continue
+        assert (name in ran or name in imported
+                or f"`tools/{name}.py`" in documented), (
+            f"tools/{name}.py is run by nothing, imported by no test "
+            f"and absent from README's Tools section")
+
+
+@pytest.mark.parametrize("doc,bounds", [
+    ("README.md", None),
+    ("PERF.md", ("## 3.", "## 5.")),
+], ids=["README", "PERF-3-4"])
+def test_documented_programs_exist(doc, bounds):
+    """Every backticked ``tools/...`` path and bare ``name.py`` (a
+    file at the repo root or the package root) in README.md and in
+    PERF.md's layer and cell sections exists in the tree."""
+    import os
+    import re
+
+    from tools.lint import REPO
+
+    text = _repo_text(doc)
+    if bounds:
+        text = _section(text, *bounds)
+    missing = []
+    for tok in re.findall(r"`([^`\n]+)`", text):
+        words = tok.split()
+        if words and words[0] in ("python", "python3") and len(words) > 1:
+            words = words[1:]
+        if not words:
+            continue
+        w = words[0]
+        if w.startswith("tools/"):
+            ok = os.path.exists(os.path.join(REPO, w))
+        elif re.fullmatch(r"\w+\.py", w):
+            ok = any(os.path.isfile(os.path.join(REPO, d, w))
+                     for d in ("", "presto_tpu"))
+        else:
+            continue
+        if not ok:
+            missing.append(w)
+    assert not missing, f"{doc} names programs not in the tree: {missing}"
 
 
 # --------------------------------------------------- plan_check wiring

@@ -21,8 +21,8 @@ Covers the subsystem contract by contract:
   - tailing cursors: exactly-the-delta rows per poll, the IVM path
     for registered view shapes, and a concurrent appender x 4 tailing
     clients at zero lock-sanitizer violations;
-  - counter registration on every surface and the loadbench
-    append-writers harness.
+  - counter registration on every surface and an append writer
+    beside an IVM reader.
 """
 
 import collections
@@ -554,16 +554,48 @@ def test_counters_registered_and_surfaced(tail_server):
         assert metric in text
 
 
-def test_loadbench_append_writers_smoke():
-    from tools.loadbench import run_append_load
+def test_append_writer_beside_ivm_reader():
+    """A writer appends while a reader refreshes an IVM-safe view:
+    every refresh folds a delta (never a full recompute), every append
+    is counted, and the settled rows equal a cold recompute."""
+    conn, rng = _mkconn(256)
+    r = _runner(conn)
+    sink = r.executor
+    view = IVM.IvmRegistry().register(r, "dash", VIEW_SQL)
+    IVM.refresh(view, session=r.session, sink=sink)  # settle + compile
+    appends, errors = 12, []
+    done = threading.Event()
 
-    out = run_append_load(writers=1, readers=1, duration_s=1.2,
-                          rows_per_append=64, seed=0)
-    assert out["errors"] == 0
-    assert out["appends"] >= 1
-    assert out["ivm_refreshes"] >= 1
-    assert out["ivm_full_recomputes"] == 0
-    assert out["stream_appends_seen"] == out["appends"]
+    def writer():
+        try:
+            for _ in range(appends):
+                conn.append("events", _batch(rng, 64))
+                sink.count_stream_append()
+                done.wait(0.01)  # pace: leave the reader CPU to fold
+        except Exception as e:  # noqa: BLE001 - surfaced by assert
+            errors.append(e)
+        done.set()
+
+    def reader():
+        try:
+            while not done.is_set():
+                conn.wait_for_offset("events", view.settled_offset(), 0.2)
+                IVM.refresh(view, session=r.session, sink=sink)
+        except Exception as e:  # noqa: BLE001 - surfaced by assert
+            errors.append(e)
+
+    threads = [threading.Thread(target=f, daemon=True)
+               for f in (writer, reader)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors
+    assert sink.stream_appends_seen == appends
+    assert sink.ivm_refreshes >= 2 and sink.ivm_full_recomputes == 0
+    _n, rows, _t = IVM.refresh(view, session=r.session, sink=sink)
+    assert view.settled_offset() == conn.offset("events") == 256 + 64 * appends
+    _rows_close(rows, r.execute(VIEW_SQL).rows)
 
 
 # ------------------------------------------- review-hardened contracts

@@ -1,23 +1,16 @@
 #!/usr/bin/env bash
-# Pre-PR static gate (ISSUE 6 + ISSUE 11 + ISSUE 12 + ISSUE 16): the
-# engine-invariant linter, the concurrency soundness pass (lock
-# registry + acquisition graph + blocking-under-lock), the
-# host<->device transfer audit (transfer registry + plane
-# classification + choke-point routing), the full plan audit
-# (bench rungs + TPC-H/TPC-DS corpus, strict mode), and the wire-serde
-# property suite (codec x type round-trip matrix, byte-stability,
-# truncation/corruption rejection — the pure-serde subset; the
-# WorkerServer-backed streaming/pool tests stay in tier 1), plus the
-# sanitized serving smoke (ISSUE 17: a bounded loadbench pass racing
-# the concurrent-admission/batching locks under the runtime
-# sanitizer), and the interpret-mode Pallas smoke (ISSUE 18: radix
-# join + segmented reduction vs host oracles, no device needed).
-# All legs but the smokes are pure host Python — nothing
-# compiles or touches a device — so the whole gate runs in well under
-# 90 s on the 2-core box (combined budget: <= 30 s for the static
-# rules, the rest for the plan audit + serde suite + smoke).
-# bench.py --prewarm runs the same plan verifier per rung before
-# compiling.
+# Pre-PR static gate: the engine-invariant linter, the concurrency
+# soundness pass (lock registry + acquisition graph +
+# blocking-under-lock), the host<->device transfer audit (transfer
+# registry + plane classification + choke-point routing), the full
+# plan audit (plans at served scale + TPC-H/TPC-DS corpus, strict
+# mode), and the wire-serde property suite (codec x type round-trip
+# matrix, byte-stability, truncation/corruption rejection — the
+# pure-serde subset; the WorkerServer-backed streaming/pool tests stay
+# in tier 1). All legs are pure host Python — nothing compiles or
+# touches a device. The serving races under the lock sanitizer run in
+# tier 1 (tests/test_concurrent_serving.py), the Pallas kernel in
+# tests/test_pallas_join.py.
 #
 # Usage: tools/ci_static.sh   (exit nonzero on any finding/violation)
 set -euo pipefail
@@ -42,18 +35,5 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
     tests/test_wire_serde.py -q -p no:cacheprovider \
     -k "not spooled_task and not connpool and not streaming \
         and not q3_family and not executor_surface"
-
-echo "# ci_static: interpret-mode Pallas smoke (tools/pallas_smoke.py)" >&2
-# ISSUE 18: radix hash-join probe + segmented reduction on a seeded
-# page, oracle-checked in pure CPU interpret mode — no device, < 5 s
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python tools/pallas_smoke.py
-
-echo "# ci_static: sanitized serving smoke (tools/loadbench.py)" >&2
-# ISSUE 17: a bounded concurrent-load pass with the lock sanitizer
-# armed — N protocol clients x the shared result cache x cache-aware
-# admission x the cross-query launch batcher race deliberately; any
-# lock-order inversion or unlocked shared-attr write fails the gate
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.loadbench \
-    --sanitize --smoke > /dev/null
 
 echo "# ci_static: clean in $(( $(date +%s) - t0 ))s" >&2

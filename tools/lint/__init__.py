@@ -17,7 +17,7 @@ counter plumbing fixes):
                   in exec/ or dist/) is declared in
                   exec/counters.QUERY_COUNTERS — the registry every
                   surfacing layer (EXPLAIN ANALYZE, /metrics,
-                  system.metrics, analyze_rung) renders.
+                  system.metrics) renders.
   excepts         no bare `except:`; a broad `except Exception` must
                   re-raise or carry an explained annotation
                   (`# noqa: BLE001 - <why>` or `# lint: broad-ok -
@@ -44,8 +44,8 @@ counter plumbing fixes):
                   .phase(...) span recorder call) is declared in obs.SPAN_KINDS, and
                   every declared kind has an emission site — the
                   QUERY_COUNTERS discipline applied to the trace
-                  vocabulary, so the QueryInfo tree, Chrome export,
-                  and analyze_rung's phase split cannot drift.
+                  vocabulary, so the QueryInfo tree and the Chrome
+                  export cannot drift.
 
 Run: `python -m tools.lint` (exit 1 on findings); tier-1 runs the
 same checks via tests/test_static_analysis.py, and tools/ci_static.sh
@@ -108,9 +108,6 @@ def _py_files(*rel_roots: str) -> List[str]:
     out = []
     for root in rel_roots:
         abs_root = os.path.join(REPO, root)
-        if os.path.isfile(abs_root):
-            out.append(abs_root)
-            continue
         for dirpath, dirnames, filenames in os.walk(abs_root):
             dirnames[:] = [d for d in dirnames
                            if d != "__pycache__" and
@@ -224,7 +221,7 @@ def check_session_props() -> List[Finding]:
                 f"property {name!r}"))
     # consumption: every property must be read somewhere in the engine
     consumed: Set[str] = set()
-    for path in _py_files("presto_tpu", "tools", "bench.py"):
+    for path in _py_files("presto_tpu", "tools"):
         tree, _ = _parse(path)
         for node in ast.walk(tree):
             # READS only — a session.set() write is not consumption
@@ -251,23 +248,6 @@ def check_session_props() -> List[Finding]:
                 "session-props", "README.md", 1,
                 f"etc key {etc_key!r} is undocumented — add it to "
                 f"README's deployment-config table"))
-    # PR-13 mixed-pool caveat pin (ISSUE 18 satellite): the Pallas
-    # exchange partition-id hash is NOT compatible with the splitmix64
-    # tier, so pallas-join.enabled's doc row must carry the warning
-    # that a per-process backend auto-probe would mis-route
-    # co-partitioned keys on a mixed pool — a silently-dropped caveat
-    # here re-opens a wrong-results hole, hence a build gate
-    pj_row = next(
-        (ln for ln in readme.splitlines()
-         if ln.strip().startswith("| `pallas-join.enabled`")), "")
-    if "mixed pool" not in pj_row or "mis-route" not in pj_row:
-        out.append(Finding(
-            "session-props", "README.md", 1,
-            "the `pallas-join.enabled` config-table row must state "
-            "the mixed-pool hashing caveat (Pallas partition ids "
-            "are not splitmix64-compatible; auto-probing the "
-            "backend per process would mis-route co-partitioned "
-            "keys)"))
     return out
 
 
@@ -349,8 +329,8 @@ def check_counters() -> List[Finding]:
             "counters", path, line,
             f"counter {name!r} (zero-initialized and incremented) is "
             f"not declared in exec/counters.QUERY_COUNTERS — it will "
-            f"not reach EXPLAIN ANALYZE, /metrics, system.metrics, "
-            f"or analyze_rung"))
+            f"not reach EXPLAIN ANALYZE, /metrics or "
+            f"system.metrics"))
     for name in sorted(QUERY_COUNTERS):
         if name not in zero_init or name not in written:
             out.append(Finding(
@@ -683,8 +663,7 @@ def check_spans(paths=None) -> List[Finding]:
 
     out: List[Finding] = []
     emitted: Dict[str, Tuple[str, int]] = {}
-    for path in (paths or _py_files("presto_tpu", "tools",
-                                    "bench.py")):
+    for path in (paths or _py_files("presto_tpu", "tools")):
         tree, _ = _parse(path)
         for node in ast.walk(tree):
             if isinstance(node, ast.Call) and \
@@ -701,7 +680,7 @@ def check_spans(paths=None) -> List[Finding]:
                 "spans", path, line,
                 f"span kind {kind!r} is emitted but not declared in "
                 f"obs.SPAN_KINDS — trace surfaces (QueryInfo tree, "
-                f"Chrome export, analyze_rung) would carry an "
+                f"Chrome export) would carry an "
                 f"undocumented vocabulary; declare it with help text"))
     for kind in sorted(set(SPAN_KINDS) - set(emitted)):
         out.append(Finding(
@@ -721,7 +700,7 @@ def run_lint(rules=ALL_RULES) -> List[Finding]:
     findings: List[Finding] = []
     if "excepts" in rules:
         findings += check_excepts(
-            _py_files("presto_tpu", "tools", "bench.py"))
+            _py_files("presto_tpu", "tools"))
     if "session-props" in rules:
         findings += check_session_props()
     if "counters" in rules:

@@ -1,19 +1,18 @@
-"""Static plan audit: sweep every bench-rung plan and the TPC-H/
-TPC-DS test corpus through the pre-compile plan verifier
+"""Static plan audit: sweep the plans at served scale (SCALE_PLANS)
+and the TPC-H/TPC-DS test corpus through the pre-compile plan verifier
 (exec/plan_check.py, strict mode) and exit nonzero on any violation.
 
 Reference: presto-verifier's suite replay, applied to PLANS instead of
 results — the point is catching invariant drift (schema-inconsistent
 edges, off-ladder capacities, non-canonical jit keys, missing split
 determinism) across the WHOLE query corpus before a PR lands, not
-after a bench rung hangs on real hardware. Planning is pure host
+after a statement hangs on real hardware. Planning is pure host
 Python; nothing traces, compiles, or touches a device, so the sweep
-is cheap enough for the pre-PR gate (tools/ci_static.sh) and for
-`bench.py --prewarm`, which runs the same verifier per rung.
+is cheap enough for the pre-PR gate (tools/ci_static.sh).
 
 Usage:
-    python tools/plan_audit.py                 # rungs + both corpora
-    python tools/plan_audit.py --rungs         # bench rungs only
+    python tools/plan_audit.py                 # scale plans + corpora
+    python tools/plan_audit.py --scale-plans   # scale plans only
     python tools/plan_audit.py --corpus tpch   # one corpus only
     python tools/plan_audit.py --sf 0.001      # corpus scale factor
 """
@@ -27,6 +26,27 @@ sys.path.insert(
     0, os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 from tools._common import make_runner, queries  # noqa: E402
+
+# (label, suite, query id, scale factor, session props): plans whose
+# capacities only become real at the scale they are served at
+# (generator connectors are lazy — row counts, not rows). SF1 and SF10
+# Q1/Q3/Q5/Q6 are the benchmark cells' statements (BENCHMARK.json);
+# q17 keeps a TPC-DS join build near the 1.32M-slot line, q1_sf100 the
+# split-batched scan at 600M rows.
+BIG_PAGES = ("page_rows=1048576",)
+SCALE_PLANS = [
+    ("q1_sf1", "tpch", 1, 1.0, BIG_PAGES),
+    ("q6_sf1", "tpch", 6, 1.0, BIG_PAGES),
+    ("q3_sf01", "tpch", 3, 0.1, ()),
+    ("q1_sf10", "tpch", 1, 10.0, BIG_PAGES),
+    ("q6_sf10", "tpch", 6, 10.0, BIG_PAGES),
+    ("q3_sf1", "tpch", 3, 1.0, BIG_PAGES),
+    ("q5_sf1", "tpch", 5, 1.0, BIG_PAGES),
+    ("q17_sf025", "tpcds", 17, 0.25, ()),
+    ("q3_sf10", "tpch", 3, 10.0, ()),
+    ("q5_sf10", "tpch", 5, 10.0, ()),
+    ("q1_sf100", "tpch", 1, 100.0, BIG_PAGES),
+]
 
 
 def _seeded_misestimate_sweep(runner, label: str, dag,
@@ -213,16 +233,16 @@ def _audit_one(runner, label: str, sql: str, failures: list,
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--rungs", action="store_true",
-                    help="bench rungs only")
+    ap.add_argument("--scale-plans", action="store_true",
+                    help="SCALE_PLANS only")
     ap.add_argument("--corpus", choices=("tpch", "tpcds", "all"),
                     default=None, help="corpus only (default both "
-                    "plus rungs)")
+                    "plus SCALE_PLANS)")
     ap.add_argument("--sf", type=float, default=0.001,
                     help="corpus scale factor (planning-only)")
     args = ap.parse_args()
-    do_rungs = args.rungs or args.corpus is None
-    corpora = ([] if args.rungs else
+    do_scale = args.scale_plans or args.corpus is None
+    corpora = ([] if args.scale_plans else
                ["tpch", "tpcds"] if args.corpus in (None, "all")
                else [args.corpus])
 
@@ -233,15 +253,10 @@ def main() -> int:
     n = 0
     _wire_misestimate_case(failures)
     _ici_flip_case(failures)
-    if do_rungs:
-        from bench import RUNGS
-
-        for name, suite, qid, sf, props in RUNGS:
-            # plan at the rung's REAL scale + session props (generator
-            # connectors are lazy — row counts, not rows); the bench
-            # prewarm path verifies the same plans before compiling
+    if do_scale:
+        for name, suite, qid, sf, props in SCALE_PLANS:
             runner = make_runner(suite, sf, props)
-            _audit_one(runner, f"rung {name}",
+            _audit_one(runner, f"scale {name}",
                        queries(suite)[qid], failures, dag_stats,
                        replans)
             n += 1
