@@ -36,6 +36,7 @@ from presto_tpu.exec import programs as PG
 from presto_tpu.exec import xfer as XF
 from presto_tpu.exec.executor import (
     Executor,
+    _apply_steps,
     _compact_with_flag,
     _final_agg_page,
     _final_global_agg,
@@ -229,6 +230,11 @@ class DistExecutor(Executor):
         ):
             yield from super()._pages_impl(node)
             return
+        if isinstance(node, (P.Filter, P.Project, P.HashJoin)):
+            fused = self._fused_rounds(node)
+            if fused is not None:
+                yield from fused
+                return
         if isinstance(node, P.TableScan):
             yield from self._scan_sharded(node)
             return
@@ -327,6 +333,28 @@ class DistExecutor(Executor):
 
     # -------------------------------------------------------------- scan
     def _scan_sharded(self, node: P.TableScan) -> Iterator[Page]:
+        gen = self._round_generator(node)
+        if gen is None:
+            conn = self.catalogs[node.catalog]
+            yield from self._scan_staged(node, conn, tuple(node.columns))
+            return
+        n, gen_local, make_page, rounds = gen
+        fn = self._mesh_jit(
+            ("d_scan", node.catalog, node.table, tuple(node.columns), n),
+            gen_local)
+        for start_arr in rounds():
+            yield make_page(*fn(start_arr))
+
+    def _round_generator(self, node: P.TableScan):
+        """A sharded scan of an on-device generator as rounds of D
+        splits, one a chip: ``(n, gen_local, make_page, rounds)``: the
+        slots a chip generates a round, the shard-local generator
+        (split start -> columns, valid), the page of its output, and
+        the iterator of the rounds' split starts, sharded over the
+        mesh (it counts the real splits; the tail round is padded).
+        None for a host-page connector (_scan_staged). Shared by the
+        bare scan (d_scan) and the fused scan round (d_fused), so
+        both see the same rounds, splits and slots."""
         conn = self.catalogs[node.catalog]
         schema = conn.table_schema(node.table)
         names = tuple(node.columns)
@@ -335,8 +363,7 @@ class DistExecutor(Executor):
         total = splits[-1].start_row + splits[-1].row_count
         body = conn.gen_body(node.table, n, names)
         if body is None:
-            yield from self._scan_staged(node, conn, names)
-            return
+            return None
         dicts = getattr(conn, "_dicts", {}).get(node.table, {})
 
         def gen_local(start_arr):
@@ -349,37 +376,94 @@ class DistExecutor(Executor):
             ) < jnp.int64(total)
             return datas, valid & in_range
 
-        fn = self._mesh_jit(
-            ("d_scan", node.catalog, node.table, names, n), gen_local)
+        def make_page(datas, valid):
+            return Page(blocks=tuple(
+                Block(data=data, type=schema.column_type(nm),
+                      nulls=None, dictionary=dicts.get(nm))
+                for nm, data in zip(names, datas)
+            ), valid=valid)
 
         starts = [s.start_row for s in splits]
         spec = NamedSharding(self.mesh, PS("d"))
-        for r in range(0, len(starts), self.D):
-            chunk = starts[r:r + self.D]
-            real = len(chunk)
-            # pad the tail round; padded starts generate fully-masked rows
-            chunk = chunk + [total] * (self.D - len(chunk))
-            start_arr = XF.to_device(
-                # xfercheck: raw-ok - chunk is a host list of split starts
-                np.asarray(chunk, dtype=np.int64),
-                spec=spec, label="split-starts",
-            )
-            datas, valid = fn(start_arr)
-            # launch amortization: a mesh round is one
-            # program covering D splits — the same accounting the
-            # split-batched local scan reports
-            self.program_launches += 1
-            self.splits_scanned += real
-            blocks = tuple(
-                Block(
-                    data=data,
-                    type=schema.column_type(nm),
-                    nulls=None,
-                    dictionary=dicts.get(nm),
+
+        def rounds():
+            for r in range(0, len(starts), self.D):
+                chunk = starts[r:r + self.D]
+                # launch amortization: a mesh round is one program
+                # covering D splits (counted at the launch point:
+                # programs.FUSED_SCAN_LABELS), the same accounting
+                # the split-batched local scan reports
+                self.splits_scanned += len(chunk)
+                # pad the tail round; padded starts generate
+                # fully-masked rows
+                chunk = chunk + [total] * (self.D - len(chunk))
+                yield XF.to_device(
+                    # xfercheck: raw-ok - a host list of split starts
+                    np.asarray(chunk, dtype=np.int64),
+                    spec=spec, label="split-starts",
                 )
-                for nm, data in zip(names, datas)
-            )
-            yield Page(blocks=blocks, valid=valid)
+
+        return n, gen_local, make_page, rounds
+
+    def _fused_rounds(self, node: P.PhysicalNode
+                      ) -> Optional[Iterator[Page]]:
+        """A SHARDED scan chain as ONE program a scan round (d_fused):
+        where ``node`` tops a chain of Filter / Project / build-free
+        generated joins over a TableScan of an on-device generator,
+        the shard_map body generates the chip's split, builds the page
+        and applies the step list the one-chip fused stream applies
+        (Executor._chain_steps), so a round pays one launch and one
+        pages() boundary instead of one a plan node. The rounds, the
+        splits and the page a round are the per-node chain's, slot
+        for slot; the two drivers stay apart (one chip batches splits
+        under vmap / lax.scan, the mesh runs D splits a round under
+        shard_map with psum'd flags) and share the step list.
+
+        When it engages is read from the plan and the connector: None
+        (the per-node programs, which stay) where the chain holds an
+        Exchange (_scan_chain walks through one because on one chip
+        it moves nothing; over a mesh it moves rows, and the chain
+        below it is looked at again when the walk reaches it), a
+        live result-cache point (the rule of _fused_stream), or a
+        host-page connector. Without an Exchange every link of a
+        chain down to a TableScan is SHARDED (dist())."""
+        walked = self._scan_chain(node, through_joins=True)
+        if walked is None:
+            return None
+        scan, chain = walked
+        if any(isinstance(link, P.Exchange) for link in chain):
+            return None
+        if self._chain_holds_cache_point(scan, chain):
+            return None
+        gen = self._round_generator(scan)
+        if gen is None:
+            return None
+        n, gen_local, make_page, rounds = gen
+        steps = self._chain_steps(chain)
+        # a windowed generated join's multi-match flag is the one
+        # collective of the program (see _dist_join_generated)
+        n_flags = sum(kind == "joinw" for kind, _fn in steps)
+
+        def body(start_arr):
+            page, flags = _apply_steps(
+                make_page(*gen_local(start_arr)), steps)
+            return page, tuple(
+                jax.lax.psum(f.astype(jnp.int32), "d") > 0
+                for f in flags)
+
+        fn = self._mesh_jit(
+            ("d_fused", node, n), body,
+            out_specs=(PS("d"), (PS(),) * n_flags),
+            fenced=n_flags > 0)
+
+        def stream():
+            for start_arr in rounds():
+                page, flags = fn(start_arr)
+                self.mesh_fused_rounds += 1
+                self._pending_overflow.extend(flags)
+                yield page
+
+        return stream()
 
     def _scan_staged(self, node, conn, names) -> Iterator[Page]:
         """Host-page connectors (e.g. memory connector): stage each round

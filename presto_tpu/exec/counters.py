@@ -49,6 +49,12 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "rows between chips this attempt (family exchange in "
         "exec/programs.PROGRAM_LABELS: all_to_all repartition, "
         "all_gather, residue split); 0 on one device"),
+    "mesh_fused_rounds": (
+        "gauge", "scan rounds of this attempt that ran their whole "
+        "SHARDED chain (generator, generated joins, filter, project) "
+        "as one program over the mesh (d_fused: "
+        "dist/executor.DistExecutor._fused_rounds); 0 where the chain "
+        "fell back to one program a plan node, and on one device"),
     "dispatch_wall_us": (
         "gauge", "host microseconds inside those calls this attempt: "
         "trace-cache lookup, argument handling, enqueue (and a "

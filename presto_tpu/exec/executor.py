@@ -442,9 +442,11 @@ class Executor:
         # (exec/xfer.py pulls, devsync.drain, the overflow-flag read);
         # _launches_by_label feeds the attempt span while tracing;
         # exchange_launches = the calls among them whose program moves
-        # rows between chips (family "exchange", over a mesh)
+        # rows between chips (family "exchange", over a mesh);
+        # mesh_fused_rounds = the scan rounds a mesh ran as one program
         self.device_launches = 0
         self.exchange_launches = 0
+        self.mesh_fused_rounds = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
@@ -1324,6 +1326,42 @@ class Executor:
             else:
                 return None
 
+    def _chain_holds_cache_point(self, scan, chain) -> bool:
+        """A chain member that is a live result-cache point must stay
+        an observable pages() boundary (fusing through it would
+        bypass _cached_pages entirely — no hit, no population); an
+        INFLIGHT point is its own miss-path collection, where fusion
+        is exactly what we want."""
+        if not self._cache_points:
+            return False
+        for link in chain + [scan]:
+            n = link[0] if isinstance(link, tuple) else link
+            if id(n) in self._cache_points and \
+                    id(n) not in self._cache_inflight:
+                return True
+        return False
+
+    def _chain_steps(self, chain) -> List:
+        """A _scan_chain's links (top-down) as the bottom-up list of
+        page transforms a fused scan program applies to the page it
+        generates (_apply_steps): ("map", page -> page) for a Filter
+        or Project, ("join", page -> page) for a build-free generated
+        join, ("joinw", page -> (page, multi_flag)) for a windowed
+        one. THE one step list of the one-chip fused stream and of
+        the mesh executor's fused scan round (dist/executor.py)."""
+        steps: List = []
+        for nd in reversed(chain):
+            if isinstance(nd, tuple):
+                jnode, info = nd
+                kern, windowed = self.generated_join_kernel(jnode, info)
+                steps.append(("joinw" if windowed else "join", kern))
+                self.generated_joins_used += 1
+            else:
+                fn = _node_replay_fn(nd)
+                if fn is not None:
+                    steps.append(("map", fn))
+        return steps
+
     def _fused_stream(self, node: P.PhysicalNode, agg_tail=None,
                       key_extra=None) -> Optional[Iterator[Page]]:
         """Whole-pipeline fusion: when `node` is a chain of Filter /
@@ -1364,17 +1402,8 @@ class Executor:
         if walked is None:
             return None
         cur, chain = walked
-        # a chain member that is a live result-cache point must stay
-        # an observable pages() boundary (fusing through it would
-        # bypass _cached_pages entirely — no hit, no population);
-        # an INFLIGHT point is its own miss-path collection, where
-        # fusion is exactly what we want
-        if self._cache_points:
-            for link in chain + [cur]:
-                n = link[0] if isinstance(link, tuple) else link
-                if id(n) in self._cache_points and \
-                        id(n) not in self._cache_inflight:
-                    return None
+        if self._chain_holds_cache_point(cur, chain):
+            return None
         if not chain and agg_tail is None:
             return None  # a bare scan already runs as one program
         conn = self.catalogs[cur.catalog]
@@ -1415,18 +1444,7 @@ class Executor:
         if cur.constraint:
             splits = conn.prune_splits(cur.table, splits, cur.constraint)
 
-        # bottom-up list of page transforms (top-down in `chain`)
-        steps: List = []
-        for nd in reversed(chain):
-            if isinstance(nd, tuple):
-                jnode, info = nd
-                kern, windowed = self.generated_join_kernel(jnode, info)
-                steps.append(("joinw" if windowed else "join", kern))
-                self.generated_joins_used += 1
-            else:
-                fn = _node_replay_fn(nd)
-                if fn is not None:
-                    steps.append(("map", fn))
+        steps = self._chain_steps(chain)
         batch_merge = None
         if agg_tail is not None:
             kind, fn, batch_merge = agg_tail
@@ -1447,20 +1465,10 @@ class Executor:
                 for d, t, dic in zip(datas, scan_types, scan_dicts)
             ), valid=valid)
 
-        def apply_steps(page, use_steps):
-            flags = []
-            for kind, fn in use_steps:
-                if kind in ("joinw", "aggflag"):
-                    page, flag = fn(page)
-                    flags.append(flag)
-                else:
-                    page = fn(page)
-            return page, tuple(flags)
-
         def run_split(gen_fn, n_pad, start, count):
             datas, valid = gen_fn(start)
-            return apply_steps(make_page(datas, valid, n_pad, count),
-                               steps)
+            return _apply_steps(make_page(datas, valid, n_pad, count),
+                                steps)
 
         scan_row_b = chain_row_b
 
@@ -1486,7 +1494,7 @@ class Executor:
             gen_b = conn.gen_batch(cur.table, n_pad, names)
 
             def post(datas, valid, count):
-                return apply_steps(
+                return _apply_steps(
                     make_page(datas, valid, n_pad, count), steps)
 
             def run_xq(starts, counts):
@@ -1636,7 +1644,7 @@ class Executor:
                 gen_b = conn.gen_batch(cur.table, n_pad_all, names)
 
                 def post(datas, valid, count):
-                    return apply_steps(
+                    return _apply_steps(
                         make_page(datas, valid, n_pad_all, count),
                         steps,
                     )
@@ -1679,7 +1687,7 @@ class Executor:
 
             def one_state(start, count):
                 datas, valid = gen_fn(start)
-                page, flags = apply_steps(
+                page, flags = _apply_steps(
                     make_page(datas, valid, n_pad_all, count), pre)
                 st, ovf = tail_fn(page)
                 return st, or_flags(flags) | ovf
@@ -2205,6 +2213,7 @@ class Executor:
                     tr.end(att_span, outcome="ok", rows=len(rows),
                            launches=dict(self._launches_by_label),
                            exchange_launches=self.exchange_launches,
+                           mesh_fused_rounds=self.mesh_fused_rounds,
                            **self._agg_sizing_attrs())
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
@@ -2258,6 +2267,7 @@ class Executor:
         self.program_launches = 0
         self.device_launches = 0
         self.exchange_launches = 0
+        self.mesh_fused_rounds = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label = {}
@@ -5138,6 +5148,20 @@ def _node_replay_fn(nd):
     if isinstance(nd, P.Project):
         return functools.partial(_project_page, nd.exprs)
     return None
+
+
+def _apply_steps(page: Page, steps):
+    """Run a fused scan program's step list (Executor._chain_steps,
+    plus a partial-aggregation tail) over one generated page: the
+    page and the deferred flags of its "joinw" / "aggflag" steps."""
+    flags = []
+    for kind, fn in steps:
+        if kind in ("joinw", "aggflag"):
+            page, flag = fn(page)
+            flags.append(flag)
+        else:
+            page = fn(page)
+    return page, tuple(flags)
 
 
 def _subtree_has_join(node: P.PhysicalNode) -> bool:

@@ -81,6 +81,9 @@ PROGRAM_LABELS: Dict[str, str] = {
     # rows between chips (all_to_all, all_gather, the residue split of
     # a replicated page) are the family "exchange"
     "d_scan": "scan",
+    # a scan round's whole chain (generator, generated joins, filter,
+    # project) in one shard_map program, as "fused" is on one device
+    "d_fused": "scan",
     "d_filter": "filter_project",
     "d_project": "filter_project",
     "d_unnest": "filter_project",
@@ -106,7 +109,10 @@ PROGRAM_LABELS: Dict[str, str] = {
 }
 
 
-FUSED_SCAN_LABELS = frozenset(("fused", "fused_batch", "xq_batch"))
+# the launches program_launches counts: a fused scan step on one
+# device, a scan round (D splits, one a chip) over a mesh
+FUSED_SCAN_LABELS = frozenset(("fused", "fused_batch", "xq_batch",
+                               "d_scan", "d_fused"))
 
 
 def label_of(key) -> str:
@@ -147,7 +153,8 @@ class Program:
         self.note = f"launch:{label}"  # built once, not per call
         self.jitted = jax.jit(program, **jit_kwargs)
         self.donates = donates
-        # the launches program_launches counts (split-batched scans)
+        # the launches program_launches counts (split-batched scans,
+        # a mesh's scan rounds)
         self.fused_scan = label in FUSED_SCAN_LABELS
         # the launches exchange_launches counts (rows change chips)
         self.exchange = PROGRAM_LABELS.get(label) == "exchange"

@@ -223,10 +223,11 @@ TRANSFER_REGISTRY: Dict[str, Tuple[str, str, str]] = {
         "so np_host meters ZERO bytes unless a demoted entry "
         "rehydrated device-side"),
     # ---- distributed executor (mesh staging)
-    "dist.executor.DistExecutor._scan_sharded": (
+    "dist.executor.DistExecutor._round_generator": (
         "h2d", "data",
         "per-round split-start indices stage onto the mesh (D int64s "
-        "per round, not page data)"),
+        "per round, not page data), for the bare scan and the fused "
+        "scan round alike"),
     "dist.executor.DistExecutor._fenced": (
         "d2h", "data",
         "CPU-only collective fence: blocks on program outputs to "

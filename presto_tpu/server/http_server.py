@@ -507,8 +507,8 @@ class QueryManager:
     _EXEC_TOTAL_SUMS = (
         "program_launches", "splits_scanned", "cross_query_batches",
         "cross_query_batched_queries", "batch_gather_wait_ms",
-        "device_launches", "exchange_launches", "dispatch_wall_us",
-        "device_wait_us",
+        "device_launches", "exchange_launches", "mesh_fused_rounds",
+        "dispatch_wall_us", "device_wait_us",
     )
     _EXEC_TOTAL_MAX = ("queries_per_launch",)
 

@@ -291,6 +291,42 @@ def test_mesh_q3_compaction_compiles_shard_local(topo, tpu_branches):
     assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
 
 
+def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
+        topo, tpu_branches):
+    """Q3 at SF1 over four chips (ISSUE 32): a scan round's whole chain
+    (the generator of 262,143 lineitem slots a chip, both generated
+    joins, filter, project) is ONE program, d_fused, with no
+    collective in it (no windowed join: no flag) and a sharded page
+    out."""
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.runner import LocalRunner
+    from tests.tpch_queries import QUERIES
+
+    mesh = Mesh(np.array(topo.devices), ("d",))
+    runner = LocalRunner({"tpch": TpchConnector(scale=1.0)},
+                         page_rows=1 << 18, mesh=mesh)
+    ex = runner.executor
+    ex.fault_rows = SH.SAFE_BUFFER_ROWS
+    ex.device_memory_budget = (16 << 30) * 7 // 8
+    top = runner.plan(QUERIES[3])
+    while ex._fused_rounds(top) is None:
+        (top,) = top.children()[:1]
+    ((key, program),) = [(k, p) for k, p in ex._jit_cache.items()
+                         if k[0] == "d_fused"]
+    assert key[1] is top and key[2] == (1 << 18) - 1
+    assert ex.generated_joins_used == 2
+    compiled = program.jitted.lower(_spec(
+        (mesh.devices.size,), jnp.int64,
+        NamedSharding(mesh, PS("d")))).compile()
+    text = compiled.as_text()
+    for collective in ("all-reduce", "all-to-all", "all-gather"):
+        assert collective not in text, collective
+    out_page, flags = compiled.output_shardings
+    assert flags == ()
+    assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
+    assert len(out_page.blocks) == len(ex.output_types(top))
+
+
 def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
     from presto_tpu.dist import executor as DX
 

@@ -54,7 +54,11 @@ def test_mesh_statement_equals_the_plain_reference(
     info = srv.query_info(res.query_id)
     attempts = [sp for sp in _spans(info) if sp["kind"] == "attempt"]
     launches = attempts[-1]["attrs"]["launches"]
-    assert launches.get("d_scan", 0) >= 1, launches
+    # Q3's and Q5's scan chains are one program a round
+    assert launches.get("d_fused", 0) >= 1, launches
+    assert "d_scan" not in launches and "d_genjoin" not in launches
+    assert attempts[-1]["attrs"]["mesh_fused_rounds"] == \
+        launches["d_fused"]
     if gather_capacity is not None:
         assert launches.get("d_repartition", 0) >= 1, launches
         assert attempts[-1]["attrs"]["exchange_launches"] >= 2
@@ -128,9 +132,13 @@ def test_topn_over_a_sharded_scan_merges_page_after_page(single, mesh4):
            "l_linenumber limit 12")
     assert mesh4.execute(sql).rows == single.execute(sql).rows
     launches = _last_attempt(mesh4)["launches"]
-    assert launches["d_scan"] >= 2
-    assert launches["d_topn_local"] == launches["d_scan"]
-    assert launches["d_topn_merge"] == launches["d_scan"] - 1
+    # the planner's projection over the scan makes a chain of two: a
+    # round is d_fused (a bare scan stays d_scan:
+    # test_a_chain_ends_below_an_exchange)
+    rounds = launches["d_fused"]
+    assert rounds >= 2 and "d_scan" not in launches
+    assert launches["d_topn_local"] == rounds
+    assert launches["d_topn_merge"] == rounds - 1
     assert launches["d_gather"] == 1
 
 
@@ -211,8 +219,15 @@ def test_mesh_q3_compacts_and_aggregates_once(key, cell_mesh):
         runner, ROUND_SLOTS, STATEMENTS[key].sql)
     assert reference.mismatch(got, want[key]) == ""
     launches = only["launches"]
-    rounds = launches["d_scan"]
+    # a scan round is one program: generator, both generated joins,
+    # filter and project (ISSUE 32); the one d_project left is the
+    # projection above the final aggregation
+    rounds = launches["d_fused"]
     assert rounds >= 3, launches
+    assert only["mesh_fused_rounds"] == rounds
+    assert runner.executor.program_launches == rounds  # not twice
+    assert not {"d_scan", "d_genjoin", "d_filter"} & set(launches)
+    assert launches.get("d_project", 0) <= 1, launches
     assert launches["d_stream_compact1"] == rounds
     assert launches["d_stream_compact2"] == rounds - 1
     assert (launches["d_agg_partial"], launches["d_repartition"],
@@ -236,7 +251,9 @@ def test_mesh_q5_bypasses_the_compaction(key, cell_mesh):
     (only,) = _attempts(runner)
     launches = only["launches"]
     assert not [lab for lab in launches if "stream_compact" in lab]
-    assert launches["d_agg_partial"] == launches["d_scan"] >= 3
+    assert launches["d_agg_partial"] == launches["d_fused"] >= 3
+    assert only["mesh_fused_rounds"] == launches["d_fused"]
+    assert not {"d_scan", "d_genjoin", "d_filter"} & set(launches)
     assert only["agg_compact_rows"] == 0
 
 
@@ -316,7 +333,7 @@ def test_rows_of_one_chips_splits_alone(
     # labels in the order of their first launch: no row changed chips
     # between the scan and the compaction
     order = list(attempts[-1]["launches"])
-    assert order.index("d_scan") < order.index("d_stream_compact1") \
+    assert order.index("d_fused") < order.index("d_stream_compact1") \
         < order.index("d_repartition"), order
 
 
@@ -325,3 +342,235 @@ def _valid_keys(conn, split):
 
     page = conn.page_for_split(split, ("l_orderkey",))
     return np.asarray(page.block(0).data)[np.asarray(page.valid)]
+
+
+# ------------------------------------------ one program a scan round
+# (ISSUE 32) Where a SHARDED subtree is a chain of Filter / Project /
+# build-free generated joins over a generated scan, a round is ONE
+# shard_map program (d_fused) that applies the step list the one-chip
+# fused stream applies; everything else keeps one program a plan node.
+def _per_node(monkeypatch):
+    """Today's per-node programs: the fused round never engages."""
+    from presto_tpu.dist.executor import DistExecutor
+
+    monkeypatch.setattr(DistExecutor, "_fused_rounds",
+                        lambda self, node: None)
+
+
+def _fused_chains(runner, sql, monkeypatch):
+    """Every chain of ``sql`` that ran fused, with the pages it
+    yielded a round: [(top node, [page, ...])]."""
+    from presto_tpu.dist.executor import DistExecutor
+
+    seen = []
+    fused_rounds = DistExecutor._fused_rounds
+
+    def recording(self, node):
+        stream = fused_rounds(self, node)
+        if stream is None:
+            return None
+        pages = []
+        seen.append((node, pages))
+
+        def tee():
+            for page in stream:
+                pages.append(page)
+                yield page
+        return tee()
+
+    monkeypatch.setattr(DistExecutor, "_fused_rounds", recording)
+    rows = runner.execute(sql).rows
+    monkeypatch.setattr(DistExecutor, "_fused_rounds", fused_rounds)
+    return rows, seen
+
+
+def _assert_pages_equal(got, want):
+    import jax
+    import numpy as np
+
+    assert len(got) == len(want) >= 2, (len(got), len(want))
+    for rnd, (a, b) in enumerate(zip(got, want)):
+        assert a.capacity == b.capacity, rnd
+        assert np.array_equal(np.asarray(a.valid), np.asarray(b.valid))
+        assert len(a.blocks) == len(b.blocks)
+        for ch, (x, y) in enumerate(zip(a.blocks, b.blocks)):
+            assert (x.type, x.dictionary) == (y.type, y.dictionary)
+            xs, ys = jax.tree.leaves(x), jax.tree.leaves(y)
+            assert len(xs) == len(ys), (rnd, ch)  # data (+ nulls)
+            for u, v in zip(xs, ys):
+                # every slot, the masked ones too
+                assert np.array_equal(np.asarray(u), np.asarray(v)), (
+                    rnd, ch)
+
+
+@pytest.mark.parametrize("template", ["q3", "q5"])
+def test_fused_round_yields_the_per_node_chains_page(
+        template, cell_mesh, monkeypatch):
+    """Same rounds, same splits, same pages: what d_stream_compact1/2,
+    d_agg_partial and the rest consume is slot for slot what the
+    per-node chain (d_scan -> d_genjoin... -> d_filter -> d_project)
+    yields for the round."""
+    runner, want = cell_mesh
+    key = min(k for k, st in STATEMENTS.items()
+              if st.template == template)
+    rows, chains = _fused_chains(runner, STATEMENTS[key].sql,
+                                 monkeypatch)
+    assert reference.mismatch(rows, want[key]) == ""
+    ((top, fused),) = chains  # one scan chain a statement
+    ex = runner.executor
+    assert ex.mesh_fused_rounds == len(fused)
+    _per_node(monkeypatch)
+    ex._begin_attempt()
+    per_node = list(ex.pages(top))
+    assert ex.mesh_fused_rounds == 0
+    assert ex.device_launches > 2 * len(per_node)
+    _assert_pages_equal(fused, per_node)
+
+
+@pytest.fixture(scope="module")
+def tpcds_mesh():
+    from presto_tpu.connectors.tpcds import TpcdsConnector
+
+    conn = TpcdsConnector(0.01)
+    runner = LocalRunner(
+        {"tpcds": conn}, default_catalog="tpcds", page_rows=1 << 12,
+        mesh=make_mesh(4))
+    runner.session.set("query_trace_enabled", True)
+    return runner
+
+
+def test_fused_round_keeps_a_windowed_joins_flag(tpcds_mesh,
+                                                 monkeypatch):
+    """store_sales to store_returns on ticket and item is a WINDOWED
+    generated join: its multi-match flag stays a psum'd, deferred
+    flag of the round's one program, and the page is the per-node
+    chain's."""
+    sql = ("select ss_item_sk, ss_ticket_number, ss_quantity, "
+           "sr_return_quantity from store_sales join store_returns "
+           "on ss_ticket_number = sr_ticket_number "
+           "and ss_item_sk = sr_item_sk where ss_quantity > 10")
+    rows, chains = _fused_chains(tpcds_mesh, sql, monkeypatch)
+    fused_attempt = _last_attempt(tpcds_mesh)
+    ((top, fused),) = chains
+    rounds = len(fused)
+    assert fused_attempt["launches"]["d_fused"] == rounds
+    assert fused_attempt["mesh_fused_rounds"] == rounds
+    assert "d_genjoin_win" not in fused_attempt["launches"]
+    ex = tpcds_mesh.executor
+    ex._begin_attempt()
+    flags = []
+    for _page in ex.pages(top):
+        flags = list(ex._pending_overflow)
+    assert len(flags) == rounds  # one deferred flag a round
+    assert all(f.shape == () and not bool(f) for f in flags)
+    _per_node(monkeypatch)
+    ex._begin_attempt()
+    per_node = list(ex.pages(top))
+    assert len(ex._pending_overflow) == rounds
+    _assert_pages_equal(fused, per_node)
+    assert sorted(tpcds_mesh.execute(sql).rows) == sorted(rows)
+    launches = _last_attempt(tpcds_mesh)["launches"]
+    assert launches["d_genjoin_win"] == launches["d_scan"] == rounds
+    assert _last_attempt(tpcds_mesh)["mesh_fused_rounds"] == 0
+
+
+def _run_plan(runner, source, names):
+    """A hand-built plan on the runner's executor, traced: its rows
+    and its last attempt span's attrs."""
+    from presto_tpu import obs
+    from presto_tpu.exec import plan as P
+
+    trace = obs.maybe_trace(runner.session, sql="a hand-built plan")
+    obs.attach(runner.executor, trace)
+    try:
+        _names, rows = runner.executor.execute(
+            P.Output(source=source, names=names))
+    finally:
+        obs.finalize(runner.executor, trace)
+    return rows, [sp for sp in trace.spans()
+                  if sp.kind == "attempt"][-1].attrs
+
+
+def test_a_chain_ends_below_an_exchange(mesh4, single):
+    """_scan_chain walks through an Exchange (on one chip it moves
+    nothing); over a mesh it moves rows, so a chain that holds one
+    keeps today's programs, and the links below it are a chain of
+    their own."""
+    from presto_tpu.exec import plan as P
+    from presto_tpu.expr.ir import InputRef
+    from presto_tpu import types as T
+
+    ex = mesh4.executor
+    scan = P.TableScan("tpch", "lineitem", ("l_orderkey", "l_quantity"))
+    swap = (InputRef(1, T.DecimalType(12, 2)), InputRef(0, T.BIGINT))
+    over = P.Project(
+        P.Exchange(scan, kind="repartition", keys=(0,)), swap)
+    assert ex._scan_chain(over, through_joins=True) is not None
+    assert ex._fused_rounds(over) is None
+    rows, attempt = _run_plan(
+        mesh4, P.Exchange(over, kind="gather"), ("q", "k"))
+    assert attempt["mesh_fused_rounds"] == 0
+    launches = attempt["launches"]
+    # a bare sharded scan has no chain to fuse: d_scan, one a round
+    assert launches["d_scan"] == launches["d_repartition"] \
+        == launches["d_project"] >= 2 and "d_fused" not in launches
+    assert ex.program_launches == launches["d_scan"]
+    want = sorted(single.execute(
+        "select l_quantity, l_orderkey from lineitem").rows)
+    assert sorted(rows) == want
+    # the links below the exchange fuse; the one above stays
+    under = P.Project(P.Exchange(
+        P.Project(scan, (swap[1], swap[0])), kind="repartition",
+        keys=(0,)), swap)
+    rows, attempt = _run_plan(
+        mesh4, P.Exchange(under, kind="gather"), ("q", "k"))
+    launches = attempt["launches"]
+    assert launches["d_fused"] == launches["d_project"] \
+        == attempt["mesh_fused_rounds"] >= 2
+    assert "d_scan" not in launches
+    assert sorted(rows) == want
+
+
+def test_a_host_page_connector_keeps_the_per_node_programs():
+    """No generator on the device (gen_body is None): the scan stages
+    host pages (_scan_staged) and the chain above it runs one program
+    a plan node."""
+    from presto_tpu import types as T
+    from presto_tpu.connectors.memory import MemoryConnector
+
+    mem = MemoryConnector()
+    mem.create_table("t", ("a", "b"), (T.BIGINT, T.BIGINT),
+                     [(i, i % 7) for i in range(1000)])
+    runner = LocalRunner({"memory": mem}, default_catalog="memory",
+                         page_rows=128, mesh=make_mesh(4))
+    runner.session.set("query_trace_enabled", True)
+    got = runner.execute("select a + b from t where b < 3").rows
+    assert sorted(got) == sorted(
+        (i + i % 7,) for i in range(1000) if i % 7 < 3)
+    attempt = _last_attempt(runner)
+    assert attempt["mesh_fused_rounds"] == 0
+    assert "d_fused" not in attempt["launches"]
+    assert {"d_filter", "d_project"} & set(attempt["launches"])
+
+
+def test_a_live_cache_point_in_the_chain_stays_a_boundary(mesh4):
+    """The rule _fused_stream has: a chain member that is a live
+    result-cache point must stay a pages() boundary; its own miss
+    path (inflight) fuses."""
+    from presto_tpu.exec import plan as P
+
+    plan = mesh4.plan("select l_orderkey, l_quantity + 1 from lineitem "
+                      "where l_quantity < 10")
+    ex = mesh4.executor
+    top = plan
+    while ex._fused_rounds(top) is None:
+        (top,) = top.children()
+    inner = top.children()[0]
+    assert isinstance(inner, (P.Filter, P.Project, P.TableScan))
+    try:
+        ex._cache_points = {id(inner): ("entry",)}
+        assert ex._fused_rounds(top) is None
+        ex._cache_inflight = {id(inner)}
+        assert ex._fused_rounds(top) is not None
+    finally:
+        ex._cache_points, ex._cache_inflight = {}, set()

@@ -138,6 +138,7 @@ def test_family_of_reads_a_trace_program_name():
         assert PG.family_of(f"jit_{label}(5456584955919556897)") == \
             "exchange", label
     assert PG.family_of("jit_d_scan(1)") == "scan"
+    assert PG.family_of("jit_d_fused(1)") == "scan"
     assert PG.family_of("jit_d_agg_final(1)") == "agg"
     assert PG.family_of("jit_d_topn_local(1)") == "sort_topn"
     assert PG.family_of("jit_d_genjoin(1)") == "join"
@@ -504,9 +505,14 @@ def test_a_mesh_statement_counts_every_program_it_launches(
                    if PG.PROGRAM_LABELS[lab] == "exchange")
     assert ex.exchange_launches == exchange >= 2  # repartition + gather
     assert attempt.attrs["exchange_launches"] == exchange
-    assert {"d_scan", "d_genjoin", "d_agg_partial", "d_repartition",
+    # a scan round is ONE program (generator, both generated joins,
+    # filter, project), and it is the round's fused-scan launch: once
+    assert {"d_fused", "d_agg_partial", "d_repartition",
             "d_agg_final", "d_topn_local", "d_gather",
             "topn_local"} <= set(by_label)
+    assert not {"d_scan", "d_genjoin", "d_filter"} & set(by_label)
+    assert ex.program_launches == by_label["d_fused"] \
+        == attempt.attrs["mesh_fused_rounds"] == ex.mesh_fused_rounds
     assert QUERY_COUNTERS["exchange_launches"][0] == "gauge"
 
 
@@ -529,8 +535,17 @@ def test_exchange_launches_count_on_the_calling_executor():
     # a shard-local program is a launch and no exchange
     caller._mesh_jit(("d_filter", "t"), lambda x: x + 1)(jnp.arange(8))
     assert (caller.device_launches, caller.exchange_launches) == (2, 1)
+    # a mesh's scan round is the fused-scan launch of its statement,
+    # counted where every launch is, fused chain or bare scan
+    assert caller.program_launches == 0
+    for label in ("d_fused", "d_scan"):
+        caller._mesh_jit((label, "t"), lambda x: x + 1)(jnp.arange(8))
+    assert (caller.device_launches, caller.program_launches) == (4, 2)
+    caller.mesh_fused_rounds = 3
     caller._begin_attempt()
-    assert (caller.device_launches, caller.exchange_launches) == (0, 0)
+    assert (caller.device_launches, caller.exchange_launches,
+            caller.program_launches, caller.mesh_fused_rounds) == (
+        0, 0, 0, 0)
 
 
 def test_metrics_expose_exchange_launches(mesh_runner):
@@ -543,3 +558,6 @@ def test_metrics_expose_exchange_launches(mesh_runner):
     assert scraped["device_launches"] == ex.device_launches
     assert scraped["device_launches"] > scraped["exchange_launches"]
     assert "exchange_launches" in QueryManager._EXEC_TOTAL_SUMS
+    assert scraped["mesh_fused_rounds"] == ex.mesh_fused_rounds >= 1
+    assert "mesh_fused_rounds" in QueryManager._EXEC_TOTAL_SUMS
+    assert QUERY_COUNTERS["mesh_fused_rounds"][0] == "gauge"

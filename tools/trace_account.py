@@ -158,7 +158,7 @@ def record(cell_name: str, sids: List[str], out_dir: str,
             acc["metrics_after"] = {
                 k: v for k, v in served.metrics().items()
                 if k in ("device_launches", "program_launches",
-                         "exchange_launches",
+                         "exchange_launches", "mesh_fused_rounds",
                          "dispatch_wall_us", "device_wait_us",
                          "spill_partitions_used")}
             out.append(acc)
