@@ -81,8 +81,36 @@ def _builtin_factories() -> Dict[str, Callable]:
 
         return StreamConnector()
 
-    return {"tpch": tpch, "tpcds": tpcds, "memory": memory,
-            "blackhole": blackhole, "stream": stream}
+    def resident(props):
+        """Tables of an inner connector held on the device
+        (connectors/cached.py): ``resident.inner`` names a built-in
+        connector, whose own keys ride in the same file;
+        ``resident.tables`` the tables stored (absent: every table at
+        its first scan)."""
+        from presto_tpu.connectors.cached import ResidentConnector
+
+        inner_name = props.get("resident.inner", "")
+        factory = builtins.get(inner_name)
+        if factory is None or inner_name == "resident":
+            raise ValueError(
+                f"unknown resident.inner {inner_name!r} "
+                f"(known: {sorted(set(builtins) - {'resident'})})")
+        inner = factory(props)
+        tables = [t.strip() for t in
+                  props.get("resident.tables", "").split(",")
+                  if t.strip()]
+        unknown = sorted(set(tables) - set(inner.tables()))
+        if unknown:
+            raise ValueError(
+                f"resident.tables names {unknown}, which connector "
+                f"{inner_name!r} does not have "
+                f"(has: {sorted(inner.tables())})")
+        return ResidentConnector(inner, tables=tables or None)
+
+    builtins = {"tpch": tpch, "tpcds": tpcds, "memory": memory,
+                "blackhole": blackhole, "stream": stream,
+                "resident": resident}
+    return builtins
 
 
 def load_catalogs(etc_dir: str) -> Dict[str, object]:

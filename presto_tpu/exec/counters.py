@@ -55,6 +55,26 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "as one program over the mesh (d_fused: "
         "dist/executor.DistExecutor._fused_rounds); 0 where the chain "
         "fell back to one program a plan node, and on one device"),
+    "resident_table_bytes": (
+        "gauge", "device bytes the catalogs' stored tables hold now "
+        "(connectors/cached.py: every column and the validity of each "
+        "resident table, pad included); comes off the governor's "
+        "budget"),
+    "resident_loads": (
+        "counter", "tables loaded into the device-resident store (a "
+        "table's first touch, or its first after a write moved the "
+        "snapshot; catalog lifetime)"),
+    "resident_load_wall_us": (
+        "counter", "microseconds those loads took, start to the last "
+        "byte on the device (catalog lifetime)"),
+    "resident_splits_scanned": (
+        "gauge", "real splits whose columns this attempt's fused-scan "
+        "launches read from a stored table (the part of "
+        "splits_scanned that generated nothing)"),
+    "resident_bytes_scanned": (
+        "gauge", "bytes of stored columns and validity in those "
+        "splits' slices (padded rows x the touched buffers' widths), "
+        "counted at the launch from shapes: no device read"),
     "dispatch_wall_us": (
         "gauge", "host microseconds inside those calls this attempt: "
         "trace-cache lookup, argument handling, enqueue (and a "

@@ -82,6 +82,29 @@ def _row_bytes(types) -> int:
     return total
 
 
+class _GeneratedSource:
+    """What a split's columns come from when the connector generates
+    them (the fused scan driver's source, Executor._fused_stream): the
+    connector's traceable gen_body / gen_batch (``reads``: itself),
+    and no device buffer to hand a launch. A stored table's is
+    connectors/cached.StoredSource, whose ``args`` are the buffers."""
+
+    args = ()
+
+    def __init__(self, conn, table: str, names: tuple):
+        self._conn, self._table, self._names = conn, table, names
+
+    @property
+    def reads(self):
+        return self
+
+    def body(self, n_pad: int):
+        return self._conn.gen_body(self._table, n_pad, self._names)
+
+    def batch(self, n_pad: int):
+        return self._conn.gen_batch(self._table, n_pad, self._names)
+
+
 def _canonical_join_cols(
     left_blocks: List[Block], right_blocks: List[Block]
 ):
@@ -450,6 +473,18 @@ class Executor:
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
+        # scans of a stored table (connectors/cached.py), this attempt:
+        # resident_splits_scanned = real splits whose columns the fused
+        # scan step read from the store, resident_bytes_scanned = the
+        # bytes of stored columns and validity those splits' slices
+        # hold (counted where they are launched, from the buffers'
+        # dtypes and the split's padded rows: no device read). What the
+        # store holds and what loading it cost are the catalogs' own
+        # (resident_table_bytes, resident_loads, resident_load_wall_us
+        # below)
+        self.resident_splits_scanned = 0
+        self.resident_bytes_scanned = 0
+        self._attempt_span = None   # the open attempt, while tracing
         # _agg_sizing's decisions this attempt (the attempt span
         # reports the costliest: most passes, then largest capacity)
         self._agg_sizings: List[AggSizing] = []
@@ -1015,6 +1050,18 @@ class Executor:
             by = self._launches_by_label
             by[prog.label] = by.get(prog.label, 0) + 1
 
+    def count_resident_load(self, table: str, wall_s: float,
+                            **attrs) -> None:
+        """THE sink connectors/cached.py records a table's load on: a
+        span of the attempt whose scan touched the table first (the
+        tallies are the connector's own)."""
+        tr = self.trace
+        if tr is not None:
+            t1 = tr.now()
+            tr.complete("resident_load", table, t1 - wall_s, t1,
+                        parent=self._attempt_span, **attrs)
+            self.trace_spans += 1
+
     def count_device_wait(self, wall_s: float) -> None:
         """THE sink exec/xfer.py counts host time blocked on the
         device on (its pulls, devsync.drain, the overflow-flag read)."""
@@ -1030,19 +1077,43 @@ class Executor:
     def _budget(self) -> int:
         """Resolved device-memory budget in bytes (membudget.py): an
         explicit device_memory_budget wins; auto = HBM minus headroom
-        on TPU, a generous cap on CPU; a device-OOM retry halves it
-        (_tighten_budget) so the governor re-plans chunked. Cached per
-        (setting, tightening) — resolution may query device memory
-        stats once."""
-        key = (self.device_memory_budget, self._oom_divisor)
+        on TPU, a generous cap on CPU; what the catalogs hold resident
+        on the device (connectors/cached.py) comes off it: the
+        governor plans with the memory that is there; a device-OOM
+        retry halves it (_tighten_budget) so the governor re-plans
+        chunked. Cached per (setting, tightening, resident bytes) —
+        resolution may query device memory stats once."""
+        resident = self.resident_table_bytes
+        key = (self.device_memory_budget, self._oom_divisor, resident)
         if self._budget_resolved is None or self._budget_resolved[0] != key:
-            resolved = MB.resolve_budget(self.device_memory_budget)
+            resolved = MB.resolve_budget(self.device_memory_budget,
+                                         resident=resident)
             floor = min(resolved, self._OOM_BUDGET_FLOOR)
             self._budget_resolved = (
                 key,
                 max(resolved // self._oom_divisor, floor),
             )
         return self._budget_resolved[1]
+
+    def _catalog_sum(self, name: str) -> int:
+        return sum(int(getattr(conn, name, 0) or 0)
+                   for conn in self.catalogs.values())
+
+    # what this executor's catalogs hold resident on the device and
+    # what loading it cost (connectors/cached.py keeps the tallies; the
+    # registry, /metrics and EXPLAIN ANALYZE read them under the
+    # connector's names): process truths, not per-attempt counts
+    @property
+    def resident_table_bytes(self) -> int:
+        return self._catalog_sum("resident_table_bytes")
+
+    @property
+    def resident_loads(self) -> int:
+        return self._catalog_sum("resident_loads")
+
+    @property
+    def resident_load_wall_us(self) -> int:
+        return self._catalog_sum("resident_load_wall_us")
 
     def _tighten_budget(self) -> None:
         """Halve the resolved budget for the next attempt (the device
@@ -1422,9 +1493,21 @@ class Executor:
         if not base_pages or "pages" in vars(conn):
             return None
         names = tuple(cur.columns)
-        probe = conn.gen_body(cur.table, 8, names)
-        if probe is None:
-            return None
+        # what a split's columns come from: a read of the table the
+        # connector holds on the device (connectors/cached.py), or the
+        # connector's traceable generator. A stored table loads at its
+        # first touch, here, so the budget below already has it
+        stored = getattr(conn, "stored_source", None)
+        src = stored(cur.table, names, SH.bucket(self.page_rows)) \
+            if stored is not None else None
+        if src is None:
+            if conn.gen_body(cur.table, 8, names) is None:
+                return None
+            src = _GeneratedSource(conn, cur.table, names)
+        # the programs made below stay in the jit cache: they close
+        # over the source's reads, never over the source, whose args
+        # are a stored table's buffers (a write has to free them)
+        reads = src.reads
         schema = conn.table_schema(cur.table)
         scan_types = tuple(schema.column_type(c) for c in names)
         dicts = getattr(conn, "_dicts", {}).get(cur.table, {})
@@ -1470,6 +1553,28 @@ class Executor:
             return _apply_steps(make_page(datas, valid, n_pad, count),
                                 steps)
 
+        def run_one(n_pad, *a):
+            # a = the source's buffers (none for a generator), then
+            # the split's start and count
+            return run_split(reads.body(n_pad, *a[:-2]), n_pad, *a[-2:])
+
+        def jit_one(n_pad):
+            def make():
+                return functools.partial(run_one, n_pad)
+
+            if src.args:
+                return self._jit(
+                    ("stored", node, key_extra, cur.table, n_pad),
+                    make=make)
+            return self._jit(
+                ("fused", node, key_extra, cur.table, n_pad), make=make)
+
+        def count_stored(n_splits, n_pad):
+            if src.args:
+                self.resident_splits_scanned += n_splits
+                self.resident_bytes_scanned += (
+                    n_splits * n_pad * src.slot_bytes)
+
         scan_row_b = chain_row_b
 
         # cross-query launch batching (ISSUE 17): when the concurrent
@@ -1478,10 +1583,13 @@ class Executor:
         # shared batch point — compatible launches from OTHER queries
         # (equal frozen plan nodes hash equal, so identical statements
         # across clients share a key) gang into one vmapped step.
+        # (a stored source's launches run solo: ganging them is not
+        # built)
         xq_on = (
             self.launch_batcher is not None
             and self.cross_query_batching not in
             (False, None, "false", "off")
+            and not src.args
         )
 
         def make_xq_fn(n_pad, B):
@@ -1491,7 +1599,7 @@ class Executor:
             # every ganged query walks away with exactly the page its
             # solo launch would have produced (row parity is
             # structural, not reassembled on the host)
-            gen_b = conn.gen_batch(cur.table, n_pad, names)
+            gen_b = reads.batch(n_pad)
 
             def post(datas, valid, count):
                 return _apply_steps(
@@ -1589,15 +1697,14 @@ class Executor:
                 solo_mark = self.launch_batcher.solo_inflight(
                     ("xq", node, key_extra, cur.table, n_pad))
             n_pad = SH.bucket(split.row_count)
-            key = ("fused", node, key_extra, cur.table, n_pad)
-            run_fused = self._jit(key, make=lambda: functools.partial(
-                run_split, conn.gen_body(cur.table, n_pad, names),
-                n_pad))
+            run_fused = jit_one(n_pad)
             with solo_mark:
                 page, flags = run_fused(
+                    *src.args,
                     jnp.int64(split.start_row),
                     jnp.int64(split.row_count),
                 )
+            count_stored(1, n_pad)
             # the generation buffer lives INSIDE the fused program and
             # never passes _account_page — account it here so
             # peak_device_bytes stays honest for fused pipelines
@@ -1635,43 +1742,45 @@ class Executor:
             return out
 
         def build_batch_fn():
+            # every run_batch below is called with the source's buffers
+            # (none for a generator) before the splits' starts and
+            # counts
             if agg_tail is None:
                 # page-emitting chain: vmap the fused body over the
                 # stacked [B, n_pad] batch; the batch emits as ONE
                 # page of B*n_pad slots (the exact concatenation of
                 # the per-split pages), so downstream per-page
                 # programs amortize their launches by B too
-                gen_b = conn.gen_batch(cur.table, n_pad_all, names)
-
                 def post(datas, valid, count):
                     return _apply_steps(
                         make_page(datas, valid, n_pad_all, count),
                         steps,
                     )
 
-                def run_batch(starts, counts):
-                    datas, valid = gen_b(starts)
-                    pages, flags = jax.vmap(post)(datas, valid, counts)
+                def run_batch(*a):
+                    datas, valid = reads.batch(n_pad_all, *a[:-2])(a[-2])
+                    pages, flags = jax.vmap(post)(datas, valid, a[-1])
                     return (
                         _merge_leading(pages),
                         tuple(jnp.any(f) for f in flags),
                     )
 
                 return run_batch
-            gen_fn = conn.gen_body(cur.table, n_pad_all, names)
             if steps[-1][0] == "map":
                 # global partial-agg tail: scan over splits, stacking
                 # the 1-row state pages — the batch emits exactly the
                 # concat of the per-split states, so parity with the
                 # unbatched driver loop is bit-exact
-                def body(_, x):
-                    page, flags = run_split(
-                        gen_fn, n_pad_all, x[0], x[1])
-                    return 0, (page, or_flags(flags))
+                def run_batch(*a):
+                    gen_fn = reads.body(n_pad_all, *a[:-2])
 
-                def run_batch(starts, counts):
+                    def body(_, x):
+                        page, flags = run_split(
+                            gen_fn, n_pad_all, x[0], x[1])
+                        return 0, (page, or_flags(flags))
+
                     _, (states, flags) = jax.lax.scan(
-                        body, 0, (starts, counts))
+                        body, 0, (a[-2], a[-1]))
                     return _merge_leading(states), (jnp.any(flags),)
 
                 return run_batch
@@ -1685,14 +1794,17 @@ class Executor:
             pre = steps[:-1]
             tail_fn = steps[-1][1]
 
-            def one_state(start, count):
-                datas, valid = gen_fn(start)
-                page, flags = _apply_steps(
-                    make_page(datas, valid, n_pad_all, count), pre)
-                st, ovf = tail_fn(page)
-                return st, or_flags(flags) | ovf
+            def run_batch(*a):
+                gen_fn = reads.body(n_pad_all, *a[:-2])
+                starts, counts = a[-2:]
 
-            def run_batch(starts, counts):
+                def one_state(start, count):
+                    datas, valid = gen_fn(start)
+                    page, flags = _apply_steps(
+                        make_page(datas, valid, n_pad_all, count), pre)
+                    st, ovf = tail_fn(page)
+                    return st, or_flags(flags) | ovf
+
                 # split 0 seeds the carry (merged alone into the carry
                 # capacity, so init and body share one state shape)
                 st0, f0 = one_state(starts[0], counts[0])
@@ -1724,9 +1836,15 @@ class Executor:
                     i += 1
                     continue
                 B = SH.split_batch_bucket(len(chunk))
-                key = ("fused_batch", node, key_extra, cur.table,
-                       n_pad_all, B)
-                run_batch = self._jit(key, make=build_batch_fn)
+                tail = (node, key_extra, cur.table, n_pad_all, B)
+                key = ("stored_batch" if src.args else "fused_batch",
+                       *tail)
+                run_batch = (
+                    self._jit(("stored_batch", *tail),
+                              make=build_batch_fn)
+                    if src.args else
+                    self._jit(("fused_batch", *tail),
+                              make=build_batch_fn))
                 starts = np.zeros(B, np.int64)
                 counts = np.zeros(B, np.int64)
                 for j, s in enumerate(chunk):
@@ -1736,6 +1854,7 @@ class Executor:
                     # metered h2d: 2xB int64 split descriptors per
                     # batched launch (exec/xfer.py choke point)
                     page, flags = run_batch(
+                        *src.args,
                         XF.to_device(starts, label="batch-starts"),
                         XF.to_device(counts, label="batch-starts"))
                 except Exception:
@@ -1750,6 +1869,7 @@ class Executor:
                     yield from stream_single()
                     return
                 self.splits_scanned += len(chunk)
+                count_stored(len(chunk), n_pad_all)
                 self._pending_overflow.extend(flags)
                 # vmapped batches materialize the [B, n_pad] stack;
                 # scanned (agg-tail) batches carry one split at a time
@@ -2166,7 +2286,7 @@ class Executor:
                     self._collect_stats.clear()
                 att_span = None
                 if tr is not None:
-                    att_span = tr.begin(
+                    att_span = self._attempt_span = tr.begin(
                         "attempt", f"a{attempts}", parent=exec_span,
                         boost=self._capacity_boost)
                     self.trace_spans += 1
@@ -2214,6 +2334,10 @@ class Executor:
                            launches=dict(self._launches_by_label),
                            exchange_launches=self.exchange_launches,
                            mesh_fused_rounds=self.mesh_fused_rounds,
+                           resident_splits_scanned=(
+                               self.resident_splits_scanned),
+                           resident_bytes_scanned=(
+                               self.resident_bytes_scanned),
                            **self._agg_sizing_attrs())
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
@@ -2270,6 +2394,8 @@ class Executor:
         self.mesh_fused_rounds = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
+        self.resident_splits_scanned = 0
+        self.resident_bytes_scanned = 0
         self._launches_by_label = {}
         self._agg_sizings = []
         self.splits_scanned = 0
@@ -2518,8 +2644,9 @@ class Executor:
                     on_attempt()
                 att_span = None
                 if tr is not None:
-                    att_span = tr.begin("attempt", f"a{attempts}",
-                                        boost=self._capacity_boost)
+                    att_span = self._attempt_span = tr.begin(
+                        "attempt", f"a{attempts}",
+                        boost=self._capacity_boost)
                     self.trace_spans += 1
                 try:
                     self._maybe_inject_oom()

@@ -31,7 +31,10 @@ than a failure:
 The budget itself: session property `device_memory_budget` (bytes;
 0 = auto). Auto resolves to the device's real HBM minus headroom on
 TPU and a generous cap on CPU (tier-1 tests see no behavior change
-unless they force a tiny budget).
+unless they force a tiny budget), less what the catalogs hold resident
+on the device (a stored table, connectors/cached.py: the store checks
+at load that it fits, the executor plans every buffer with what is
+left).
 
 Shares: one pipeline holds several live buffers at once (build +
 probe page + output page + downstream materialization), so no single
@@ -80,28 +83,39 @@ def device_hbm_bytes() -> Optional[int]:
     return None
 
 
-def resolve_budget(setting: int, backend: Optional[str] = None) -> int:
+def resolve_budget(setting: int, backend: Optional[str] = None,
+                   resident: int = 0) -> int:
     """device_memory_budget resolution: an explicit positive setting
     wins; 0 (auto) = real HBM minus headroom on TPU, the generous
     CPU_BUDGET elsewhere. A TPU whose runtime reports no memory limit
-    is an error, not a guessed size."""
-    if setting and int(setting) > 0:
-        return int(setting)
+    is an error, not a guessed size.
+
+    ``resident`` is what the catalogs hold on the device for good
+    (stored tables, connectors/cached.py): it comes off the auto
+    budget, and an explicit setting is held to what is left, so the
+    governor never plans with memory a resident table has. With
+    nothing resident an explicit setting is taken as it is."""
+    explicit = int(setting) if setting and int(setting) > 0 else 0
+    if explicit and not resident:
+        return explicit
     import jax
 
     if backend is None:
         backend = jax.default_backend()
     if backend != "tpu":
-        return CPU_BUDGET
-    hbm = device_hbm_bytes()
-    if not hbm:
-        raise RuntimeError(
-            "device_memory_budget=auto needs the device's HBM size, "
-            "and memory_stats() of "
-            f"{jax.local_devices()[0].device_kind!r} reports no "
-            "bytes_limit; set device_memory_budget explicitly"
-        )
-    return hbm - hbm // HEADROOM_DIV
+        auto = CPU_BUDGET
+    else:
+        hbm = device_hbm_bytes()
+        if not hbm:
+            raise RuntimeError(
+                "device_memory_budget=auto needs the device's HBM size, "
+                "and memory_stats() of "
+                f"{jax.local_devices()[0].device_kind!r} reports no "
+                "bytes_limit; set device_memory_budget explicitly"
+            )
+        auto = hbm - hbm // HEADROOM_DIV
+    left = max(auto - int(resident), 0)
+    return min(explicit, left) if explicit else left
 
 
 def group_share_bytes(share: float, setting: int = 0,

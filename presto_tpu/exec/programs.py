@@ -42,6 +42,16 @@ PROGRAM_LABELS: Dict[str, str] = {
     "fused_batch": "scan",
     "xq_batch": "scan",
     "scan_gen": "scan",
+    # the same steps over a table stored on the device
+    # (connectors/cached.py): the split's columns are slices of the
+    # program's arguments, not generated, so a trace tells a stored
+    # scan from a generated one
+    "stored": "scan",
+    "stored_batch": "scan",
+    # the store's own: a loaded page written into the resident buffers
+    # in place, and the pages() path's read of one split
+    "resident_store": "scan",
+    "resident_read": "scan",
     "filter": "filter_project",
     "filter_lazy": "filter_project",
     "project": "filter_project",
@@ -112,6 +122,7 @@ PROGRAM_LABELS: Dict[str, str] = {
 # the launches program_launches counts: a fused scan step on one
 # device, a scan round (D splits, one a chip) over a mesh
 FUSED_SCAN_LABELS = frozenset(("fused", "fused_batch", "xq_batch",
+                               "stored", "stored_batch",
                                "d_scan", "d_fused"))
 
 

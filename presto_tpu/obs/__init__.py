@@ -90,6 +90,11 @@ SPAN_KINDS: Dict[str, str] = {
             "the summed span wall equals the query's transfer_wall_s "
             "counter — the copy-time phase ROADMAP item 6 drives "
             "toward zero",
+    "resident_load": "one table loaded into the device-resident "
+                     "store (connectors/cached.py), recorded on the "
+                     "statement whose scan touched it first; attrs: "
+                     "columns, slots, bytes; on the profiler's host "
+                     "plane resident_load:<table>",
     "cache": "one result-cache point served (presto_tpu/cache/): "
              "hit:<Node> replays stored pages (attrs: pages, key) in "
              "the span's interval — compile+launch skipped; "

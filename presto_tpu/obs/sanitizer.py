@@ -80,6 +80,10 @@ LOCK_REGISTRY: Dict[str, str] = {
         "heartbeat threads write (update_from_info), scheduler "
         "dispatch threads read (might_contain) — pure bytes ops, "
         "probes themselves go over connpool OUTSIDE the lock",
+    "connectors.cached.ResidentConnector._lock":
+        "the device-resident table store: the table map, and a "
+        "table's LOAD, which runs under it on purpose so that "
+        "concurrent first touches load once and the rest wait",
     "connectors.stream.StreamConnector._cv":
         "the append-log table map + offset advance; appends "
         "notify_all so tailing long-pollers (wait_for_offset) wake",

@@ -509,6 +509,7 @@ class QueryManager:
         "cross_query_batched_queries", "batch_gather_wait_ms",
         "device_launches", "exchange_launches", "mesh_fused_rounds",
         "dispatch_wall_us", "device_wait_us",
+        "resident_splits_scanned", "resident_bytes_scanned",
     )
     _EXEC_TOTAL_MAX = ("queries_per_launch",)
 

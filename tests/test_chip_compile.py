@@ -250,6 +250,80 @@ def test_q3_compacted_aggregation_compiles(one_chip, tpu_branches):
         page(C))
 
 
+class _Handed(BaseException):
+    """A launch caught before it runs (a BaseException: the batched
+    driver's escape to the one-split loop catches Exception)."""
+
+
+@pytest.mark.parametrize("template", ["q1", "q6"])
+def test_stored_scan_batch_takes_the_table_as_arguments(
+        template, one_chip, tpu_branches, monkeypatch):
+    """ISSUE 33: lineitem at SF10 as the resident store holds it
+    (105 M slots and the pad; 64-bit columns as uint32[2, slots])
+    handed to the chip's batched fused scan step of Q1 / Q6, 64 splits
+    a launch, as ARGUMENTS. It compiles for the chip, and by the
+    compiler's own account its temporaries are megabytes: nothing the
+    size of a column is made. (A 64-bit argument of a column's size is
+    split into its halves at the top of every program that takes it:
+    0.85 GB of temporaries and 20 ms a launch, PERF.md, PR 33.)"""
+    from benchmarks.harness import manifest
+    from presto_tpu.cache.rules import snapshot_of
+    from presto_tpu.connectors import cached
+    from presto_tpu.connectors.base import Split
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import membudget as MB
+    from presto_tpu.exec import programs as PG
+    from presto_tpu.runner import LocalRunner
+
+    cell = manifest.load_cell("scan_sf10_resident_solo")
+    (st,) = [s for s in cell.every if s.key == f"{template}_sf10#0"]
+    inner = TpchConnector(scale=10.0)
+    conn = cached.ResidentConnector(inner, tables=["lineitem"])
+    slots = inner.row_count("lineitem")
+    cap = slots + cached.LOAD_ROWS
+    assert slots == 105_000_000
+    names = tuple(inner.table_schema("lineitem").column_names())
+    piece = jax.eval_shape(lambda: inner.page_for_split(
+        Split("lineitem", 0, cached.LOAD_ROWS), names))
+    # the store's buffers, described, not made (cached._load_locked)
+    page = jax.tree.map(
+        lambda x: _spec((2, cap), jnp.uint32, one_chip)
+        if x.dtype.itemsize == 8 else _spec((cap,), x.dtype, one_chip),
+        piece)
+    nbytes = sum(x.dtype.itemsize * x.size for x in jax.tree.leaves(page))
+    assert nbytes == cap * 93
+    conn._store["lineitem"] = cached._Stored(
+        snapshot_of(inner, "lineitem"), page,
+        tuple(cached._leaf_dtypes([b]) for b in piece.blocks),
+        slots, cached.LOAD_ROWS, nbytes)
+    monkeypatch.setattr(MB, "device_hbm_bytes", lambda: 16 << 30)
+
+    def handed(sink, program, *args, **kwargs):
+        raise _Handed(program, args)
+
+    monkeypatch.setattr(PG, "launch", handed)
+    runner = LocalRunner({"tpch": conn}, default_catalog="tpch",
+                         page_rows=1 << 18)
+    runner.executor.fault_rows = SH.SAFE_BUFFER_ROWS
+    with pytest.raises(_Handed) as caught:
+        runner.execute(st.sql)
+    program, args = caught.value.args
+    assert program.label == "stored_batch"
+    assert runner.executor._budget() == \
+        (16 << 30) - (16 << 30) // MB.HEADROOM_DIV - nbytes
+    *buffers, starts, counts = jax.tree.leaves(args)
+    assert starts.shape == counts.shape == (SH.SPLIT_BATCH_MAX,)
+    columns = {"q1": 7, "q6": 4}[template]
+    assert len(buffers) == columns + 1      # and the validity
+    assert all(b.shape[-1] == cap for b in buffers)
+    compiled = program.jitted.lower(*_on(args, one_chip)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > cap * (
+        {"q1": 44, "q6": 28}[template] + 1)
+    assert memory.temp_size_in_bytes < 64 << 20, memory
+    assert memory.output_size_in_bytes < 1 << 20
+
+
 def test_mesh_q3_compaction_compiles_shard_local(topo, tpu_branches):
     """Q3 at SF1 over four chips (ISSUE 30): a scan round's join output
     (262,144 slots a chip) compacts into each chip's 65,536-slot share

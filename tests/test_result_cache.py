@@ -16,8 +16,8 @@ Covers the subsystem contract by contract:
   - the process-shared store under concurrency: the same statement
     from 8 client threads executes at least once, the rest hit, all
     rows identical;
-  - the CachingConnector key fix: canonical structural constraint
-    encoding + snapshot versioning + the invalidation registration.
+  - the resident store's key (connectors/cached.py): one copy whatever
+    the constraint, snapshot versioning, the invalidation registration.
 """
 
 import collections
@@ -33,7 +33,7 @@ from presto_tpu.cache import (
     shared_cache_if_exists,
     uncacheable_reason,
 )
-from presto_tpu.connectors.cached import CachingConnector
+from presto_tpu.connectors.cached import ResidentConnector
 from presto_tpu.connectors.memory import MemoryConnector
 from presto_tpu.connectors.tpch import TpchConnector
 from presto_tpu.exec import plan as P
@@ -527,60 +527,66 @@ def test_concurrent_clients_share_one_execution(conn):
         srv.stop()
 
 
-# ------------------------------------------- CachingConnector key fix
+# --------------------------- the resident store's key (connectors/cached.py)
 class _CountingConnector(MemoryConnector):
+    """Counts loads: a table is loaded by one walk of its splits."""
+
     def __init__(self):
         super().__init__()
-        self.pages_calls = 0
+        self.loads = 0
 
-    def pages(self, table, columns=None, target_rows=1 << 20,
-              constraint=None):
-        self.pages_calls += 1
-        return super().pages(table, columns, target_rows, constraint)
+    def splits(self, table, target_rows):
+        self.loads += 1
+        return super().splits(table, target_rows)
 
 
 def test_caching_connector_canonical_constraint_key():
     """Structurally equal constraints built as distinct objects must
-    share one cache entry (the repr() key split the cache whenever a
-    constraint carried any non-literal; the canonical structural
-    encoding cannot)."""
+    share one stored copy. (The page cache this wrapper once was keyed
+    its page lists by the constraint, canonically encoded; the store
+    holds a table once and the constraint is no part of its key, ISSUE
+    33: any constraint, equal or not, is served from the one copy.)"""
     inner = _CountingConnector()
     inner.create_table("t", ["a", "b"], [T.BIGINT, T.BIGINT],
                        [(i, i * 2) for i in range(10)])
-    cc = CachingConnector(inner)
+    cc = ResidentConnector(inner)
     c1 = (("a", 2, None),)
     c2 = tuple([("a", 2, None)])  # distinct object, same structure
     r1 = [p for p in cc.pages("t", constraint=c1)]
-    assert inner.pages_calls == 1
+    assert cc.resident_loads == 1
+    loads = inner.loads
     r2 = [p for p in cc.pages("t", constraint=c2)]
-    assert inner.pages_calls == 1, "second scan must hit the cache"
-    assert len(r1) == len(r2)
+    r3 = [p for p in cc.pages("t", constraint=(("b", 4, 8),))]
+    assert cc.resident_loads == 1, "second scan must hit the store"
+    # (each pages() call asks the inner connector for its splits, once)
+    assert inner.loads == loads + 2
+    assert len(r1) == len(r2) == len(r3)
 
 
 def test_caching_connector_snapshot_and_invalidate():
-    """Wrapping a WRITABLE connector is safe now: the inner snapshot
-    version rides in the page-cache key, and the invalidation path
+    """Wrapping a WRITABLE connector is safe: the inner snapshot
+    version is the stored copy's key, and the invalidation path
     (runner._invalidate_caches -> invalidate()) reclaims bytes."""
     inner = _CountingConnector()
     inner.create_table("t", ["a"], [T.BIGINT], [(1,), (2,)])
-    cc = CachingConnector(inner)
+    cc = ResidentConnector(inner)
     rows = [r for p in cc.pages("t") for r in p.to_pylist()]
     assert len(rows) == 2
-    assert inner.pages_calls == 1
+    assert cc.resident_loads == 1
     inner.insert("t", [(3,)])  # write THROUGH the wrapper's inner
     rows = [r for p in cc.pages("t") for r in p.to_pylist()]
-    assert len(rows) == 3, "stale page list served after a write"
-    assert inner.pages_calls == 2
-    assert cc.cached_page_count > 0
+    assert len(rows) == 3, "stale copy served after a write"
+    assert cc.resident_loads == 2
+    assert cc.resident_table_bytes > 0
     assert cc.invalidate("t") > 0
-    assert cc.cached_page_count == 0
+    assert cc.resident_table_bytes == 0
 
 
 def test_runner_invalidation_reaches_wrapped_connector():
     """The runner's write path drops a wrapping page cache's stale
     lists through the registered invalidation hook."""
     inner = MemoryConnector()
-    cc = CachingConnector(inner)
+    cc = ResidentConnector(inner)
     r = LocalRunner({"mem": cc}, default_catalog="mem")
     r.execute("create table t as select 1 x")
     assert r.execute("select * from t").rows == [(1,)]

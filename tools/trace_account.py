@@ -160,7 +160,11 @@ def record(cell_name: str, sids: List[str], out_dir: str,
                 if k in ("device_launches", "program_launches",
                          "exchange_launches", "mesh_fused_rounds",
                          "dispatch_wall_us", "device_wait_us",
-                         "spill_partitions_used")}
+                         "spill_partitions_used",
+                         "resident_splits_scanned",
+                         "resident_bytes_scanned",
+                         "resident_table_bytes", "resident_loads",
+                         "resident_load_wall_us")}
             out.append(acc)
     finally:
         served.stop()
