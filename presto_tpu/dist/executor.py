@@ -46,6 +46,7 @@ from presto_tpu.exec.executor import (
     _partial_agg_page,
     _partial_global_agg,
     _probe_join_page,
+    _returning_rows,
     _semi_join_page,
     _topn_merge,
 )
@@ -74,6 +75,12 @@ class DistExecutor(Executor):
     stream code paths (XLA replicates the compute across devices), sharded
     nodes run shard_map-wrapped kernels.
     """
+
+    # an eager program between launches is the pace of a mesh
+    # statement, so every program made here returns its page's row
+    # count with the page (Executor._jit; _mesh_jit for the shard_map
+    # programs): pages() dispatches nothing for the query trace
+    launch_counts_rows = True
 
     def __init__(self, catalogs, mesh: Mesh, **kw):
         super().__init__(catalogs, **kw)
@@ -145,10 +152,20 @@ class DistExecutor(Executor):
         exec/programs.launch like a one-device program's (counted,
         timed and annotated on the calling executor). The default
         specs are a shard-local page -> page map; ``fenced`` marks a
-        body that holds a cross-device collective (see _fenced)."""
+        body that holds a cross-device collective (see _fenced).
+
+        The program also returns the row count of the page it makes
+        (the body's output is a page or a tuple that begins with one),
+        counted in the body: a chip's own sum(valid) under the page's
+        spec, so one entry a chip of a sharded page, one of a
+        replicated page, and no collective; the host adds them after
+        the run (Executor._resolve_row_counts)."""
+        page_spec = out_specs[0] if isinstance(out_specs, tuple) \
+            else out_specs
         fn = self._jit(key, make=lambda: jax.shard_map(
-            body, mesh=self.mesh, in_specs=in_specs,
-            out_specs=out_specs, check_vma=False))
+            _returning_rows(body), mesh=self.mesh, in_specs=in_specs,
+            out_specs=(out_specs, page_spec), check_vma=False),
+            returns_rows=True)
         return self._fenced(fn) if fenced else fn
 
     # ---------------------------------------------------------- dist tags
@@ -341,9 +358,9 @@ class DistExecutor(Executor):
         n, gen_local, make_page, rounds = gen
         fn = self._mesh_jit(
             ("d_scan", node.catalog, node.table, tuple(node.columns), n),
-            gen_local)
+            lambda start_arr: make_page(*gen_local(start_arr)))
         for start_arr in rounds():
-            yield make_page(*fn(start_arr))
+            yield fn(start_arr)
 
     def _round_generator(self, node: P.TableScan):
         """A sharded scan of an on-device generator as rounds of D

@@ -55,6 +55,18 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "as one program over the mesh (d_fused: "
         "dist/executor.DistExecutor._fused_rounds); 0 where the chain "
         "fell back to one program a plan node, and on one device"),
+    "row_counts_launched": (
+        "gauge", "(plan node, page) row counts this attempt kept for "
+        "the query trace or EXPLAIN ANALYZE that rode in the launch "
+        "that made the page (Page.rows: over a mesh every program "
+        "returns its page's count, a chip's own sum a chip); 0 with "
+        "tracing off"),
+    "row_counts_eager": (
+        "gauge", "the ones that Executor.pages computed with "
+        "page.num_rows() instead, two eager programs dispatched from "
+        "the driver thread between launches: every count on one "
+        "device, 0 over a mesh for a statement whose pages all come "
+        "out of programs"),
     "resident_table_bytes": (
         "gauge", "device bytes the catalogs' stored tables hold now "
         "(connectors/cached.py: every column and the validity of each "
@@ -82,8 +94,9 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "cache load)"),
     "device_wait_us": (
         "gauge", "host microseconds blocked on the device this "
-        "attempt: every exec/xfer.py pull (to_host, np_host), "
-        "devsync.drain and the overflow-flag read"),
+        "attempt: every exec/xfer.py pull (to_host, np_host; the "
+        "overflow flags' one pull and the row counts' among them) "
+        "and devsync.drain"),
     "splits_scanned": (
         "gauge", "real (unpadded) splits covered by this attempt's "
         "fused-scan launches — splits_per_launch is the ratio"),

@@ -288,11 +288,62 @@ class NodeStats:
     label: str
     wall_s: float = 0.0
     pages: int = 0
+    # one entry a page: a host int, or a device value left unread
+    # until the run is over (Executor._resolve_row_counts reads them
+    # all at once): the scalar of page.num_rows(), or the array a
+    # launch returned with the page (Page.rows), whose sum it is
     row_counts: list = dataclasses.field(default_factory=list)
 
     @property
     def rows(self) -> int:
-        return sum(int(c) for c in self.row_counts)
+        return sum(int(np.sum(c)) for c in self.row_counts)
+
+
+def _page_of(out) -> Optional[Page]:
+    """The page a program's output holds: the output itself, or the
+    first element of a tuple (page, flags...); None for anything
+    else."""
+    if isinstance(out, Page):
+        return out
+    if isinstance(out, tuple) and out and isinstance(out[0], Page):
+        return out[0]
+    return None
+
+
+def _rows_key(key):
+    """The jit-cache key of the program that also returns its page's
+    row count; it still begins with the program's label."""
+    return (*key, "rows") if isinstance(key, tuple) else (key, "rows")
+
+
+def _returning_rows(fn):
+    """``fn`` as a program that also returns the row count of the page
+    it makes, computed in the same launch: ``(out, rows)`` with
+    ``rows`` an int32[1] (under shard_map a chip's own count, so one
+    entry a chip and no collective), or ``()`` where the output holds
+    no page."""
+    def counted(*args, **kwargs):
+        out = fn(*args, **kwargs)
+        page = _page_of(out)
+        if page is None:
+            return out, ()
+        return out, jnp.sum(page.valid, dtype=jnp.int32).reshape(1)
+
+    return counted
+
+
+def _attaching_rows(run):
+    """The caller's side of _returning_rows: the count travels on the
+    page (Page.rows) to the pages() boundary that wants it, and the
+    caller gets the output it always got."""
+    def launched(*args, **kwargs):
+        out, rows = run(*args, **kwargs)
+        page = _page_of(out)
+        if page is not None:
+            page.rows = rows
+        return out
+
+    return launched
 
 
 class Executor:
@@ -462,7 +513,8 @@ class Executor:
         # launch, which counts on the CALLING executor, this attempt:
         # device_launches = calls, dispatch_wall_us = host time inside
         # them, device_wait_us = host time blocked on the device
-        # (exec/xfer.py pulls, devsync.drain, the overflow-flag read);
+        # (exec/xfer.py pulls, the overflow flags' among them, and
+        # devsync.drain);
         # _launches_by_label feeds the attempt span while tracing;
         # exchange_launches = the calls among them whose program moves
         # rows between chips (family "exchange", over a mesh);
@@ -473,6 +525,13 @@ class Executor:
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
+        # the (plan node, page) row counts pages() kept this attempt
+        # under tracing or EXPLAIN ANALYZE: row_counts_launched = the
+        # page brought its count from the launch that made it
+        # (Page.rows), row_counts_eager = pages() dispatched
+        # page.num_rows(), two eager programs on the driver thread
+        self.row_counts_launched = 0
+        self.row_counts_eager = 0
         # scans of a stored table (connectors/cached.py), this attempt:
         # resident_splits_scanned = real splits whose columns the fused
         # scan step read from the store, resident_bytes_scanned = the
@@ -893,6 +952,7 @@ class Executor:
         stats = self._collect_stats
         if not stats:
             return
+        self._resolve_row_counts()
         for st in stats.values():
             if not isinstance(st, NodeStats):
                 continue
@@ -900,6 +960,22 @@ class Executor:
                         att_span.t0 + st.wall_s, parent=att_span,
                         rows=st.rows, pages=st.pages)
             self.trace_spans += 1
+
+    def _resolve_row_counts(self) -> None:
+        """Read the attempt's deferred row counts, all plan nodes' at
+        once: one metered pull through exec/xfer.py after the run
+        (the deferred-sync rule), then host ints."""
+        stats = [st for st in (self._collect_stats or {}).values()
+                 if isinstance(st, NodeStats)]
+        where = [(st, i) for st in stats
+                 for i, c in enumerate(st.row_counts)
+                 if not isinstance(c, int)]
+        if not where:
+            return
+        host = XF.to_host([st.row_counts[i] for st, i in where],
+                          label="row-counts")
+        for (st, i), c in zip(where, host):
+            st.row_counts[i] = int(np.sum(c))
 
     def _seed_profile(self, node) -> Optional[str]:
         """Observed-stats profile seeding (obs/profile.py): start the
@@ -930,6 +1006,7 @@ class Executor:
             prof["pages_out"] = int(pages_out)
         stats = self._collect_stats
         if stats:
+            self._resolve_row_counts()
             ops: Dict[str, int] = {}
             for st in stats.values():
                 if isinstance(st, NodeStats):
@@ -999,8 +1076,17 @@ class Executor:
         backend barely pays."""
         return self._tristate_on(self.device_exchange)
 
+    # whether the programs this executor makes also return the row
+    # count of the page they make (_returning_rows), so a pages()
+    # boundary under tracing or EXPLAIN ANALYZE dispatches no eager
+    # program for it. Over a mesh (dist/executor.py) they do: there an
+    # eager program between launches is the pace of a statement. On
+    # one device the host runs far ahead of the device, the eager count
+    # costs nothing end to end, and the programs stay as they are.
+    launch_counts_rows = False
+
     def _jit(self, key, fn=None, static_argnums=(), donate_argnums=(),
-             make=None):
+             make=None, returns_rows=False):
         """One program per CANONICAL key, jitted under the label the
         key begins with (exec/programs.py) and called through THE
         launch point, which counts and annotates the call on this
@@ -1019,22 +1105,43 @@ class Executor:
         in place and the invocation counts on buffers_donated. The
         donated program caches under a salted key so flipping the
         session property mid-executor can never hand a donating
-        program to a non-donating call site."""
+        program to a non-donating call site.
+
+        Where ``launch_counts_rows`` holds, or the function handed in
+        already ``returns_rows`` (a shard_map body counts a chip's own
+        rows: DistExecutor._mesh_jit), the program returns the page's
+        row count beside its output and the call hands it on as
+        ``Page.rows``; salted likewise, because executors that share
+        a jit cache need not agree."""
         if not self.use_jit:
-            return fn if fn is not None else make()
+            run = fn if fn is not None else make()
+            return _attaching_rows(run) if returns_rows else run
+        label = PG.label_of(key)
         donate = bool(donate_argnums) and self._donate_on()
         if donate:
             key = (key, "donate")
+        rows = returns_rows or self.launch_counts_rows
+        if rows:
+            key = _rows_key(key)
         prog = self._jit_cache.get(key)
         if prog is None:
             kw = {"static_argnums": static_argnums}
             if donate:
                 _filter_donation_warning()
                 kw["donate_argnums"] = donate_argnums
+            body = fn if fn is not None else make()
+            if rows and not returns_rows:
+                body = _returning_rows(body)
             prog = self._jit_cache[key] = PG.Program(
-                PG.label_of(key[0] if donate else key),
-                fn if fn is not None else make(), donates=donate, **kw)
-        return functools.partial(PG.launch, self, prog)
+                label, body, donates=donate, **kw)
+        run = functools.partial(PG.launch, self, prog)
+        return _attaching_rows(run) if rows else run
+
+    def _jit_drop(self, key) -> None:
+        """Forget the program _jit made for ``key`` (a chain that did
+        not trace), under whichever salt it was cached."""
+        for k in (key, _rows_key(key)):
+            self._jit_cache.pop(k, None)
 
     def count_launch(self, prog, wall_ns: int) -> None:
         """THE sink exec/programs.launch counts a program call on."""
@@ -1064,7 +1171,8 @@ class Executor:
 
     def count_device_wait(self, wall_s: float) -> None:
         """THE sink exec/xfer.py counts host time blocked on the
-        device on (its pulls, devsync.drain, the overflow-flag read)."""
+        device on (its pulls, the overflow flags' among them, and
+        devsync.drain)."""
         self.device_wait_us += int(round(wall_s * 1e6))
 
     # ------------------------------------------- device-memory governor
@@ -1360,17 +1468,29 @@ class Executor:
                 break
             st.wall_s += _time.perf_counter() - t0
             st.pages += 1
-            # device scalar; resolved after the run (deferred-sync
-            # rule). Host-served pages (cache replay / RemoteSource at
-            # the host sink) count host-side instead — num_rows() on a
-            # numpy page would implicitly re-stage the valid mask, an
-            # un-metered crossing the transfer auditor exists to kill
-            v = page.valid
-            st.row_counts.append(
-                int(XF.np_host(v).sum()) if isinstance(v, np.ndarray)
-                else page.num_rows())
+            st.row_counts.append(self._deferred_rows(page))
             self._account_page(page)
             yield page
+
+    def _deferred_rows(self, page: Page):
+        """One page's row count for the per-node accounting: a device
+        value, resolved after the run (deferred-sync rule). The page
+        brings it where the launch that made it counted it (Page.rows;
+        a boundary that passes the page through unchanged hands the
+        same count on); where it brings none, page.num_rows() is two
+        eager programs dispatched from here. Host-served pages (cache
+        replay / RemoteSource at the host sink) count host-side
+        instead — num_rows() on a numpy page would implicitly re-stage
+        the valid mask, an un-metered crossing the transfer auditor
+        exists to kill."""
+        v = page.valid
+        if isinstance(v, np.ndarray):
+            return int(XF.np_host(v).sum())
+        if page.rows is not None:
+            self.row_counts_launched += 1
+            return page.rows
+        self.row_counts_eager += 1
+        return page.num_rows()
 
     def _scan_chain(self, node: P.PhysicalNode, *, through_joins: bool):
         """Walk a Filter/Project/Exchange chain (and, when
@@ -1655,7 +1775,7 @@ class Executor:
                     # conservative escape (the stream_batched shape):
                     # a chain that does not trace under vmap demotes
                     # every participant to its solo path
-                    self._jit_cache.pop(jkey, None)
+                    self._jit_drop(jkey)
                     self.split_batch_fallbacks += 1
                     raise
                 return [out[j] for j in range(len(entries))]
@@ -1864,7 +1984,7 @@ class Executor:
                     # under vmap/scan (custom kernels, host callbacks)
                     # runs the per-split loop instead — nothing has
                     # been yielded yet, so the stream restarts whole
-                    self._jit_cache.pop(key, None)
+                    self._jit_drop(key)
                     self.split_batch_fallbacks += 1
                     yield from stream_single()
                     return
@@ -2255,8 +2375,13 @@ class Executor:
         # check is the entire cost with tracing off. Tracing borrows
         # the EXPLAIN ANALYZE per-node accounting for operator spans;
         # per-page cost is two perf_counter calls plus retaining one
-        # deferred row-count scalar per (node, page) — no device sync
-        # until after the run (the reference always collects
+        # deferred row count per (node, page): the one the page
+        # brings from the launch that made it (Page.rows: over a mesh
+        # every program returns it, so nothing is dispatched here),
+        # else page.num_rows(), two eager programs (one device: the
+        # host is far ahead, they cost nothing end to end). No device
+        # sync until after the run, where _resolve_row_counts reads
+        # them in one pull (the reference always collects
         # OperatorStats; execute() retains every output page anyway,
         # so the handles are marginal). query_trace_enabled=false
         # drops all of it for latency-critical serving.
@@ -2334,6 +2459,9 @@ class Executor:
                            launches=dict(self._launches_by_label),
                            exchange_launches=self.exchange_launches,
                            mesh_fused_rounds=self.mesh_fused_rounds,
+                           row_counts_launched=(
+                               self.row_counts_launched),
+                           row_counts_eager=self.row_counts_eager,
                            resident_splits_scanned=(
                                self.resident_splits_scanned),
                            resident_bytes_scanned=(
@@ -2392,6 +2520,8 @@ class Executor:
         self.device_launches = 0
         self.exchange_launches = 0
         self.mesh_fused_rounds = 0
+        self.row_counts_launched = 0
+        self.row_counts_eager = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self.resident_splits_scanned = 0
@@ -2497,9 +2627,7 @@ class Executor:
                 self._account_page(dp)
                 if st is not None:
                     st.pages += 1
-                    st.row_counts.append(
-                        int(XF.np_host(dp.valid).sum())
-                        if serve_host else dp.num_rows())
+                    st.row_counts.append(self._deferred_rows(dp))
                 yield dp
             if tr is not None:
                 tr.complete("cache", f"hit:{label}", t0, tr.now(),
@@ -2585,15 +2713,15 @@ class Executor:
             )
 
     def _overflow_flagged(self) -> bool:
-        """OR-reduce the attempt's deferred overflow flags — the ONE
-        host sync of the deferred-sync discipline (see __init__)."""
+        """Whether any of the attempt's deferred overflow flags is
+        set — the ONE host sync of the deferred-sync discipline (see
+        __init__): the flags are read together in one metered pull
+        (no eager program folds them first) and OR-ed on the host."""
         if not self._pending_overflow:
             return False
-        flag = self._pending_overflow[0]
-        for f in self._pending_overflow[1:]:
-            flag = flag | f
-        with XF.device_wait("overflow-flag"):
-            return bool(flag)
+        flags = XF.to_host(self._pending_overflow,
+                           label="overflow-flag")
+        return any(bool(np.any(f)) for f in flags)
 
     def stream_fragment(self, node: P.PhysicalNode, emit,
                         cancelled=lambda: False,
@@ -2749,6 +2877,7 @@ class Executor:
         self._collect_stats = {}
         try:
             names, rows = self.execute(node)
+            self._resolve_row_counts()
             stats = dict(self._collect_stats)
         finally:
             self._collect_stats = None

@@ -28,10 +28,13 @@ Two sides, one discipline (the QUERY_COUNTERS / LOCK_REGISTRY model):
            so Chrome traces show copy time as its own phase. A d2h
            pull is also host time BLOCKED ON THE
            DEVICE (the value is ready when the programs that make it
-           have run): it counts on `device_wait_us` with the two waits
-           that cross no page, `devsync.drain` and the executor's
-           overflow-flag read (`device_wait`), and every such wait is
-           a `wait:<site>` annotation on the profiler's host plane.
+           have run): it counts on `device_wait_us` with the wait
+           that crosses no page, `devsync.drain` (`device_wait`), and
+           every such wait is a `wait:<site>` annotation on the
+           profiler's host plane. The executor's two reads at an
+           attempt's end, the overflow flags and the deferred row
+           counts, are one pull each (`wait:overflow-flag`,
+           `wait:row-counts`).
 
 Sink binding is per-thread (execute()/stream_fragment() install the
 running executor via swap_sink), so concurrent per-query executors on
@@ -113,11 +116,13 @@ TRANSFER_REGISTRY: Dict[str, Tuple[str, str, str]] = {
         "h2d", "control",
         "array-element flattening LUTs embedded at trace time "
         "(escaped raw-ok)"),
-    "exec.executor.Executor.pages": (
+    "exec.executor.Executor._deferred_rows": (
         "d2h", "data",
-        "EXPLAIN ANALYZE row accounting of HOST-served pages reads "
-        "the numpy valid mask in place (device pages keep the "
-        "deferred num_rows() scalar; a free view, never a copy)"),
+        "EXPLAIN ANALYZE / trace row accounting of HOST-served pages "
+        "(cache replay at the host sink, RemoteSource) reads the "
+        "numpy valid mask in place: a free view, never a copy. Device "
+        "pages keep a deferred count (Page.rows from the launch, else "
+        "num_rows()) that _resolve_row_counts pulls after the run"),
     "exec.executor.Executor._pages_impl": (
         "h2d", "data",
         "RemoteSource ingest: deserialized exchange pages stage onto "
@@ -126,13 +131,17 @@ TRANSFER_REGISTRY: Dict[str, Tuple[str, str, str]] = {
         "d2h", "data",
         "grace-join skew rebalance reads per-piece row counts (host "
         "decision point, admissible on the boosted retry path)"),
-    "exec.executor.Executor._cached_pages": (
+    "exec.executor.Executor._resolve_row_counts": (
         "d2h", "data",
-        "result-cache fragment replay accounting: host-sink hits "
-        "serve host pages directly — zero crossings — and read row "
-        "counts host-side for the stats plane (d2h on device pages "
-        "only); re-staging for device consumers lives in "
-        "_stage_replay"),
+        "the attempt's deferred (plan node, page) row counts, every "
+        "node's in ONE pull after the run: 4 bytes a chip a page "
+        "where the count rode in the launch, 8 a page where "
+        "num_rows() made it (tracing / EXPLAIN ANALYZE only)"),
+    "exec.executor.Executor._overflow_flagged": (
+        "d2h", "data",
+        "the attempt's deferred overflow flags, read together in ONE "
+        "pull after the last launch (a byte a flag) and OR-ed on the "
+        "host: the one host sync of the deferred-sync discipline"),
     "exec.executor.Executor._stage_replay": (
         "h2d", "data",
         "result-cache replay re-stage: stored host pages stage onto "
@@ -394,8 +403,8 @@ def _count_wait(wall: float) -> None:
 
 class device_wait:
     """``with device_wait(site):`` around a host read that blocks on the
-    device and crosses no page (devsync.drain, the overflow-flag read):
-    counted on ``device_wait_us`` and annotated like the pulls below."""
+    device and crosses no page (devsync.drain): counted on
+    ``device_wait_us`` and annotated like the pulls below."""
 
     __slots__ = ("note", "t0")
 

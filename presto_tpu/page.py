@@ -189,6 +189,14 @@ class Page:
 
     blocks: Tuple[Block, ...]
     valid: jnp.ndarray  # bool[capacity]
+    # the count of selected rows as the program that made this page
+    # computed it in the same launch (exec/executor._returning_rows): a
+    # device int32 array whose SUM is the count (one entry a chip for a
+    # page sharded over a mesh), or None. Not a leaf of the pytree, so
+    # it never enters a program, and every page derived from this one
+    # (with_valid, with_blocks, ...) starts without it.
+    rows: Optional[jnp.ndarray] = dataclasses.field(
+        default=None, compare=False, repr=False)
 
     @property
     def capacity(self) -> int:

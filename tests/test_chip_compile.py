@@ -356,13 +356,17 @@ def test_mesh_q3_compaction_compiles_shard_local(topo, tpu_branches):
                   type=t, nulls=None, dictionary=None)
             for t in ex.output_types(agg.source)),
         valid=_spec((4 << 18,), jnp.bool_, sharded))
-    compiled = ex._jit_cache[("d_stream_compact1", share)].jitted.lower(
+    compiled = ex._jit_cache[
+        ("d_stream_compact1", share, "rows")].jitted.lower(
         page).compile()
     text = compiled.as_text()
     assert "all-reduce" in text
     assert "all-to-all" not in text and "all-gather" not in text
-    out_page, _flag = compiled.output_shardings
+    # the accumulator's row count rides in the launch (ISSUE 37): a
+    # chip's own, sharded like the page, so it adds no collective
+    (out_page, _flag), rows = compiled.output_shardings
     assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
+    assert rows.spec == PS("d")
 
 
 def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
@@ -370,8 +374,8 @@ def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
     """Q3 at SF1 over four chips (ISSUE 32): a scan round's whole chain
     (the generator of 262,143 lineitem slots a chip, both generated
     joins, filter, project) is ONE program, d_fused, with no
-    collective in it (no windowed join: no flag) and a sharded page
-    out."""
+    collective in it (no windowed join: no flag), a sharded page out
+    and, sharded like it, each chip's count of the page's rows."""
     from presto_tpu.connectors.tpch import TpchConnector
     from presto_tpu.runner import LocalRunner
     from tests.tpch_queries import QUERIES
@@ -395,8 +399,10 @@ def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
     text = compiled.as_text()
     for collective in ("all-reduce", "all-to-all", "all-gather"):
         assert collective not in text, collective
-    out_page, flags = compiled.output_shardings
-    assert flags == ()
+    # the page's row count rides in the launch (ISSUE 37), a chip's
+    # own: still no collective (above)
+    (out_page, flags), rows = compiled.output_shardings
+    assert flags == () and rows.spec == PS("d")
     assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
     assert len(out_page.blocks) == len(ex.output_types(top))
 

@@ -67,6 +67,14 @@ def test_mesh_statement_equals_the_plain_reference(
     metrics = srv.metrics()
     assert metrics["exchange_launches"] >= 1
     assert metrics["device_launches"] > metrics["exchange_launches"]
+    # every row count the query trace kept rode in a launch (ISSUE 37):
+    # on the attempt span, on /metrics and in the operator spans
+    kept = sum(sp["attrs"]["pages"] for sp in _spans(info)
+               if sp["kind"] == "operator")
+    assert attempts[-1]["attrs"]["row_counts_eager"] == 0 \
+        == metrics["row_counts_eager"]
+    assert attempts[-1]["attrs"]["row_counts_launched"] == kept \
+        == metrics["row_counts_launched"] > launches["d_fused"]
 
 
 def _spans(info):

@@ -239,8 +239,8 @@ def test_mesh_compaction_is_shard_local_with_one_flag():
     # a replicated source takes the one-chip pair
     ex._stream_compact_fns(
         types.SimpleNamespace(source=P.Values((), ())), 8)
-    assert {("stream_compact1",), ("stream_compact2",)} <= set(
-        ex._jit_cache)
+    assert {("stream_compact1", "rows"),
+            ("stream_compact2", "rows")} <= set(ex._jit_cache)
 
 
 @pytest.fixture(scope="module")
