@@ -41,12 +41,14 @@ from presto_tpu.exec.executor import (
     _final_agg_page,
     _final_global_agg,
     _merge_compact_flag,
+    _merge_leading,
     _next_pow2,
     _null_blocks,
     _partial_agg_page,
     _partial_global_agg,
     _probe_join_page,
     _returning_rows,
+    _row_bytes,
     _semi_join_page,
     _topn_merge,
 )
@@ -355,23 +357,33 @@ class DistExecutor(Executor):
             conn = self.catalogs[node.catalog]
             yield from self._scan_staged(node, conn, tuple(node.columns))
             return
-        n, gen_local, make_page, rounds = gen
+        n, gen_split, make_page, _n_rounds, launches = gen
         fn = self._mesh_jit(
             ("d_scan", node.catalog, node.table, tuple(node.columns), n),
-            lambda start_arr: make_page(*gen_local(start_arr)))
-        for start_arr in rounds():
+            lambda start_arr: make_page(*gen_split(start_arr[0])))
+        for start_arr, _rounds in launches():
             yield fn(start_arr)
 
     def _round_generator(self, node: P.TableScan):
         """A sharded scan of an on-device generator as rounds of D
-        splits, one a chip: ``(n, gen_local, make_page, rounds)``: the
-        slots a chip generates a round, the shard-local generator
-        (split start -> columns, valid), the page of its output, and
-        the iterator of the rounds' split starts, sharded over the
-        mesh (it counts the real splits; the tail round is padded).
+        splits, one a chip: ``(n, gen_split, make_page, n_rounds,
+        launches)``: the slots a chip generates a round, the
+        shard-local generator (a split's start -> columns, valid), the
+        page of its output, the scan's rounds, and ``launches(bmax)``,
+        the iterator of ``(split starts, rounds)`` a launch, the
+        starts sharded over the mesh (it counts the real splits; the
+        tail round is padded). A launch of one round (every launch at
+        the default ``bmax`` 1) takes an ``int64[D]``, a chip's start;
+        one of B > 1 rounds an ``int64[D, B]``, a chip's B starts in
+        scan order, the round-robin of the one-round launches (chip d
+        has splits d, d + D, ...), so a row is generated on the chip
+        that generates it a round a launch. Batches are ``bmax``
+        rounds and the tail batch its exact width: no padded round
+        generates anything.
         None for a host-page connector (_scan_staged). Shared by the
-        bare scan (d_scan) and the fused scan round (d_fused), so
-        both see the same rounds, splits and slots."""
+        bare scan (d_scan) and the fused scan chain (d_fused,
+        d_fused_batch), so all see the same rounds, splits and
+        slots."""
         conn = self.catalogs[node.catalog]
         schema = conn.table_schema(node.table)
         names = tuple(node.columns)
@@ -383,8 +395,7 @@ class DistExecutor(Executor):
             return None
         dicts = getattr(conn, "_dicts", {}).get(node.table, {})
 
-        def gen_local(start_arr):
-            start = start_arr[0]
+        def gen_split(start):
             datas, valid = body(start)
             # rounds are padded to D devices; slots past the table are
             # masked out here (the generator itself has no bound)
@@ -402,39 +413,57 @@ class DistExecutor(Executor):
 
         starts = [s.start_row for s in splits]
         spec = NamedSharding(self.mesh, PS("d"))
+        D = self.D
 
-        def rounds():
-            for r in range(0, len(starts), self.D):
-                chunk = starts[r:r + self.D]
+        def launches(bmax: int = 1):
+            for r in range(0, len(starts), D * bmax):
+                chunk = starts[r:r + D * bmax]
                 # launch amortization: a mesh round is one program
                 # covering D splits (counted at the launch point:
                 # programs.FUSED_SCAN_LABELS), the same accounting
                 # the split-batched local scan reports
                 self.splits_scanned += len(chunk)
+                rounds = -(-len(chunk) // D)
                 # pad the tail round; padded starts generate
                 # fully-masked rows
-                chunk = chunk + [total] * (self.D - len(chunk))
-                yield XF.to_device(
-                    # xfercheck: raw-ok - a host list of split starts
-                    np.asarray(chunk, dtype=np.int64),
-                    spec=spec, label="split-starts",
-                )
+                chunk = chunk + [total] * (rounds * D - len(chunk))
+                # xfercheck: raw-ok - a host list of split starts
+                table = np.asarray(chunk, dtype=np.int64)
+                if rounds > 1:
+                    table = np.ascontiguousarray(
+                        table.reshape(rounds, D).T)
+                yield XF.to_device(table, spec=spec,
+                                   label="split-starts"), rounds
 
-        return n, gen_local, make_page, rounds
+        return n, gen_split, make_page, -(-len(starts) // D), launches
 
     def _fused_rounds(self, node: P.PhysicalNode
                       ) -> Optional[Iterator[Page]]:
-        """A SHARDED scan chain as ONE program a scan round (d_fused):
-        where ``node`` tops a chain of Filter / Project / build-free
+        """A SHARDED scan chain as ONE program a launch of scan rounds
+        (d_fused a round, d_fused_batch a batch of them): where
+        ``node`` tops a chain of Filter / Project / build-free
         generated joins over a TableScan of an on-device generator,
         the shard_map body generates the chip's split, builds the page
         and applies the step list the one-chip fused stream applies
         (Executor._chain_steps), so a round pays one launch and one
         pages() boundary instead of one a plan node. The rounds, the
         splits and the page a round are the per-node chain's, slot
-        for slot; the two drivers stay apart (one chip batches splits
-        under vmap / lax.scan, the mesh runs D splits a round under
-        shard_map with psum'd flags) and share the step list.
+        for slot.
+
+        A scan of several rounds is launched a batch of rounds at a
+        time, as one chip's is a batch of splits, and by the rule one
+        chip uses (split_batch_size through _split_batch_max: B x n
+        slots a chip under the row line and the governor's scan share
+        a chip; auto engages on a TPU only; B < 2 is a round a
+        launch). The body runs the one-split body once a split in a
+        SEQUENTIAL loop (lax.map: the loop's step stays the one-split
+        program, where one chip's vmapped [B, n] step costs several
+        times its rows) and emits the stack as ONE page of B x n
+        slots a chip, a chip's splits in scan order, so every
+        per-page program above the chain runs once a batch. The flags
+        are OR'd over the loop and psum'd once. The two drivers stay
+        apart (one chip batches splits under vmap / lax.scan) and
+        share the step list and the batch rule.
 
         When it engages is read from the plan and the connector: None
         (the per-node programs, which stay) where the chain holds an
@@ -455,28 +484,57 @@ class DistExecutor(Executor):
         gen = self._round_generator(scan)
         if gen is None:
             return None
-        n, gen_local, make_page, rounds = gen
+        n, gen_split, make_page, n_rounds, launches = gen
         steps = self._chain_steps(chain)
         # a windowed generated join's multi-match flag is the one
         # collective of the program (see _dist_join_generated)
         n_flags = sum(kind == "joinw" for kind, _fn in steps)
+        out_specs = (PS("d"), (PS(),) * n_flags)
 
-        def body(start_arr):
-            page, flags = _apply_steps(
-                make_page(*gen_local(start_arr)), steps)
-            return page, tuple(
-                jax.lax.psum(f.astype(jnp.int32), "d") > 0
+        def one_split(start):
+            return _apply_steps(make_page(*gen_split(start)), steps)
+
+        def any_chip(flags):
+            return tuple(
+                jax.lax.psum(jnp.any(f).astype(jnp.int32), "d") > 0
                 for f in flags)
 
-        fn = self._mesh_jit(
-            ("d_fused", node, n), body,
-            out_specs=(PS("d"), (PS(),) * n_flags),
-            fenced=n_flags > 0)
+        def body(start_arr):
+            page, flags = one_split(start_arr[0])
+            return page, any_chip(flags)
+
+        def batch_body(starts):
+            # int64[1, B] a chip: its B splits, one a loop step
+            pages, flags = jax.lax.map(one_split, starts[0])
+            return _merge_leading(pages), any_chip(flags)
+
+        def program(rounds):
+            if rounds == 1:
+                return self._mesh_jit(
+                    ("d_fused", node, n), body, out_specs=out_specs,
+                    fenced=n_flags > 0)
+            return self._mesh_jit(
+                ("d_fused_batch", node, n, rounds), batch_body,
+                out_specs=out_specs, fenced=n_flags > 0)
+
+        bmax = 1
+        if n_rounds > 1:
+            # a round's row is D splits' rows, one a chip, and the
+            # mesh's budget D chips' shares: the stack fits a chip's
+            bmax = max(1, self._split_batch_max(
+                n, scanned=False, row_bytes=self.D * max(
+                    _row_bytes(self.output_types(scan)),
+                    _row_bytes(self.output_types(node)))))
+        # full batches, and the tail batch at its exact width
+        fns = {rounds: program(rounds) for rounds in {
+            min(bmax, n_rounds - r) for r in range(0, n_rounds, bmax)}}
 
         def stream():
-            for start_arr in rounds():
-                page, flags = fn(start_arr)
-                self.mesh_fused_rounds += 1
+            for starts, rounds in launches(bmax):
+                page, flags = fns[rounds](starts)
+                self.mesh_fused_rounds += rounds
+                if rounds > 1:
+                    self.mesh_batched_rounds += rounds
                 self._pending_overflow.extend(flags)
                 yield page
 

@@ -55,6 +55,11 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "as one program over the mesh (d_fused: "
         "dist/executor.DistExecutor._fused_rounds); 0 where the chain "
         "fell back to one program a plan node, and on one device"),
+    "mesh_batched_rounds": (
+        "gauge", "the ones among mesh_fused_rounds that ran inside a "
+        "launch of more than one round (d_fused_batch: a sequential "
+        "loop over a chip's splits, sized by split_batch_size's "
+        "rule); 0 where a round is a launch"),
     "row_counts_launched": (
         "gauge", "(plan node, page) row counts this attempt kept for "
         "the query trace or EXPLAIN ANALYZE that rode in the launch "

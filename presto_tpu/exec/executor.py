@@ -518,10 +518,13 @@ class Executor:
         # _launches_by_label feeds the attempt span while tracing;
         # exchange_launches = the calls among them whose program moves
         # rows between chips (family "exchange", over a mesh);
-        # mesh_fused_rounds = the scan rounds a mesh ran as one program
+        # mesh_fused_rounds = the scan rounds a mesh ran as one
+        # program, mesh_batched_rounds = the ones among them that
+        # shared a launch with other rounds
         self.device_launches = 0
         self.exchange_launches = 0
         self.mesh_fused_rounds = 0
+        self.mesh_batched_rounds = 0
         self.dispatch_wall_us = 0
         self.device_wait_us = 0
         self._launches_by_label: Dict[str, int] = {}
@@ -2459,6 +2462,8 @@ class Executor:
                            launches=dict(self._launches_by_label),
                            exchange_launches=self.exchange_launches,
                            mesh_fused_rounds=self.mesh_fused_rounds,
+                           mesh_batched_rounds=(
+                               self.mesh_batched_rounds),
                            row_counts_launched=(
                                self.row_counts_launched),
                            row_counts_eager=self.row_counts_eager,
@@ -2520,6 +2525,7 @@ class Executor:
         self.device_launches = 0
         self.exchange_launches = 0
         self.mesh_fused_rounds = 0
+        self.mesh_batched_rounds = 0
         self.row_counts_launched = 0
         self.row_counts_eager = 0
         self.dispatch_wall_us = 0

@@ -94,6 +94,9 @@ PROGRAM_LABELS: Dict[str, str] = {
     # a scan round's whole chain (generator, generated joins, filter,
     # project) in one shard_map program, as "fused" is on one device
     "d_fused": "scan",
+    # a batch of scan rounds in one program: d_fused's body once a
+    # split in a sequential loop, as "fused_batch" is on one device
+    "d_fused_batch": "scan",
     "d_filter": "filter_project",
     "d_project": "filter_project",
     "d_unnest": "filter_project",
@@ -120,10 +123,11 @@ PROGRAM_LABELS: Dict[str, str] = {
 
 
 # the launches program_launches counts: a fused scan step on one
-# device, a scan round (D splits, one a chip) over a mesh
+# device, a scan round (D splits, one a chip) or a batch of rounds
+# over a mesh
 FUSED_SCAN_LABELS = frozenset(("fused", "fused_batch", "xq_batch",
                                "stored", "stored_batch",
-                               "d_scan", "d_fused"))
+                               "d_scan", "d_fused", "d_fused_batch"))
 
 
 def label_of(key) -> str:

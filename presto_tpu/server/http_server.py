@@ -508,7 +508,7 @@ class QueryManager:
         "program_launches", "splits_scanned", "cross_query_batches",
         "cross_query_batched_queries", "batch_gather_wait_ms",
         "device_launches", "exchange_launches", "mesh_fused_rounds",
-        "row_counts_launched", "row_counts_eager",
+        "mesh_batched_rounds", "row_counts_launched", "row_counts_eager",
         "dispatch_wall_us", "device_wait_us",
         "resident_splits_scanned", "resident_bytes_scanned",
     )

@@ -229,7 +229,9 @@ SYSTEM_SESSION_PROPERTIES: Dict[str, PropertyMetadata] = {
             "indices with the partial-aggregation state as carry for "
             "scan->filter->project->partial-agg chains; a vmapped "
             "[B, page] stacked batch emitted as one page for "
-            "page-emitting chains). auto = on when running on TPU "
+            "page-emitting chains; over a mesh a launch of up to this "
+            "many scan rounds, a sequential loop over a chip's "
+            "splits emitted as one page). auto = on when running on TPU "
             "with the default max batch (the win is the per-launch "
             "launch overhead, which CPU doesn't pay — the "
             "pallas_join_enabled policy); false = per-split launches. "

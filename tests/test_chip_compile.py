@@ -369,13 +369,17 @@ def test_mesh_q3_compaction_compiles_shard_local(topo, tpu_branches):
     assert rows.spec == PS("d")
 
 
+@pytest.mark.parametrize("rounds", [1, 11], ids=["round", "batch"])
 def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
-        topo, tpu_branches):
+        rounds, topo, tpu_branches):
     """Q3 at SF1 over four chips (ISSUE 32): a scan round's whole chain
     (the generator of 262,143 lineitem slots a chip, both generated
     joins, filter, project) is ONE program, d_fused, with no
     collective in it (no windowed join: no flag), a sharded page out
-    and, sharded like it, each chip's count of the page's rows."""
+    and, sharded like it, each chip's count of the page's rows. On a
+    TPU the scan's 11 rounds are one launch (ISSUE 40): d_fused_batch
+    at the exact width 11, the rule's 16 being the most, a sequential
+    loop over a chip's starts whose page is 11 rounds' slots a chip."""
     from presto_tpu.connectors.tpch import TpchConnector
     from presto_tpu.runner import LocalRunner
     from tests.tpch_queries import QUERIES
@@ -386,25 +390,37 @@ def test_mesh_q3_scan_round_compiles_as_one_shard_local_program(
     ex = runner.executor
     ex.fault_rows = SH.SAFE_BUFFER_ROWS
     ex.device_memory_budget = (16 << 30) * 7 // 8
+    if rounds == 1:
+        ex.split_batch = 0  # a round a launch: the CPU's auto
     top = runner.plan(QUERIES[3])
     while ex._fused_rounds(top) is None:
         (top,) = top.children()[:1]
-    ((key, program),) = [(k, p) for k, p in ex._jit_cache.items()
-                         if k[0] == "d_fused"]
-    assert key[1] is top and key[2] == (1 << 18) - 1
+    ((key, program),) = ex._jit_cache.items()
+    n = (1 << 18) - 1
+    assert key[:3] == ("d_fused" if rounds == 1 else "d_fused_batch",
+                       top, n)
+    assert ex._split_batch_max(n, scanned=False) == (
+        0 if rounds == 1 else 16)
+    assert rounds == 1 or key[3] == rounds
     assert ex.generated_joins_used == 2
+    d = mesh.devices.size
     compiled = program.jitted.lower(_spec(
-        (mesh.devices.size,), jnp.int64,
+        (d,) if rounds == 1 else (d, rounds), jnp.int64,
         NamedSharding(mesh, PS("d")))).compile()
     text = compiled.as_text()
     for collective in ("all-reduce", "all-to-all", "all-gather"):
         assert collective not in text, collective
+    assert (" while(" in text) == (rounds > 1)
     # the page's row count rides in the launch (ISSUE 37), a chip's
     # own: still no collective (above)
     (out_page, flags), rows = compiled.output_shardings
     assert flags == () and rows.spec == PS("d")
     assert all(s.spec == PS("d") for s in jax.tree.leaves(out_page))
     assert len(out_page.blocks) == len(ex.output_types(top))
+    (out_shapes, _flags), _rows = jax.eval_shape(
+        program.jitted, _spec((d,) if rounds == 1 else (d, rounds),
+                              jnp.int64, NamedSharding(mesh, PS("d"))))
+    assert out_shapes.valid.shape == (d * rounds * n,)
 
 
 def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):

@@ -140,6 +140,73 @@ def _never(self):
     raise AssertionError("page.num_rows() inside a mesh attempt")
 
 
+@pytest.fixture(scope="module")
+def mesh4_rounds():
+    """The same over seven scan rounds of 2,048 slots a chip, so that
+    a forced split_batch_size launches them 2 + 2 + 2 + 1 or 4 + 3."""
+    conn = TpchConnector(SF)
+    runner = LocalRunner(
+        {"tpch": conn, "tpch_sf1": conn},
+        default_catalog=CELL.every[0].catalog, page_rows=1 << 11,
+        mesh=make_mesh(4),
+        dist_options=dict(broadcast_rows=64, gather_capacity=16))
+    runner.session.set("query_trace_enabled", True)
+    return runner
+
+
+def _chain_and_result_rows(operators):
+    """(node, rows) of the nodes whose rows do not depend on how many
+    pages the scan came in: a partial aggregation emits its groups a
+    page, and the exchange above it carries them."""
+    return sorted((name, rows) for name, rows, _pages in operators
+                  if name not in ("Aggregation", "Exchange"))
+
+
+@pytest.mark.parametrize("size,launches", [(2, 4), (4, 2)])
+@pytest.mark.parametrize("template", ["q3", "q5"])
+def test_a_batchs_page_brings_the_sum_of_its_splits_counts(
+        template, size, launches, mesh4_rounds, monkeypatch):
+    """With a batch of rounds a launch (ISSUE 40) the batch's page
+    brings ONE count from its launch, the sum of its splits' rows, a
+    chip's own a chip; nothing is counted eagerly, and every plan
+    node's rows are the round-a-launch run's."""
+    runner = mesh4_rounds
+    sql = STATEMENTS[template].sql
+    want = runner.execute(sql).rows
+    (plain,) = _attempts(runner)
+    assert plain["launches"]["d_fused"] == 7
+    rows_by_node = _chain_and_result_rows(_operators(runner))
+    seen = _recording_pages(monkeypatch)
+    runner.session.set("split_batch_size", str(size))
+    try:
+        assert runner.execute(sql).rows == want  # traces the programs
+        (attempt,) = _attempts(runner)
+        by_node = _eager(seen)
+        batches = [pages for label, pages in seen.values()
+                   if len(pages) == launches]
+        with monkeypatch.context() as m:
+            m.setattr(Page, "num_rows", _never)
+            assert runner.execute(sql).rows == want
+            (again,) = _attempts(runner)
+    finally:
+        runner.session.set("split_batch_size", "auto")
+    assert attempt["mesh_batched_rounds"] == 7 - (size == 2)
+    assert attempt["launches"]["d_fused_batch"] == launches - (size == 2)
+    assert _operators(runner) == by_node
+    assert _chain_and_result_rows(by_node) == rows_by_node
+    boundaries = sum(pages for _label, _rows, pages in by_node)
+    for where in (attempt, again):
+        assert where["row_counts_eager"] == 0
+        assert where["row_counts_launched"] == boundaries
+    assert boundaries < plain["row_counts_launched"]
+    # the chain's pages: a count a chip, in the launch's own output
+    assert batches, sorted(seen.values())
+    for page in batches[0]:
+        assert page.rows.shape == (4,)
+        per_chip = np.asarray(page.valid).reshape(4, -1).sum(axis=1)
+        assert np.asarray(page.rows).tolist() == per_chip.tolist()
+
+
 def test_a_passed_through_page_keeps_its_count(mesh4):
     """A gather over a REPLICATED source and Output hand the page on
     as it is, so the count it brought serves each boundary; a page

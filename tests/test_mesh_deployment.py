@@ -32,21 +32,29 @@ def served(tmp_path_factory):
 
 
 # with the deployment's own exchange decisions (at SF0.01 Q3's partial
-# states are gathered), and with every group-by repartitioned, which is
+# states are gathered), with every group-by repartitioned, which is
 # what SF1 takes on the chip: all_to_all, shard-local final aggregation,
-# top-N on every chip below the gather
-@pytest.mark.parametrize("gather_capacity", [None, 16],
-                         ids=["as_deployed", "repartitioned"])
+# top-N on every chip below the gather, and with that and the scan's
+# rounds (pages of 4,096 slots, so that there are several) launched a
+# batch at a time, which is what a TPU takes under split_batch_size's
+# auto (ISSUE 40)
+SESSIONS = {
+    "as_deployed": {},
+    "repartitioned": {"agg_gather_capacity": "16"},
+    "batched": {"agg_gather_capacity": "16", "page_rows": "4096",
+                "split_batch_size": "4"},
+}
+
+
+@pytest.mark.parametrize("session", sorted(SESSIONS))
 @pytest.mark.parametrize("key", sorted(STATEMENTS))
 def test_mesh_statement_equals_the_plain_reference(
-        key, gather_capacity, served):
+        key, session, served):
     srv, want = served
     st = STATEMENTS[key]
     client = srv.client(st.catalog)
     client.session_properties["query_trace_enabled"] = "true"
-    if gather_capacity is not None:
-        client.session_properties["agg_gather_capacity"] = str(
-            gather_capacity)
+    client.session_properties.update(SESSIONS[session])
     res = client.execute(st.sql)
     got = reference.engine_encoding(res.columns, res.rows)
     assert want[key], "the reference has no row: nothing is compared"
@@ -54,17 +62,26 @@ def test_mesh_statement_equals_the_plain_reference(
     info = srv.query_info(res.query_id)
     attempts = [sp for sp in _spans(info) if sp["kind"] == "attempt"]
     launches = attempts[-1]["attrs"]["launches"]
-    # Q3's and Q5's scan chains are one program a round
-    assert launches.get("d_fused", 0) >= 1, launches
+    # Q3's and Q5's scan chains are one program a round, or a batch
+    # of rounds
+    rounds = attempts[-1]["attrs"]["mesh_fused_rounds"]
+    scans = launches.get("d_fused", 0) + launches.get("d_fused_batch", 0)
+    assert scans >= 1, launches
     assert "d_scan" not in launches and "d_genjoin" not in launches
-    assert attempts[-1]["attrs"]["mesh_fused_rounds"] == \
-        launches["d_fused"]
-    if gather_capacity is not None:
+    metrics = srv.metrics()
+    if session == "batched":
+        assert launches["d_fused_batch"] >= 2 and rounds > 4, launches
+        assert rounds - 1 <= attempts[-1]["attrs"][
+            "mesh_batched_rounds"] == metrics["mesh_batched_rounds"]
+        assert metrics["program_launches"] == scans == -(-rounds // 4)
+    else:
+        assert rounds == launches["d_fused"]
+        assert attempts[-1]["attrs"]["mesh_batched_rounds"] == 0
+    if session != "as_deployed":
         assert launches.get("d_repartition", 0) >= 1, launches
         assert attempts[-1]["attrs"]["exchange_launches"] >= 2
         if st.template == "q3":
             assert launches.get("d_topn_local", 0) >= 1, launches
-    metrics = srv.metrics()
     assert metrics["exchange_launches"] >= 1
     assert metrics["device_launches"] > metrics["exchange_launches"]
     # every row count the query trace kept rode in a launch (ISSUE 37):
@@ -74,7 +91,7 @@ def test_mesh_statement_equals_the_plain_reference(
     assert attempts[-1]["attrs"]["row_counts_eager"] == 0 \
         == metrics["row_counts_eager"]
     assert attempts[-1]["attrs"]["row_counts_launched"] == kept \
-        == metrics["row_counts_launched"] > launches["d_fused"]
+        == metrics["row_counts_launched"] > scans
 
 
 def _spans(info):
@@ -539,10 +556,11 @@ def test_a_chain_ends_below_an_exchange(mesh4, single):
     assert sorted(rows) == want
 
 
-def test_a_host_page_connector_keeps_the_per_node_programs():
+@pytest.mark.parametrize("split_batch", ["auto", "4"])
+def test_a_host_page_connector_keeps_the_per_node_programs(split_batch):
     """No generator on the device (gen_body is None): the scan stages
     host pages (_scan_staged) and the chain above it runs one program
-    a plan node."""
+    a plan node, whatever the batch rule says."""
     from presto_tpu import types as T
     from presto_tpu.connectors.memory import MemoryConnector
 
@@ -552,12 +570,14 @@ def test_a_host_page_connector_keeps_the_per_node_programs():
     runner = LocalRunner({"memory": mem}, default_catalog="memory",
                          page_rows=128, mesh=make_mesh(4))
     runner.session.set("query_trace_enabled", True)
+    runner.session.set("split_batch_size", split_batch)
     got = runner.execute("select a + b from t where b < 3").rows
     assert sorted(got) == sorted(
         (i + i % 7,) for i in range(1000) if i % 7 < 3)
     attempt = _last_attempt(runner)
-    assert attempt["mesh_fused_rounds"] == 0
-    assert "d_fused" not in attempt["launches"]
+    assert attempt["mesh_fused_rounds"] == 0 \
+        == attempt["mesh_batched_rounds"]
+    assert not {"d_fused", "d_fused_batch"} & set(attempt["launches"])
     assert {"d_filter", "d_project"} & set(attempt["launches"])
 
 
@@ -582,3 +602,331 @@ def test_a_live_cache_point_in_the_chain_stays_a_boundary(mesh4):
         assert ex._fused_rounds(top) is not None
     finally:
         ex._cache_points, ex._cache_inflight = {}, set()
+
+
+# ------------------------------------- a batch of scan rounds a launch
+# (ISSUE 40) A scan of several rounds is launched a batch of rounds at
+# a time (d_fused_batch: the one-split body once a split in a
+# sequential loop), sized by the rule one chip uses for its splits
+# (split_batch_size: auto engages on a TPU only, an integer forces it
+# here). Seven rounds of 2,048 slots a chip: batches of 2 + 2 + 2 and
+# a lone tail round (d_fused), of 4 + 3, and one of 7.
+BATCH_SLOTS = 1 << 11
+BATCHES = {2: [2, 2, 2, 1], 4: [4, 3], 16: [7]}
+
+
+@pytest.fixture(scope="module")
+def batch_mesh(conn):
+    runner = LocalRunner(
+        {"tpch": conn, "tpch_sf1": conn},
+        default_catalog=CELL.every[0].catalog, page_rows=BATCH_SLOTS,
+        mesh=make_mesh(4), dist_options=dict(gather_capacity=16))
+    runner.session.set("query_trace_enabled", True)
+    return runner
+
+
+@pytest.fixture(scope="module")
+def batch_single(conn):
+    return LocalRunner({"tpch": conn, "tpch_sf1": conn},
+                       default_catalog=CELL.every[0].catalog,
+                       page_rows=BATCH_SLOTS)
+
+
+def _with_split_batch(runner, size, run):
+    runner.session.set("split_batch_size", str(size))
+    try:
+        return run()
+    finally:
+        runner.session.set("split_batch_size", "auto")
+
+
+def _chip_major(pages, chips=4):
+    """The rounds' pages as the one page a batch of them is: a chip's
+    shard of each round, in scan order, then the next chip's."""
+    import jax
+    import numpy as np
+
+    def stack(*leaves):
+        shards = [np.asarray(x).reshape(chips, -1) for x in leaves]
+        return np.concatenate(shards, axis=1).reshape(-1)
+
+    return jax.tree.map(stack, *pages)
+
+
+@pytest.mark.parametrize("size", sorted(BATCHES))
+@pytest.mark.parametrize("template", ["q3", "q5"])
+def test_batched_rounds_yield_the_rounds_pages_and_rows(
+        template, size, batch_mesh, batch_single, monkeypatch):
+    """Same splits on the same chips, same slots: a batch's page is
+    its rounds' pages laid side by side a chip, the statement's rows
+    are the round-a-launch run's and one device's, and the per-page
+    programs above the chain run once a batch."""
+    key = min(k for k, st in STATEMENTS.items()
+              if st.template == template)
+    sql = STATEMENTS[key].sql
+    want, ((top, rounds),) = _fused_chains(batch_mesh, sql, monkeypatch)
+    plain = _last_attempt(batch_mesh)
+    n_rounds = sum(BATCHES[size])
+    assert plain["launches"]["d_fused"] == n_rounds == len(rounds)
+    assert plain["mesh_batched_rounds"] == 0
+    assert "d_fused_batch" not in plain["launches"]
+    assert sorted(want) == sorted(batch_single.execute(sql).rows)
+
+    got, ((top_b, batches),) = _with_split_batch(
+        batch_mesh, size,
+        lambda: _fused_chains(batch_mesh, sql, monkeypatch))
+    assert got == want
+    only = _last_attempt(batch_mesh)
+    launches = only["launches"]
+    widths = BATCHES[size]
+    many = [w for w in widths if w > 1]
+    assert launches["d_fused_batch"] == len(many)
+    assert launches.get("d_fused", 0) == len(widths) - len(many)
+    assert batch_mesh.executor.program_launches == len(widths)
+    assert only["mesh_fused_rounds"] == n_rounds
+    assert only["mesh_batched_rounds"] == sum(many)
+    assert only["row_counts_eager"] == 0
+    assert not {"d_scan", "d_genjoin", "d_filter"} & set(launches)
+    # once a batch, not once a round
+    if template == "q5":
+        assert launches["d_agg_partial"] == len(widths)
+    else:
+        assert launches["d_stream_compact1"] \
+            + launches.get("d_stream_compact2", 0) <= 2 * len(widths)
+        assert launches["d_agg_partial"] == 1
+    # slot for slot, the masked ones and the tail round's padded
+    # splits too (they yield no row)
+    assert [p.capacity for p in batches] == [
+        w * rounds[0].capacity for w in widths]
+    at = 0
+    for page, width in zip(batches, widths):
+        whole = _chip_major(rounds[at:at + width]) if width > 1 \
+            else rounds[at]
+        _assert_pages_equal([page, page], [whole, whole])
+        at += width
+    assert sum(int(p.num_rows()) for p in batches) == sum(
+        int(p.num_rows()) for p in rounds)
+
+
+def test_batching_is_sized_by_one_chips_rule(batch_mesh):
+    """B x n slots a chip stay under the row line and the governor's
+    scan share a chip, by Executor._split_batch_max with a round's row
+    D splits wide; off on a CPU under auto; a scan of one round is a
+    round."""
+    from presto_tpu.exec import shapes as SH
+
+    ex = batch_mesh.executor
+    plan = batch_mesh.plan(STATEMENTS[min(STATEMENTS)].sql)
+    top = plan
+    while ex._fused_rounds(top) is None:
+        (top,) = top.children()[:1]
+
+    def labels(split_batch):
+        ex._jit_cache.clear()
+        ex.split_batch = split_batch
+        try:
+            ex._fused_rounds(top)
+        finally:
+            ex.split_batch = "auto"
+        return sorted((k[0], k[3]) if k[0] == "d_fused_batch"
+                      else (k[0],) for k in ex._jit_cache)
+
+    assert labels("auto") == [("d_fused",)]
+    assert labels(0) == [("d_fused",)]
+    assert labels(1) == [("d_fused",)]
+    assert labels(4) == [("d_fused_batch", 3), ("d_fused_batch", 4)]
+    assert labels(64) == [("d_fused_batch", 7)]
+    rows_max = SH.SPLIT_BATCH_ROWS_MAX
+    try:
+        # the row line is a chip's: 5 rounds of 2,047 slots at most
+        SH.SPLIT_BATCH_ROWS_MAX = 5 * BATCH_SLOTS
+        assert labels(64) == [("d_fused_batch", 3),
+                              ("d_fused_batch", 4)]
+    finally:
+        SH.SPLIT_BATCH_ROWS_MAX = rows_max
+    # the governor's scan share of ONE chip's budget (the mesh's is D
+    # chips'): room for two rounds' rows a chip, not eight
+    budget = ex.device_memory_budget
+    from presto_tpu.exec import membudget as MB
+    from presto_tpu.exec.executor import _row_bytes
+
+    row = max(_row_bytes(ex.output_types(top)), _row_bytes(
+        ex.output_types(ex._scan_chain(top, through_joins=True)[0])))
+    try:
+        ex.device_memory_budget = (
+            2 * (BATCH_SLOTS - 1) * row * MB.SCAN_SHARE_DIV + 1)
+        ex._budget_resolved = None
+        assert labels(64) == [("d_fused",), ("d_fused_batch", 2)]
+    finally:
+        ex.device_memory_budget = budget
+        ex._budget_resolved = None
+    ex._jit_cache.clear()
+
+
+def test_a_scan_of_one_round_is_not_a_batch(conn):
+    runner = LocalRunner({"tpch": conn}, page_rows=1 << 16,
+                         mesh=make_mesh(4))
+    runner.session.set("query_trace_enabled", True)
+    runner.session.set("split_batch_size", "16")
+    sql = "select l_orderkey + 1 from lineitem where l_quantity < 3"
+    single = LocalRunner({"tpch": conn}, page_rows=1 << 16)
+    assert sorted(runner.execute(sql).rows) == sorted(
+        single.execute(sql).rows)
+    only = _last_attempt(runner)
+    assert only["launches"]["d_fused"] == 1 == only["mesh_fused_rounds"]
+    assert only["mesh_batched_rounds"] == 0
+
+
+def test_no_batched_round_on_one_device(batch_single):
+    """One chip batches its splits in its own driver (fused_batch);
+    the mesh's counter stays 0 there."""
+    sql = STATEMENTS[min(STATEMENTS)].sql
+    batch_single.session.set("query_trace_enabled", True)
+    try:
+        _with_split_batch(batch_single, 4,
+                          lambda: batch_single.execute(sql))
+        only = _last_attempt(batch_single)
+    finally:
+        batch_single.session.set("query_trace_enabled", False)
+    assert only["launches"]["fused_batch"] >= 1
+    assert only["mesh_batched_rounds"] == 0 == only["mesh_fused_rounds"]
+
+
+def test_no_batched_round_above_an_exchange(mesh4):
+    """A chain that holds an Exchange keeps a program a plan node
+    whatever the batch rule says; the chain below it batches."""
+    from presto_tpu.exec import plan as P
+    from presto_tpu.expr.ir import InputRef
+    from presto_tpu import types as T
+
+    scan = P.TableScan("tpch", "lineitem", ("l_orderkey", "l_quantity"))
+    swap = (InputRef(1, T.DecimalType(12, 2)), InputRef(0, T.BIGINT))
+    over = P.Project(
+        P.Exchange(scan, kind="repartition", keys=(0,)), swap)
+    ex = mesh4.executor
+    ex.split_batch = 4  # _run_plan drives the executor, not the session
+    try:
+        rows, attempt = _run_plan(
+            mesh4, P.Exchange(over, kind="gather"), ("q", "k"))
+        assert attempt["mesh_batched_rounds"] == 0 \
+            == attempt["mesh_fused_rounds"]
+        assert attempt["launches"]["d_scan"] >= 2
+        under = P.Project(P.Exchange(
+            P.Project(scan, (swap[1], swap[0])), kind="repartition",
+            keys=(0,)), swap)
+        rows_under, attempt = _run_plan(
+            mesh4, P.Exchange(under, kind="gather"), ("q", "k"))
+    finally:
+        ex.split_batch = "auto"
+    assert sorted(rows_under) == sorted(rows)
+    assert attempt["mesh_batched_rounds"] >= 2
+    assert attempt["launches"]["d_fused_batch"] \
+        == attempt["launches"]["d_repartition"] >= 1
+
+
+def test_no_batched_round_through_a_live_cache_point(batch_mesh):
+    from presto_tpu.exec import plan as P
+
+    plan = batch_mesh.plan(
+        "select l_orderkey, l_quantity + 1 from lineitem "
+        "where l_quantity < 10")
+    ex = batch_mesh.executor
+    top = plan
+    while ex._fused_rounds(top) is None:
+        (top,) = top.children()
+    inner = top.children()[0]
+    assert isinstance(inner, (P.Filter, P.Project, P.TableScan))
+    ex.split_batch = 4
+    try:
+        ex._begin_attempt()
+        pages = list(ex.pages(top))
+        assert ex.mesh_batched_rounds == ex.mesh_fused_rounds > len(pages)
+        ex._cache_points = {id(inner): ("entry",)}
+        assert ex._fused_rounds(top) is None
+    finally:
+        ex._cache_points, ex._cache_inflight = {}, set()
+        ex.split_batch = "auto"
+
+
+def test_overflow_above_a_batch_reenters_boosted(
+        batch_single, batch_mesh):
+    """The compaction of a batch's page drops rows past a chip's share
+    of the buffer (2,048 slots of its 8,192 against ≈ 7,500 joined
+    rows a chip): its psum'd flag sends the statement to the boosted
+    attempt, which answers exactly."""
+    sql = ("select l_suppkey, l_linenumber, count(*), sum(l_quantity) "
+           "from lineitem join orders on l_orderkey = o_orderkey "
+           "group by l_suppkey, l_linenumber")
+    want = sorted(batch_single.execute(sql).rows)
+    got, attempts = _with_split_batch(
+        batch_mesh, 4, lambda: _with_optimistic_rows(
+            batch_mesh, ROUND_SLOTS, sql))
+    assert sorted(got) == want
+    assert [a["outcome"] for a in attempts] == ["overflow", "ok"]
+    assert attempts[-1]["mesh_batched_rounds"] \
+        == attempts[-1]["mesh_fused_rounds"] >= 4
+    assert attempts[-1]["launches"]["d_fused_batch"] >= 2
+
+
+def test_a_flag_raised_in_a_batchs_second_split_reenters_boosted(
+        tpcds_mesh, monkeypatch):
+    """A windowed generated join's multi-match flag is OR'd over the
+    loop's splits and psum'd once: raised for one row of a chip's
+    SECOND split of the first batch alone, it still sends the
+    statement to the boosted attempt (the general join), which answers
+    what the unflagged statement answers."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from presto_tpu.exec.executor import Executor
+
+    sql = ("select ss_item_sk, ss_ticket_number, ss_quantity, "
+           "sr_return_quantity from store_sales join store_returns "
+           "on ss_ticket_number = sr_ticket_number "
+           "and ss_item_sk = sr_item_sk where ss_quantity > 10")
+    want = sorted(tpcds_mesh.execute(sql).rows)
+    conn = tpcds_mesh.executor.catalogs["tpcds"]
+    splits = conn.splits("store_sales", target_rows=1 << 12)
+    assert len(splits) > 8  # chip 1's second split: index 4 + 1
+    page = conn.page_for_split(
+        splits[5], ("ss_ticket_number", "ss_item_sk", "ss_quantity"))
+    ticket, item, _quantity = next(
+        row for row, ok in zip(zip(*(
+            np.asarray(page.block(c).data).tolist() for c in range(3))),
+            np.asarray(page.valid).tolist()) if ok and row[2] > 10)
+    kernel = Executor.generated_join_kernel
+
+    def flagging(node, info):
+        kern, windowed = kernel(node, info)
+        if not windowed:
+            return kern, windowed
+        names = list(_scan_columns(node))
+        t_ch = names.index("ss_ticket_number")
+        i_ch = names.index("ss_item_sk")
+
+        def flagged(pg):
+            out, multi = kern(pg)
+            return out, multi | jnp.any(
+                pg.valid & (pg.block(t_ch).data == ticket)
+                & (pg.block(i_ch).data == item))
+        return flagged, windowed
+
+    monkeypatch.setattr(Executor, "generated_join_kernel",
+                        staticmethod(flagging))
+    tpcds_mesh.executor._jit_cache.clear()
+    got = _with_split_batch(
+        tpcds_mesh, 4, lambda: tpcds_mesh.execute(sql).rows)
+    attempts = _attempts(tpcds_mesh)
+    tpcds_mesh.executor._jit_cache.clear()
+    assert sorted(got) == want
+    assert [a["outcome"] for a in attempts] == ["overflow", "ok"]
+    assert attempts[-1]["boost"] > 1
+
+
+def _scan_columns(join):
+    from presto_tpu.exec import plan as P
+
+    node = join.left
+    while not isinstance(node, P.TableScan):
+        (node,) = node.children()[:1]
+    return node.columns

@@ -139,6 +139,7 @@ def test_family_of_reads_a_trace_program_name():
             "exchange", label
     assert PG.family_of("jit_d_scan(1)") == "scan"
     assert PG.family_of("jit_d_fused(1)") == "scan"
+    assert PG.family_of("jit_d_fused_batch(1)") == "scan"
     assert PG.family_of("jit_d_agg_final(1)") == "agg"
     assert PG.family_of("jit_d_topn_local(1)") == "sort_topn"
     assert PG.family_of("jit_d_genjoin(1)") == "join"
@@ -538,14 +539,14 @@ def test_exchange_launches_count_on_the_calling_executor():
     # a mesh's scan round is the fused-scan launch of its statement,
     # counted where every launch is, fused chain or bare scan
     assert caller.program_launches == 0
-    for label in ("d_fused", "d_scan"):
+    for label in ("d_fused", "d_scan", "d_fused_batch"):
         caller._mesh_jit((label, "t"), lambda x: x + 1)(jnp.arange(8))
-    assert (caller.device_launches, caller.program_launches) == (4, 2)
-    caller.mesh_fused_rounds = 3
+    assert (caller.device_launches, caller.program_launches) == (5, 3)
+    caller.mesh_fused_rounds = caller.mesh_batched_rounds = 3
     caller._begin_attempt()
     assert (caller.device_launches, caller.exchange_launches,
-            caller.program_launches, caller.mesh_fused_rounds) == (
-        0, 0, 0, 0)
+            caller.program_launches, caller.mesh_fused_rounds,
+            caller.mesh_batched_rounds) == (0, 0, 0, 0, 0)
 
 
 def test_metrics_expose_exchange_launches(mesh_runner):
@@ -561,3 +562,35 @@ def test_metrics_expose_exchange_launches(mesh_runner):
     assert scraped["mesh_fused_rounds"] == ex.mesh_fused_rounds >= 1
     assert "mesh_fused_rounds" in QueryManager._EXEC_TOTAL_SUMS
     assert QUERY_COUNTERS["mesh_fused_rounds"][0] == "gauge"
+    assert scraped["mesh_batched_rounds"] == 0  # auto: off on a CPU
+
+
+@pytest.mark.parametrize("size,batches,batched", [
+    (2, 2, 4), (16, 1, 4), ("false", 4, 0)])
+def test_metrics_and_the_attempt_span_expose_batched_rounds(
+        size, batches, batched, mesh_runner):
+    """Q3's four scan rounds as two launches and as one (ISSUE 40):
+    program_launches counts a batch once, mesh_fused_rounds its rounds, and
+    mesh_batched_rounds says they shared a launch: on /metrics, on the
+    attempt span, summed over per-query executors like its twin."""
+    from presto_tpu.server.http_server import QueryManager
+
+    mesh_runner.session.set("split_batch_size", str(size))
+    try:
+        mesh_runner.execute(QUERIES[3])
+    finally:
+        mesh_runner.session.set("split_batch_size", "auto")
+    ex = mesh_runner.executor
+    (attempt,) = [sp for sp in mesh_runner.last_trace.spans()
+                  if sp.kind == "attempt"]
+    scraped = _scraped(mesh_runner)
+    assert scraped["mesh_batched_rounds"] == ex.mesh_batched_rounds \
+        == attempt.attrs["mesh_batched_rounds"] == batched
+    assert scraped["mesh_fused_rounds"] == ex.mesh_fused_rounds == 4
+    assert scraped["program_launches"] == ex.program_launches == batches
+    by_label = attempt.attrs["launches"]
+    assert by_label.get("d_fused_batch", 0) == (batches if batched else 0)
+    assert by_label.get("d_fused", 0) == (0 if batched else 4)
+    assert set(by_label) <= set(PG.PROGRAM_LABELS)
+    assert "mesh_batched_rounds" in QueryManager._EXEC_TOTAL_SUMS
+    assert QUERY_COUNTERS["mesh_batched_rounds"][0] == "gauge"

@@ -159,6 +159,7 @@ def record(cell_name: str, sids: List[str], out_dir: str,
                 k: v for k, v in served.metrics().items()
                 if k in ("device_launches", "program_launches",
                          "exchange_launches", "mesh_fused_rounds",
+                         "mesh_batched_rounds",
                          "row_counts_launched", "row_counts_eager",
                          "dispatch_wall_us", "device_wait_us",
                          "spill_partitions_used",
