@@ -372,14 +372,13 @@ class DcnRunner:
         plane (ISSUE 20 satellite): a probe against a dying holder
         must not burn wall clock the query doesn't have. Returns the
         probe timeout — capped at a fraction of the remaining
-        query_max_run_time — or None (counted) when the deadline
-        can't afford one; the caller falls back to normal dispatch."""
+        query_max_run_time — or None when the deadline can't afford
+        one; the caller falls back to normal dispatch."""
         deadline = ex.query_deadline
         if deadline is None:
             return 5.0
         remaining = deadline - time.monotonic()
         if remaining < 2.0:
-            ex.probe_deadline_skips += 1
             return None
         return min(5.0, 0.25 * remaining)
 

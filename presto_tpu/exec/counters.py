@@ -236,11 +236,6 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "torn appends, partial compaction) or barrier writes that "
         "failed to serialize — recovery degrades to the re-run rung, "
         "never a crash, never stale state served"),
-    "probe_deadline_skips": (
-        "counter", "remote-cache probes skipped because the query's "
-        "remaining query_max_run_time could not afford the probe "
-        "wall (deadline-aware retry budget; the task dispatched "
-        "normally instead)"),
     "cache_remote_hits": (
         "counter", "leaf tasks short-circuited by a FLEET member's "
         "fragment cache: the coordinator's pre-dispatch probe "

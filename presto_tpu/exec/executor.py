@@ -191,7 +191,7 @@ class _FoldBuffer:
         pages = ([self.acc] if self.acc is not None else []) + self.buf
         if not pages:
             return None
-        merged = concat_all(pages) if len(pages) > 1 else pages[0]
+        merged = _concat_states(pages)
         self.ex._account_page(merged)
         return merged
 
@@ -206,6 +206,17 @@ class _FoldBuffer:
     def final_merged(self):
         """All remaining state as one page (None if nothing was added)."""
         return self._merged()
+
+
+def _concat_states(pages):
+    """Partial-state pages as one page for the merge or final step: a
+    concatenate a column, dispatched eagerly from the driver thread
+    (no program of the registry), so an ``eager`` span: the first of
+    them is where the host meets a device that is behind."""
+    if len(pages) == 1:
+        return pages[0]
+    with XF.eager("concat-states"):
+        return concat_all(pages)
 
 
 class AggSizing(NamedTuple):
@@ -626,14 +637,12 @@ class Executor:
         # Coordinator HA (ISSUE 20, dist/checkpoint.py), lifetime-
         # cumulative on the coordinator's executor: journal records
         # published, queries recovered across a restart, dead
-        # placements re-dispatched during re-attach, checkpoint
-        # records dropped loudly, and remote-cache probes skipped by
-        # the deadline-aware retry budget.
+        # placements re-dispatched during re-attach, and checkpoint
+        # records dropped loudly.
         self.checkpoints_written = 0
         self.coordinator_reattaches = 0
         self.reattach_redispatches = 0
         self.checkpoint_drops = 0
-        self.probe_deadline_skips = 0
         # Stage-DAG scheduling (ISSUE 7, dist/scheduler.py): the
         # general fragment-DAG coordinator maintains these on ITS
         # executor, lifetime-cumulative like the task-retry counters.
@@ -1159,18 +1168,28 @@ class Executor:
         if self.trace is not None:
             by = self._launches_by_label
             by[prog.label] = by.get(prog.label, 0) + 1
+            self.span_ending_now("launch", prog.label, wall_ns / 1e9)
+
+    def span_ending_now(self, kind: str, name: str, wall_s: float,
+                        **attrs) -> None:
+        """A span of the open attempt that ends at this instant and
+        lasted ``wall_s``: how the launch point, exec/xfer.py's choke
+        points and the resident store put an interval they timed
+        themselves (the reading their counter sums) on the query
+        trace. Callers guard on ``self.trace is not None``."""
+        tr = self.trace
+        t1 = tr.now()
+        tr.complete(kind, name, t1 - wall_s, t1,
+                    parent=self._attempt_span, **attrs)
+        self.trace_spans += 1
 
     def count_resident_load(self, table: str, wall_s: float,
                             **attrs) -> None:
         """THE sink connectors/cached.py records a table's load on: a
         span of the attempt whose scan touched the table first (the
         tallies are the connector's own)."""
-        tr = self.trace
-        if tr is not None:
-            t1 = tr.now()
-            tr.complete("resident_load", table, t1 - wall_s, t1,
-                        parent=self._attempt_span, **attrs)
-            self.trace_spans += 1
+        if self.trace is not None:
+            self.span_ending_now("resident_load", table, wall_s, **attrs)
 
     def count_device_wait(self, wall_s: float) -> None:
         """THE sink exec/xfer.py counts host time blocked on the
@@ -1493,7 +1512,8 @@ class Executor:
             self.row_counts_launched += 1
             return page.rows
         self.row_counts_eager += 1
-        return page.num_rows()
+        with XF.eager("num-rows"):
+            return page.num_rows()
 
     def _scan_chain(self, node: P.PhysicalNode, *, through_joins: bool):
         """Walk a Filter/Project/Exchange chain (and, when
@@ -2394,6 +2414,7 @@ class Executor:
             self._collect_stats = {}
             own_stats = True
         exec_span = None
+        outer_attempt = self._attempt_span
         if tr is not None:
             # the statement's own run is a phase (it opens where the
             # plan phase ends); a plan-time scalar subquery's run
@@ -2489,6 +2510,9 @@ class Executor:
             self._cache_points = {}
             self._cache_pending = []
             self._snap_compile_counters(cc_base)
+            # what is timed after this belongs to no attempt of this
+            # run (the next statement's trace numbers its spans anew)
+            self._attempt_span = outer_attempt
             if tr is not None:
                 tr.end(exec_span, boost=self._capacity_boost)
             if own_stats:
@@ -2770,6 +2794,7 @@ class Executor:
         self._host_sink_ids = self._sink_chain_ids(node)
         _prev_sink = XF.swap_sink(self)
         tr = self.trace
+        outer_attempt = self._attempt_span
         try:
             attempts = 0
             while attempts < 6:
@@ -2838,6 +2863,7 @@ class Executor:
             self._cache_points = {}
             self._cache_pending = []
             self._snap_compile_counters(cc_base)
+            self._attempt_span = outer_attempt
 
     def _snap_compile_counters(self, base) -> None:
         """Record this query's compile-cost delta (see compilecache.py;
@@ -3652,7 +3678,7 @@ class Executor:
                 _empty_state_page(node.aggregates, layouts,
                                       collect_k=self._collect_k_eff)
             ]
-        merged = concat_all(partials) if len(partials) > 1 else partials[0]
+        merged = _concat_states(partials)
         final_fn = self._jit(
             ("gagg_final", node.aggregates,
              tuple(tuple(l) for l in layouts), tuple(in_types)),

@@ -401,15 +401,19 @@ def _count_wait(wall: float) -> None:
         sink.count_device_wait(wall)
 
 
-class device_wait:
-    """``with device_wait(site):`` around a host read that blocks on the
-    device and crosses no page (devsync.drain): counted on
-    ``device_wait_us`` and annotated like the pulls below."""
+class _timed_site:
+    """``with <kind>(site):`` around a stretch of the driver thread:
+    a ``<kind>:<site>`` annotation on the profiler's host plane and,
+    where the thread-bound executor is traced, a span of that kind
+    (obs.SPAN_KINDS) of its open attempt, cut from one clock reading
+    at each end."""
 
-    __slots__ = ("note", "t0")
+    __slots__ = ("site", "note", "t0")
+    kind = ""
 
     def __init__(self, site: str):
-        self.note = _wait_note(site)
+        self.site = site
+        self.note = annotation(f"{self.kind}:{site}")
         self.t0 = time.perf_counter()
 
     def __enter__(self):
@@ -418,7 +422,39 @@ class device_wait:
     def __exit__(self, *exc):
         wall = time.perf_counter() - self.t0
         self.note.__exit__(None, None, None)
-        _count_wait(wall)
+        sink = getattr(_tls, "sink", None)
+        if sink is not None:
+            self._record(sink, wall)
+
+
+class device_wait(_timed_site):
+    """``with device_wait(site):`` around a host read that blocks on the
+    device and crosses no page (devsync.drain): counted on
+    ``device_wait_us``, annotated like the pulls below, and a ``wait``
+    span."""
+
+    __slots__ = ()
+    kind = "wait"
+
+    def _record(self, sink, wall: float) -> None:
+        sink.count_device_wait(wall)
+        if sink.trace is not None:
+            sink.span_ending_now("wait", self.site, wall)
+
+
+class eager(_timed_site):
+    """``with eager(site):`` around ``jnp`` calls dispatched from the
+    driver thread outside ``_jit`` (no program of the registry, no
+    count on ``device_launches``): host time in which the runtime may
+    hold the call while the device is behind. An ``eager`` span and
+    an ``eager:<site>`` annotation; no counter."""
+
+    __slots__ = ()
+    kind = "eager"
+
+    def _record(self, sink, wall: float) -> None:
+        if sink.trace is not None:
+            sink.span_ending_now("eager", self.site, wall)
 
 
 def _meter(direction: str, nbytes: int, wall: float, label: str) -> None:
@@ -434,12 +470,9 @@ def _meter(direction: str, nbytes: int, wall: float, label: str) -> None:
     if sink is None:
         return
     sink.count_transfer(direction, nbytes, wall)
-    tr = sink.trace
-    if tr is not None:
-        t1 = tr.now()
-        tr.complete("xfer", f"{direction}:{label}", t1 - wall, t1,
-                    bytes=nbytes)
-        sink.trace_spans += 1
+    if sink.trace is not None:
+        sink.span_ending_now("xfer", f"{direction}:{label}", wall,
+                             bytes=nbytes)
 
 
 def to_host(tree, label: str = "page"):
@@ -463,8 +496,9 @@ def to_device(tree, spec=None, label: str = "page"):
     contribute no bytes (device_put leaves them in place)."""
     nbytes = _host_nbytes(tree)
     t0 = time.perf_counter()
-    out = (jax.device_put(tree, spec) if spec is not None
-           else jax.device_put(tree))
+    with annotation("xfer:h2d:" + label):
+        out = (jax.device_put(tree, spec) if spec is not None
+               else jax.device_put(tree))
     if nbytes:
         _meter("h2d", nbytes, time.perf_counter() - t0, label)
     return out

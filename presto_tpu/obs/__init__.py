@@ -89,7 +89,26 @@ SPAN_KINDS: Dict[str, str] = {
             "replay, remote-source ingest); attrs carry bytes, and "
             "the summed span wall equals the query's transfer_wall_s "
             "counter — the copy-time phase ROADMAP item 6 drives "
-            "toward zero",
+            "toward zero; a child of the open attempt; on the "
+            "profiler's host plane a pull is wait:<label>, a "
+            "staging xfer:h2d:<label>",
+    "launch": "one call of a device program on the driver thread "
+              "(exec/programs.launch, named by the program's label): "
+              "the interval is the call's host wall, cut from the "
+              "clock readings dispatch_wall_us is summed from; a "
+              "child of the attempt, on the profiler's host plane "
+              "launch:<label>",
+    "wait": "one host read that blocks on the device and crosses no "
+            "page (xfer.device_wait: devsync.drain), named by its "
+            "site; with the d2h xfer spans its wall sums to "
+            "device_wait_us; on the profiler's host plane "
+            "wait:<site>",
+    "eager": "one stretch of the driver thread in jnp dispatches "
+             "outside _jit (xfer.eager: the page.num_rows() pair a "
+             "pages() boundary keeps for the query trace on one "
+             "device), named by its site: host time in which the "
+             "runtime may make the call wait for the device; on the "
+             "profiler's host plane eager:<site>",
     "resident_load": "one table loaded into the device-resident "
                      "store (connectors/cached.py), recorded on the "
                      "statement whose scan touched it first; attrs: "

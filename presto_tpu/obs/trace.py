@@ -49,6 +49,12 @@ _TraceAnnotation = None
 
 # the phases of a served statement, in order (obs.SPAN_KINDS has each)
 PHASE_KINDS = ("queue", "parse", "plan", "execute", "encode")
+# the kinds below an ``execute`` phase that are real intervals of the
+# driver thread, each timed at its site: what ``phases()`` lists under
+# the phase, and what its self time is taken against. Not ``attempt``
+# (the container) nor ``operator`` (per-node wall totals laid at the
+# attempt's start, overlapping by design)
+INTERVAL_KINDS = ("launch", "wait", "xfer", "eager", "resident_load")
 
 
 def annotation(name: str):
@@ -241,17 +247,40 @@ class QueryTrace:
 
     def phases(self) -> List[dict]:
         """The top-level spans in order, microseconds from the anchor
-        (/v1/query/{id}'s ``phases``): they tile the root."""
+        (/v1/query/{id}'s ``phases``): they tile the root. The
+        ``execute`` phase carries ``spans``: its descendants of
+        ``INTERVAL_KINDS`` (every launch, device wait, transfer and
+        eager dispatch of its attempts) on the same clock, in order
+        of their starts."""
         now = self.now()
         root = self.root.span_id
-        return [{
-            "kind": sp.kind,
-            "startUs": int(round(sp.t0 * 1e6)),
-            "endUs": int(round(
-                (sp.t1 if sp.t1 is not None else now) * 1e6)),
-            "attrs": sp.attrs,
-        } for sp in sorted(self.spans(), key=lambda s: (s.t0, s.span_id))
-            if sp.parent_id == root and sp.kind in PHASE_KINDS]
+        spans = self.spans()
+
+        def us(t: Optional[float]) -> int:
+            return int(round((now if t is None else t) * 1e6))
+
+        out = []
+        for sp in sorted(spans, key=lambda s: (s.t0, s.span_id)):
+            if sp.parent_id != root or sp.kind not in PHASE_KINDS:
+                continue
+            phase = {"kind": sp.kind, "startUs": us(sp.t0),
+                     "endUs": us(sp.t1), "attrs": sp.attrs}
+            if sp.kind == "execute":
+                # a parent is recorded before its children
+                below = {sp.span_id}
+                inside = []
+                for c in spans:
+                    if c.parent_id in below:
+                        below.add(c.span_id)
+                        if c.kind in INTERVAL_KINDS:
+                            inside.append(c)
+                inside.sort(key=lambda s: (s.t0, s.span_id))
+                phase["spans"] = [
+                    {"kind": c.kind, "name": c.name,
+                     "startUs": us(c.t0), "endUs": us(c.t1)}
+                    for c in inside]
+            out.append(phase)
+        return out
 
     def export(self) -> List[dict]:
         """Wire form for shipping to a coordinator (worker status
@@ -290,14 +319,20 @@ class QueryTrace:
         def at(t: float) -> int:
             return ms(t - origin)
 
+        def at_us(t: float) -> int:
+            return int(round((t - origin) * 1e6))
+
         def descend(sp: Span) -> List[dict]:
             out = []
             for c in sorted(children.get(sp.span_id, ()),
                             key=lambda s: (s.t0, s.span_id)):
+                end = c.t1 if c.t1 is not None else now
+                # a launch is 1-2 ms and a pull 0.45: whole
+                # milliseconds alone would lay them on one instant
                 out.append({
                     "kind": c.kind, "name": c.name,
-                    "startMs": at(c.t0),
-                    "endMs": at(c.t1 if c.t1 is not None else now),
+                    "startMs": at(c.t0), "endMs": at(end),
+                    "startUs": at_us(c.t0), "endUs": at_us(end),
                     "attrs": c.attrs,
                 })
                 out.extend(descend(c))

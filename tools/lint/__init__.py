@@ -659,12 +659,15 @@ def check_purity(paths=None) -> List[Finding]:
 
 # ------------------------------------------------------------ rule: spans
 # the span-recorder emission methods (obs/trace.QueryTrace; _new is
-# the internal constructor the root "query" span uses). A call
+# the internal constructor the root "query" span uses;
+# Executor.span_ending_now is how the launch point and exec/xfer.py's
+# choke points hand over an interval they timed themselves). A call
 # `<anything>.begin("kind", ...)` / `.complete("kind", ...)` with a
 # constant first argument IS an emission site; dynamic kinds (the
 # ingest path re-materializing remote spans) are invisible here by
 # design — every dynamic kind originates at some constant site.
-_SPAN_EMIT_METHODS = ("begin", "complete", "phase", "_new")
+_SPAN_EMIT_METHODS = ("begin", "complete", "phase", "_new",
+                      "span_ending_now")
 
 
 def check_spans(paths=None) -> List[Finding]:
