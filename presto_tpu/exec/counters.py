@@ -72,6 +72,14 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "the driver thread between launches: every count on one "
         "device, 0 over a mesh for a statement whose pages all come "
         "out of programs"),
+    "plan_constants_folded": (
+        "counter", "Call nodes the planner's constant fold replaced, "
+        "over every planning pass so far (expr/fold.py: an "
+        "allow-listed call on non-NULL constants of exact types "
+        "becomes the Constant of its value; 3 a pass over TPC-H Q6, 0 "
+        "over Q3). The runner adds a pass's as it ends: one a "
+        "statement on the serial path, two on the concurrent path "
+        "(admission's estimate_memory plans, then the execution)"),
     "resident_table_bytes": (
         "gauge", "device bytes the catalogs' stored tables hold now "
         "(connectors/cached.py: every column and the validity of each "

@@ -557,6 +557,10 @@ class Executor:
         # below)
         self.resident_splits_scanned = 0
         self.resident_bytes_scanned = 0
+        # Call nodes the planner's constant fold replaced (expr/fold.py)
+        # in this executor's runner's planning passes
+        # (count_constants_folded below); no attempt resets it
+        self.plan_constants_folded = 0
         self._attempt_span = None   # the open attempt, while tracing
         # _agg_sizing's decisions this attempt (the attempt span
         # reports the costliest: most passes, then largest capacity)
@@ -1182,6 +1186,12 @@ class Executor:
         tr.complete(kind, name, t1 - wall_s, t1,
                     parent=self._attempt_span, **attrs)
         self.trace_spans += 1
+
+    def count_constants_folded(self, n: int) -> None:
+        """THE sink the runner records a planning pass's constant
+        folds on (tools/lint's counters rule: a registry counter is
+        written where it is declared, on the executor)."""
+        self.plan_constants_folded += n
 
     def count_resident_load(self, table: str, wall_s: float,
                             **attrs) -> None:

@@ -67,7 +67,9 @@ SPAN_KINDS: Dict[str, str] = {
              "planning)",
     "plan": "phase: analyze + plan + optimize + fragment, up to the "
             "instant the executor takes the plan (plan-time scalar "
-            "subqueries' execute spans nest inside)",
+            "subqueries' execute spans nest inside; attrs: "
+            "constants_folded, the Call nodes the statement's "
+            "expressions lost to the planner's constant fold)",
     "encode": "coordinator-side phase: executor done -> rows encoded "
               "as JSON protocol values; the root ends with it",
     "run": "worker-side: fragment execution (attrs: pages, spooled)",

@@ -889,10 +889,17 @@ class LocalRunner:
         # subqueries run on the executor meanwhile and nest here.
         ex = self.executor
         tr = ex.trace
+        span = None
         if tr is not None:
-            ex.trace_parent = tr.phase("plan")
+            span = ex.trace_parent = tr.phase("plan")
         try:
-            out = self._planner().plan_statement(query)
+            planner = self._planner()
+            out = planner.plan_statement(query)
+            # what the constant fold (expr/fold.py) replaced: on the
+            # open plan phase and the registry counter
+            ex.count_constants_folded(planner.constants_folded)
+            if span is not None:
+                span.attrs["constants_folded"] = planner.constants_folded
             self._check_plan_access(out)
             out = prune_plan(out, self.catalogs)
             out = push_scan_constraints(out)

@@ -41,6 +41,7 @@ from presto_tpu.exec import agg_states as AS
 from presto_tpu.exec import plan as P
 from presto_tpu.expr import ir
 from presto_tpu.expr import functions as F
+from presto_tpu.expr.fold import fold_constants
 from presto_tpu.ops.sort import SortKey
 from presto_tpu.sql import ast_nodes as N
 
@@ -396,6 +397,9 @@ class Planner:
         # probes and repeated translation don't re-run them
         self.scalar_cache: Dict = scalar_cache if scalar_cache is not None \
             else {}
+        # Call nodes the statement's expressions lost to constant
+        # folding (expr/fold.py): the plan span's constants_folded
+        self.constants_folded = 0
 
     # --------------------------------------------------------- statements
     def plan_statement(self, stmt: N.Node) -> P.Output:
@@ -1846,7 +1850,11 @@ class ExprTranslator:
         self._lambda_scopes: List[dict] = []
 
     def translate(self, e: N.Node, root: bool = False) -> ir.RowExpression:
-        out = self._tr(e, root)
+        """The ONE place a lowered expression leaves the translator:
+        filters, projections, join conditions, aggregate arguments and
+        HAVING all pass the constant fold here (expr/fold.py)."""
+        out, folded = fold_constants(self._tr(e, root))
+        self.planner.constants_folded += folded
         return out
 
     def _sub(self, e: N.Node) -> Optional[ir.RowExpression]:
@@ -2055,10 +2063,14 @@ class ExprTranslator:
     def _group_probe(self, e: N.Node) -> Optional[ir.RowExpression]:
         """If e translates (in the pre-agg scope) to a group expression,
         return the key channel ref."""
+        folded = self.planner.constants_folded
         try:
             pre = ExprTranslator(self.planner, self.scope).translate(e)
         except PlanningError:
             return None
+        finally:
+            # looked at and thrown away: not the statement's folds
+            self.planner.constants_folded = folded
         if pre in self.group_subst:
             ch = self.group_subst[pre]
             return ir.InputRef(ch, pre.type)
