@@ -85,8 +85,8 @@ def _builtin_factories() -> Dict[str, Callable]:
         """Tables of an inner connector held on the device
         (connectors/cached.py): ``resident.inner`` names a built-in
         connector, whose own keys ride in the same file;
-        ``resident.tables`` the tables stored (absent: every table at
-        its first scan)."""
+        ``resident.tables`` the tables stored (``*`` or absent: every
+        table, each at its first scan; ``*`` stands alone)."""
         from presto_tpu.connectors.cached import ResidentConnector
 
         inner_name = props.get("resident.inner", "")
@@ -99,6 +99,12 @@ def _builtin_factories() -> Dict[str, Callable]:
         tables = [t.strip() for t in
                   props.get("resident.tables", "").split(",")
                   if t.strip()]
+        if "*" in tables:
+            if len(tables) > 1:
+                raise ValueError(
+                    f"resident.tables names {tables}: * stands for "
+                    "every table and is given alone")
+            tables = []
         unknown = sorted(set(tables) - set(inner.tables()))
         if unknown:
             raise ValueError(

@@ -36,15 +36,27 @@ everything else reads it through ``pages()``, which yields the base
 per-split loop's pages over the inner connector's own splits
 (``fused_scan_ok``). Metadata, splits, pruning, ``page_for_split``
 (the benchmark's references read the generator's rows through it, so
-they stay independent of the store), dictionaries and the generated
-joins' ``gen_at`` / ``key_inverse`` answer from the inner connector.
+they stay independent of the store) and dictionaries answer from the
+inner connector.
+
+A table the catalog stores is LOOKED UP in the store, never generated:
+``gen_body`` / ``gen_batch`` / ``gen_at`` / ``key_inverse`` /
+``key_window_inverse`` all answer None for it (one rule,
+``_is_resident``), so the executor cannot answer a join over a stored
+table by generating the table's rows at the probe keys (a "generated
+join"): that is right only while nobody can write to the table. A
+join whose build side is a stored table is a real build (the
+executor's ``stored_build``: a lookup structure over the stored
+columns, made once a statement, ``stored_source(table, names)`` hands
+it the whole table) and its probe a step of the fused scan.
 
 In etc/ (presto_tpu/config.py)::
 
     connector.name=resident
     resident.inner=tpch          # any built-in connector.name
     tpch.scale-factor=10         # the inner connector's own keys
-    resident.tables=lineitem     # comma-separated; absent = every table
+    resident.tables=lineitem     # comma-separated; * or absent = every
+                                 # table, each loaded at its first scan
 """
 
 from __future__ import annotations
@@ -158,11 +170,15 @@ class StoredSource:
     are the device buffers every launch is handed as program ARGUMENTS,
     ``reads`` how a program reads a split from them (_SliceReads)."""
 
-    def __init__(self, datas, dtypes: Tuple[str, ...], valid):
+    def __init__(self, datas, dtypes: Tuple[str, ...], valid,
+                 rows: int):
         import jax
 
         self.args = (tuple(datas), valid)
         self.reads = _SliceReads(dtypes + ("bool",))
+        # the table's slots (what a whole-table read, a join's build,
+        # covers: reads.body(rows, *args)(0))
+        self.rows = rows
         # bytes of stored columns and validity one slot of a launch's
         # slices holds (from the buffers' shapes: no device read)
         self.slot_bytes = sum(
@@ -232,7 +248,9 @@ def _program(label: str):
 
 class ResidentConnector:
     """Wraps a connector; the tables named (all, where none are) are
-    stored on the device once and scanned from there."""
+    stored on the device once, each at its first scan, and read from
+    there by scans and by joins alike: a stored table is never
+    generated (gen_body and its neighbours below)."""
 
     # pages() below yields the base per-split loop's rows over the
     # inner connector's splits()/prune_splits(), so the executor's
@@ -397,22 +415,42 @@ class ResidentConnector:
         return StoredSource(
             [b.data for b in blocks],
             tuple(dt for j in idx for dt in st.dtypes[j]),
-            st.page.valid)
+            st.page.valid, st.rows)
+
+    def stores(self, table: str) -> bool:
+        """Whether a scan of ``table`` reads the store (it is named
+        and its staleness can be proven), without loading it."""
+        from presto_tpu.cache.rules import snapshot_of
+
+        return self._is_resident(table) and \
+            snapshot_of(self._inner, table) is not None
+
+    # No generation of a table the catalog stores, by ONE rule: its
+    # scan is a read (stored_source; the mesh executor, which asks
+    # only gen_body, stages its pages()), and a join over it is a
+    # lookup in a structure built from the stored columns, never the
+    # generator run at the probe keys (gen_at with key_inverse or
+    # key_window_inverse: the "generated join"). Other tables answer
+    # as the inner connector does.
+    def _unless_stored(self, method: str, table, *args):
+        if self._is_resident(table):
+            return None
+        return getattr(self._inner, method)(table, *args)
 
     def gen_body(self, table, n, names):
-        """No generation of a stored table: its scan is a read
-        (stored_source), and the mesh executor, which asks only this,
-        stages its pages(). Other tables generate as the inner
-        connector does; generated joins (gen_at / key_inverse) always
-        do: they are lookups, not scans."""
-        if self._is_resident(table):
-            return None
-        return self._inner.gen_body(table, n, names)
+        return self._unless_stored("gen_body", table, n, names)
 
     def gen_batch(self, table, n, names):
-        if self._is_resident(table):
-            return None
-        return self._inner.gen_batch(table, n, names)
+        return self._unless_stored("gen_batch", table, n, names)
+
+    def gen_at(self, table, names):
+        return self._unless_stored("gen_at", table, names)
+
+    def key_inverse(self, table, column):
+        return self._unless_stored("key_inverse", table, column)
+
+    def key_window_inverse(self, table, column):
+        return self._unless_stored("key_window_inverse", table, column)
 
     def pages(self, table: str, columns: Optional[Sequence[str]] = None,
               target_rows: int = 1 << 20, constraint=None):

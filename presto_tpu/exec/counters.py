@@ -100,6 +100,20 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "gauge", "bytes of stored columns and validity in those "
         "splits' slices (padded rows x the touched buffers' widths), "
         "counted at the launch from shapes: no device read"),
+    "join_builds": (
+        "gauge", "lookup structures this attempt built over stored "
+        "tables (Executor._stored_build: one a join whose build side "
+        "is a stored table, once a statement; program stored_build)"),
+    "join_build_rows": (
+        "gauge", "stored slots those builds read (the build tables' "
+        "sizes: from shapes, no device read)"),
+    "join_build_bytes": (
+        "gauge", "device bytes those structures hold (direct-address "
+        "table, key floor, the build side's page; from shapes)"),
+    "join_build_wall_us": (
+        "gauge", "host microseconds of those builds, start to the "
+        "build program's enqueue (the join_build spans' sum; the "
+        "device's part is the trace's jit_stored_build)"),
     "dispatch_wall_us": (
         "gauge", "host microseconds inside those calls this attempt: "
         "trace-cache lookup, argument handling, enqueue (and a "

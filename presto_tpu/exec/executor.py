@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from presto_tpu.obs.trace import annotation
+
 from presto_tpu import compilecache as CC
 from presto_tpu import types as T
 from presto_tpu.exec import counters as CTRS
@@ -103,6 +105,40 @@ class _GeneratedSource:
 
     def batch(self, n_pad: int):
         return self._conn.gen_batch(self._table, n_pad, self._names)
+
+
+# slots a stored join's direct-address table keeps a stored build slot:
+# dbgen's order keys use 8 of every 32 values, so a table of keys
+# 1..4N holds N orders; dense keys (custkey, suppkey) leave it 3/4
+# empty, which costs bytes and no time (a gather's time does not
+# depend on its operand's size above a few thousand entries, PERF.md)
+STORED_JOIN_KEY_SPREAD = 4
+
+
+class StoredJoin(NamedTuple):
+    """What Executor._stored_join_info says of a join whose build side
+    is a Filter / Project chain over a scan of a STORED table
+    (connectors/cached.py): the probe key that is looked up, the
+    build's scan and chain, and the size of its lookup structure."""
+
+    pivot_ch: int          # probe channel looked up
+    build_key_ch: int      # its partner, a channel of the build's root
+    extra_pairs: tuple     # (probe ch, build ch) pairs checked after
+    scan: object           # the build side's P.TableScan
+    chain_fns: tuple       # its Filter / Project chain, bottom-up
+    rows: int              # the stored table's slots
+    cap: int               # entries of the direct-address table
+    nbytes: int            # device bytes of the whole structure
+
+
+def _plain_int(t) -> bool:
+    """A key type whose values are one integer word (what a generated
+    or a stored join compares as int64)."""
+    return not (
+        T.is_string(t) or t.is_dictionary_encoded
+        or T.is_floating(t)
+        or (isinstance(t, T.DecimalType) and not t.is_short)
+    )
 
 
 def _canonical_join_cols(
@@ -557,6 +593,21 @@ class Executor:
         # below)
         self.resident_splits_scanned = 0
         self.resident_bytes_scanned = 0
+        # joins whose build side is a stored table (_stored_build),
+        # this attempt: join_builds = lookup structures built (one a
+        # join, once a statement), join_build_rows = stored slots those
+        # builds read, join_build_bytes = device bytes the structures
+        # hold (both from shapes: no device read), join_build_wall_us
+        # = host microseconds from a build's start to its program's
+        # enqueue (the join_build spans' sum; the device's part is the
+        # trace's jit_stored_build)
+        self.join_builds = 0
+        self.join_build_rows = 0
+        self.join_build_bytes = 0
+        self.join_build_wall_us = 0
+        # the structures themselves, by build: made once an attempt,
+        # freed with the statement (_release_stream_cache)
+        self._stored_builds: Dict = {}
         # Call nodes the planner's constant fold replaced (expr/fold.py)
         # in this executor's runner's planning passes
         # (count_constants_folded below); no attempt resets it
@@ -1525,13 +1576,16 @@ class Executor:
         with XF.eager("num-rows"):
             return page.num_rows()
 
-    def _scan_chain(self, node: P.PhysicalNode, *, through_joins: bool):
+    def _scan_chain(self, node: P.PhysicalNode, *, through_joins: bool,
+                    stored_joins: bool = False):
         """Walk a Filter/Project/Exchange chain (and, when
-        through_joins, generated-join-eligible HashJoins) down to its
-        TableScan. THE one chain walker shared by the generated-join
-        eligibility check and the fused-pipeline builder. Returns
-        (scan, chain top-down) with HashJoins as (node, info) tuples,
-        or None when any node breaks the chain."""
+        through_joins, generated-join-eligible HashJoins; with
+        stored_joins also the ones whose build is a stored table,
+        _stored_join_info: the one-chip fused stream alone asks for
+        them) down to its TableScan. THE one chain walker shared by
+        the generated-join eligibility check and the fused-pipeline
+        builder. Returns (scan, chain top-down) with HashJoins as
+        (node, info) tuples, or None when any node breaks the chain."""
         chain: List = []
         cur = node
         while True:
@@ -1539,8 +1593,10 @@ class Executor:
                 chain.append(cur)
                 cur = cur.source
             elif through_joins and isinstance(cur, P.HashJoin):
-                info = self._generated_join_info(
-                    cur, self.output_types(cur.left))
+                left_types = self.output_types(cur.left)
+                info = self._generated_join_info(cur, left_types)
+                if info is None and stored_joins:
+                    info = self._stored_join_info(cur, left_types)
                 if info is None:
                     return None
                 chain.append((cur, info))
@@ -1571,11 +1627,19 @@ class Executor:
         generates (_apply_steps): ("map", page -> page) for a Filter
         or Project, ("join", page -> page) for a build-free generated
         join, ("joinw", page -> (page, multi_flag)) for a windowed
-        one. THE one step list of the one-chip fused stream and of
-        the mesh executor's fused scan round (dist/executor.py)."""
+        one, ("sjoin", (page, build) -> page) for the probe of a
+        stored build (_apply_steps hands it the chain's builds in
+        this order; _fused_stream makes them). THE one step list of
+        the one-chip fused stream and of the mesh executor's fused
+        scan round (dist/executor.py)."""
         steps: List = []
         for nd in reversed(chain):
-            if isinstance(nd, tuple):
+            if isinstance(nd, tuple) and isinstance(nd[1], StoredJoin):
+                jnode, info = nd
+                steps.append(("sjoin", functools.partial(
+                    _stored_join_page, info.pivot_ch, info.extra_pairs,
+                    jnode.join_type)))
+            elif isinstance(nd, tuple):
                 jnode, info = nd
                 kern, windowed = self.generated_join_kernel(jnode, info)
                 steps.append(("joinw" if windowed else "join", kern))
@@ -1622,7 +1686,8 @@ class Executor:
         one per split."""
         if not self.use_jit:
             return None
-        walked = self._scan_chain(node, through_joins=True)
+        walked = self._scan_chain(node, through_joins=True,
+                                  stored_joins=True)
         if walked is None:
             return None
         cur, chain = walked
@@ -1657,6 +1722,19 @@ class Executor:
             if conn.gen_body(cur.table, 8, names) is None:
                 return None
             src = _GeneratedSource(conn, cur.table, names)
+        # a join over a stored table (_stored_join_info): its lookup
+        # structure, built once a statement (_stored_build), is handed
+        # to every launch beside the source's buffers, bottom-up as
+        # the step list probes them
+        builds = []
+        for link in reversed(chain):
+            if isinstance(link, tuple) and isinstance(link[1], StoredJoin):
+                built = self._stored_build(link[0], link[1])
+                if built is None:
+                    return None  # a column holds NULLs: through pages()
+                builds.append(built)
+        builds = tuple(builds)
+        nb = len(builds)
         # the programs made below stay in the jit cache: they close
         # over the source's reads, never over the source, whose args
         # are a stored table's buffers (a write has to free them)
@@ -1701,20 +1779,30 @@ class Executor:
                 for d, t, dic in zip(datas, scan_types, scan_dicts)
             ), valid=valid)
 
-        def run_split(gen_fn, n_pad, start, count):
+        def run_split(gen_fn, built, n_pad, start, count):
             datas, valid = gen_fn(start)
             return _apply_steps(make_page(datas, valid, n_pad, count),
-                                steps)
+                                steps, built)
+
+        def launch_args(a):
+            # a launch's arguments: the source's buffers (none for a
+            # generator), the chain's stored builds (none without a
+            # stored join), then the splits' starts and counts
+            return a[:len(a) - 2 - nb], a[len(a) - 2 - nb:-2]
 
         def run_one(n_pad, *a):
-            # a = the source's buffers (none for a generator), then
-            # the split's start and count
-            return run_split(reads.body(n_pad, *a[:-2]), n_pad, *a[-2:])
+            src_a, built = launch_args(a)
+            return run_split(reads.body(n_pad, *src_a), built, n_pad,
+                             *a[-2:])
 
         def jit_one(n_pad):
             def make():
                 return functools.partial(run_one, n_pad)
 
+            if nb:
+                return self._jit(
+                    ("stored_probe", node, key_extra, cur.table, n_pad),
+                    make=make)
             if src.args:
                 return self._jit(
                     ("stored", node, key_extra, cur.table, n_pad),
@@ -1742,7 +1830,7 @@ class Executor:
             self.launch_batcher is not None
             and self.cross_query_batching not in
             (False, None, "false", "off")
-            and not src.args
+            and not src.args and not nb
         )
 
         def make_xq_fn(n_pad, B):
@@ -1853,7 +1941,7 @@ class Executor:
             run_fused = jit_one(n_pad)
             with solo_mark:
                 page, flags = run_fused(
-                    *src.args,
+                    *src.args, *builds,
                     jnp.int64(split.start_row),
                     jnp.int64(split.row_count),
                 )
@@ -1904,14 +1992,16 @@ class Executor:
                 # page of B*n_pad slots (the exact concatenation of
                 # the per-split pages), so downstream per-page
                 # programs amortize their launches by B too
-                def post(datas, valid, count):
-                    return _apply_steps(
-                        make_page(datas, valid, n_pad_all, count),
-                        steps,
-                    )
-
                 def run_batch(*a):
-                    datas, valid = reads.batch(n_pad_all, *a[:-2])(a[-2])
+                    src_a, built = launch_args(a)
+
+                    def post(datas, valid, count):
+                        return _apply_steps(
+                            make_page(datas, valid, n_pad_all, count),
+                            steps, built,
+                        )
+
+                    datas, valid = reads.batch(n_pad_all, *src_a)(a[-2])
                     pages, flags = jax.vmap(post)(datas, valid, a[-1])
                     return (
                         _merge_leading(pages),
@@ -1925,11 +2015,12 @@ class Executor:
                 # concat of the per-split states, so parity with the
                 # unbatched driver loop is bit-exact
                 def run_batch(*a):
-                    gen_fn = reads.body(n_pad_all, *a[:-2])
+                    src_a, built = launch_args(a)
+                    gen_fn = reads.body(n_pad_all, *src_a)
 
                     def body(_, x):
                         page, flags = run_split(
-                            gen_fn, n_pad_all, x[0], x[1])
+                            gen_fn, built, n_pad_all, x[0], x[1])
                         return 0, (page, or_flags(flags))
 
                     _, (states, flags) = jax.lax.scan(
@@ -1948,13 +2039,15 @@ class Executor:
             tail_fn = steps[-1][1]
 
             def run_batch(*a):
-                gen_fn = reads.body(n_pad_all, *a[:-2])
+                src_a, built = launch_args(a)
+                gen_fn = reads.body(n_pad_all, *src_a)
                 starts, counts = a[-2:]
 
                 def one_state(start, count):
                     datas, valid = gen_fn(start)
                     page, flags = _apply_steps(
-                        make_page(datas, valid, n_pad_all, count), pre)
+                        make_page(datas, valid, n_pad_all, count), pre,
+                        built)
                     st, ovf = tail_fn(page)
                     return st, or_flags(flags) | ovf
 
@@ -1990,14 +2083,19 @@ class Executor:
                     continue
                 B = SH.split_batch_bucket(len(chunk))
                 tail = (node, key_extra, cur.table, n_pad_all, B)
-                key = ("stored_batch" if src.args else "fused_batch",
-                       *tail)
-                run_batch = (
-                    self._jit(("stored_batch", *tail),
-                              make=build_batch_fn)
-                    if src.args else
-                    self._jit(("fused_batch", *tail),
-                              make=build_batch_fn))
+                if nb:
+                    key = ("stored_probe_batch", *tail)
+                    run_batch = self._jit(
+                        ("stored_probe_batch", *tail),
+                        make=build_batch_fn)
+                elif src.args:
+                    key = ("stored_batch", *tail)
+                    run_batch = self._jit(
+                        ("stored_batch", *tail), make=build_batch_fn)
+                else:
+                    key = ("fused_batch", *tail)
+                    run_batch = self._jit(
+                        ("fused_batch", *tail), make=build_batch_fn)
                 starts = np.zeros(B, np.int64)
                 counts = np.zeros(B, np.int64)
                 for j, s in enumerate(chunk):
@@ -2007,7 +2105,7 @@ class Executor:
                     # metered h2d: 2xB int64 split descriptors per
                     # batched launch (exec/xfer.py choke point)
                     page, flags = run_batch(
-                        *src.args,
+                        *src.args, *builds,
                         XF.to_device(starts, label="batch-starts"),
                         XF.to_device(counts, label="batch-starts"))
                 except Exception:
@@ -2502,6 +2600,9 @@ class Executor:
                                self.resident_splits_scanned),
                            resident_bytes_scanned=(
                                self.resident_bytes_scanned),
+                           join_builds=self.join_builds,
+                           join_build_rows=self.join_build_rows,
+                           join_build_bytes=self.join_build_bytes,
                            **self._agg_sizing_attrs())
                 # overflow-free attempt: completed cache streams are
                 # safe to publish (decode above already paid the sync)
@@ -2566,6 +2667,10 @@ class Executor:
         self.device_wait_us = 0
         self.resident_splits_scanned = 0
         self.resident_bytes_scanned = 0
+        self.join_builds = 0
+        self.join_build_rows = 0
+        self.join_build_bytes = 0
+        self.join_build_wall_us = 0
         self._launches_by_label = {}
         self._agg_sizings = []
         self.splits_scanned = 0
@@ -2894,6 +2999,9 @@ class Executor:
                 pass           # failed spill-dir sweep must not mask
                 # the query's own result/error path
         self._stream_cache = {}
+        # a stored join's lookup structures live as long (a retried
+        # attempt rebuilds what it still needs)
+        self._stored_builds = {}
 
     def _account_page(self, page: Page) -> None:
         size = page_bytes(page)
@@ -3891,14 +3999,7 @@ class Executor:
             return None
         cur, chain = walked
 
-        def plain_int(t) -> bool:
-            return not (
-                T.is_string(t) or t.is_dictionary_encoded
-                or T.is_floating(t)
-                or (isinstance(t, T.DecimalType) and not t.is_short)
-            )
-
-        if not all(plain_int(left_types[c]) for c in node.left_keys):
+        if not all(_plain_int(left_types[c]) for c in node.left_keys):
             return None
         from presto_tpu.expr.ir import InputRef
 
@@ -4001,6 +4102,118 @@ class Executor:
             node.join_type, inv, window, gen_keys, gen, scan_types,
             scan_dicts, chain_fns, n_rows,
         ), True
+
+    def _stored_join_info(self, node: P.HashJoin, left_types
+                          ) -> Optional[StoredJoin]:
+        """Eligibility for a join whose build side is a STORED table
+        (connectors/cached.py: what is stored is looked up, never
+        generated) probed as a step of the fused scan: an inner or
+        left equi-join whose build subtree is a Filter / Project /
+        Exchange chain over a scan of a table its catalog stores, on
+        plain integer keys of which one scans a column the connector
+        declares unique. That key is looked up in a direct-address
+        table over the stored keys (_stored_build_page; the other key
+        pairs are equality checks on the row found), so a probe row
+        matches at most one build row and the output is the probe page
+        extended in place. The table has STORED_JOIN_KEY_SPREAD
+        entries a stored slot; a key set wider than that, or one that
+        is not unique after all, raises the build's deferred flag, and
+        the boosted retry is not eligible: it takes the materialized
+        join (_exec_join), as does a build whose structure outgrows
+        the governor's build share (_join_parts' share), which it
+        partitions."""
+        if self._capacity_boost > 1 or not self.use_jit:
+            return None
+        if node.join_type not in ("inner", "left"):
+            return None
+        walked = self._scan_chain(node.right, through_joins=False)
+        if walked is None:
+            return None
+        scan, chain = walked
+        conn = self.catalogs[scan.catalog]
+        stores = getattr(conn, "stores", None)
+        if stores is None or not stores(scan.table):
+            return None
+        right_types = self.output_types(node.right)
+
+        if not all(_plain_int(left_types[c]) for c in node.left_keys) \
+                or not all(_plain_int(right_types[c])
+                           for c in node.right_keys):
+            return None
+        pivot = next((j for j, rk in enumerate(node.right_keys)
+                      if self._scan_column_unique(node.right, rk)), None)
+        if pivot is None:
+            return None
+        rows = int(conn.row_count(scan.table))
+        cap = SH.bucket(max(rows, 1)) * STORED_JOIN_KEY_SPREAD
+        # the table's int32 entries, its key floor, and the build side
+        # as its chain leaves it (one row a stored slot; a column
+        # narrower than 8 bytes counted at 8: _carried_wide)
+        nbytes = 4 * cap + 8 + rows * max(
+            _row_bytes(right_types), 2 + 8 * len(right_types))
+        budget = self._budget()
+        if budget and nbytes > budget // MB.BUILD_SHARE_DIV:
+            return None
+        return StoredJoin(
+            pivot_ch=node.left_keys[pivot],
+            build_key_ch=node.right_keys[pivot],
+            extra_pairs=tuple(
+                (lk, rk) for j, (lk, rk) in enumerate(
+                    zip(node.left_keys, node.right_keys)) if j != pivot),
+            scan=scan,
+            chain_fns=tuple(
+                fn for fn in (_node_replay_fn(nd)
+                              for nd in reversed(chain))
+                if fn is not None),
+            rows=rows, cap=cap, nbytes=nbytes)
+
+    def _stored_build(self, node: P.HashJoin, info: StoredJoin):
+        """The lookup structure of one stored join (_stored_build_page:
+        the direct-address table, its key floor, the build side's
+        page), built ONCE an attempt by one program over the whole
+        stored table, whose buffers are the program's arguments. None
+        where the table cannot be read as a fused source (a column
+        holds NULLs). Its flag (keys wider than the table, or a
+        duplicate key) joins the deferred ladder."""
+        key = (node.right, info.build_key_ch)
+        built = self._stored_builds.get(key)
+        if built is not None:
+            return built
+        t0 = time.perf_counter()
+        scan = info.scan
+        conn = self.catalogs[scan.catalog]
+        names = tuple(scan.columns)
+        with annotation(f"join_build:{scan.table}"):
+            src = conn.stored_source(scan.table, names)
+            if src is None:
+                return None
+            schema = conn.table_schema(scan.table)
+            scan_types = tuple(schema.column_type(c) for c in names)
+            dicts = getattr(conn, "_dicts", {}).get(scan.table, {})
+            scan_dicts = tuple(dicts.get(c) for c in names)
+            fn = self._jit(
+                ("stored_build", node.right, info.build_key_ch,
+                 src.rows, info.cap),
+                functools.partial(
+                    _stored_build_page, src.reads, scan_types,
+                    scan_dicts, info.chain_fns, info.build_key_ch,
+                    src.rows, info.cap))
+            built, flag = fn(*src.args)
+        self._pending_overflow.append(flag)
+        self._stored_builds[key] = built
+        self.peak_memory_bytes = max(self.peak_memory_bytes,
+                                     info.nbytes)
+        wall = time.perf_counter() - t0
+        self.join_builds += 1
+        self.join_build_rows += src.rows
+        self.join_build_bytes += info.nbytes
+        self.join_build_wall_us += int(round(wall * 1e6))
+        if self.trace is not None:
+            self.span_ending_now(
+                "join_build", scan.table, wall, table=scan.table,
+                rows=src.rows, capacity=info.cap, structure="direct",
+                bytes=info.nbytes)
+        return built
 
     def _exec_join_generated(self, node: P.HashJoin, info
                              ) -> Iterator[Page]:
@@ -5448,15 +5661,20 @@ def _node_replay_fn(nd):
     return None
 
 
-def _apply_steps(page: Page, steps):
+def _apply_steps(page: Page, steps, builds=()):
     """Run a fused scan program's step list (Executor._chain_steps,
     plus a partial-aggregation tail) over one generated page: the
-    page and the deferred flags of its "joinw" / "aggflag" steps."""
+    page and the deferred flags of its "joinw" / "aggflag" steps.
+    ``builds``: the stored builds its "sjoin" steps probe, in the
+    list's order."""
     flags = []
+    builds = iter(builds)
     for kind, fn in steps:
         if kind in ("joinw", "aggflag"):
             page, flag = fn(page)
             flags.append(flag)
+        elif kind == "sjoin":
+            page = fn(page, next(builds))
         else:
             page = fn(page)
     return page, tuple(flags)
@@ -5524,6 +5742,41 @@ def _merge_compact_flag(acc: Page, page: Page, cap: int):
     )
 
 
+def _extend_with_match(page: Page, bpage: Page, extra_pairs, join_type
+                       ) -> Page:
+    """The probe page extended in place by its matched build rows
+    (kernel; the tail of the generated and the stored joins, whose
+    probe rows match at most one build row): ``bpage`` holds the build
+    row found for each probe slot, valid where the pivot key matched;
+    the non-pivot key pairs are equality checks against it (SQL
+    semantics: NULL on either side never matches); a left join keeps
+    every probe row with a NULL build side where nothing matched."""
+    matched = bpage.valid
+    for lk, rk in extra_pairs:
+        lblk, rblk = page.block(lk), bpage.block(rk)
+        eq = lblk.data.astype(jnp.int64) == rblk.data.astype(jnp.int64)
+        if lblk.nulls is not None:
+            eq = eq & ~lblk.nulls
+        if rblk.nulls is not None:
+            eq = eq & ~rblk.nulls
+        matched = matched & eq
+    if join_type == "left":
+        right_blocks = tuple(
+            Block(
+                data=b.data, type=b.type,
+                nulls=(~matched if b.nulls is None
+                       else (b.nulls | ~matched)),
+                dictionary=b.dictionary,
+            )
+            for b in bpage.blocks
+        )
+        out_valid = page.valid
+    else:  # inner
+        right_blocks = bpage.blocks
+        out_valid = page.valid & matched
+    return Page(blocks=page.blocks + right_blocks, valid=out_valid)
+
+
 def _generated_join_page(left_key_ch, extra_pairs, join_type, inv, gen,
                          scan_types, scan_dicts, chain_fns, n_rows,
                          page: Page) -> Page:
@@ -5548,32 +5801,110 @@ def _generated_join_page(left_key_ch, extra_pairs, join_type, inv, gen,
     bpage = Page(blocks=blocks, valid=found & gvalid)
     for fn in chain_fns:
         bpage = fn(bpage)
-    matched = bpage.valid
-    # non-pivot key pairs: equality against the generated build columns
-    # (SQL semantics: NULL on either side never matches)
-    for lk, rk in extra_pairs:
-        lblk, rblk = page.block(lk), bpage.block(rk)
-        eq = lblk.data.astype(jnp.int64) == rblk.data.astype(jnp.int64)
-        if lblk.nulls is not None:
-            eq = eq & ~lblk.nulls
-        if rblk.nulls is not None:
-            eq = eq & ~rblk.nulls
-        matched = matched & eq
-    if join_type == "left":
-        right_blocks = tuple(
-            Block(
-                data=b.data, type=b.type,
-                nulls=(~matched if b.nulls is None
-                       else (b.nulls | ~matched)),
-                dictionary=b.dictionary,
-            )
-            for b in bpage.blocks
-        )
-        out_valid = page.valid
-    else:  # inner
-        right_blocks = bpage.blocks
-        out_valid = page.valid & matched
-    return Page(blocks=page.blocks + right_blocks, valid=out_valid)
+    return _extend_with_match(page, bpage, extra_pairs, join_type)
+
+
+def _stored_build_page(reads, scan_types, scan_dicts, chain_fns, key_ch,
+                       rows: int, cap: int, datas, valid):
+    """A stored join's build (kernel): the whole stored table as ONE
+    page (its buffers are the program's arguments), the build side's
+    Filter / Project chain over it, and a direct-address table over
+    the surviving rows' keys: entry ``key - floor`` holds the row's
+    index, -1 where no row has the key (``floor``: the least key that
+    survived, so the table spans the keys that can match and a filter
+    that narrows them narrows it). Returns ((table, floor, page),
+    flag): flag where a key lies past the table's ``cap`` entries or
+    two rows share a key, which the caller defers to the overflow
+    ladder (the retry takes the materialized join)."""
+    datas, valid = reads.body(rows, datas, valid)(0)
+    page = Page(blocks=tuple(
+        Block(data=d, type=t, nulls=None, dictionary=dic)
+        for d, t, dic in zip(datas, scan_types, scan_dicts)
+    ), valid=valid)
+    for fn in chain_fns:
+        page = fn(page)
+    kblk = page.block(key_ch)
+    ok = page.valid
+    if kblk.nulls is not None:
+        ok = ok & ~kblk.nulls
+    keys = kblk.data.astype(jnp.int64)
+    floor = jnp.min(jnp.where(ok, keys, jnp.iinfo(jnp.int64).max))
+    floor = jnp.where(jnp.any(ok), floor, jnp.int64(0))
+    # modular: the true distance wherever key >= floor, huge below it
+    off = keys.astype(jnp.uint64) - floor.astype(jnp.uint64)
+    inside = ok & (off < jnp.uint64(cap))
+    slot = jnp.where(inside, off, jnp.uint64(cap)).astype(jnp.int32)
+    rid = jnp.arange(rows, dtype=jnp.int32)
+    table = jnp.full((cap,), -1, dtype=jnp.int32).at[slot].set(
+        rid, mode="drop")
+    # of two rows with one key the scatter keeps one: the other finds
+    # a stranger in its entry
+    shared = inside & (table[jnp.minimum(slot, cap - 1)] != rid)
+    page = Page(blocks=tuple(_carried_wide(b) for b in page.blocks),
+                valid=page.valid)
+    return (table, floor, page), jnp.any(ok & ~inside) | jnp.any(shared)
+
+
+def _narrow_int(blk: Block):
+    """The block's own integer dtype where it is narrower than 64
+    bits (INTEGER, DATE, dictionary codes), else None."""
+    if isinstance(blk.data, tuple):
+        return None
+    try:
+        dt = jnp.dtype(blk.type.device_dtype)
+    except NotImplementedError:
+        return None
+    if jnp.issubdtype(dt, jnp.integer) and dt.itemsize < 8:
+        return dt
+    return None
+
+
+def _carried_wide(blk: Block) -> Block:
+    """A build column as a stored join's probe is handed it: integer
+    columns narrower than 64 bits are held as int64. The probe program
+    gathers from its ARGUMENTS, and what the TPU compiler does with an
+    argument decides the gather's cost: a 64-bit argument is split
+    into halves that it keeps in the core's vector memory (7 ns a row
+    gathered, the same on every run), a 32-bit argument of this size
+    can stay in HBM (23 ns a row, and a level of its own every
+    process: PERF.md, PR 44). _carried_narrow undoes it on the
+    gathered rows; only the low half is gathered then."""
+    dt = _narrow_int(blk)
+    if dt is None or blk.data.dtype != dt:
+        return blk
+    return Block(data=blk.data.astype(jnp.int64), type=blk.type,
+                 nulls=blk.nulls, dictionary=blk.dictionary)
+
+
+def _carried_narrow(blk: Block) -> Block:
+    dt = _narrow_int(blk)
+    if dt is None or blk.data.dtype != jnp.int64:
+        return blk
+    return Block(data=blk.data.astype(dt), type=blk.type,
+                 nulls=blk.nulls, dictionary=blk.dictionary)
+
+
+def _stored_join_page(pivot_ch, extra_pairs, join_type, page: Page,
+                      build) -> Page:
+    """A stored join's probe (kernel), a step of the fused scan
+    program: the probe key's entry of the build's direct-address table
+    is the build row (exact: an entry is one key's), whose columns are
+    gathered from the build page; the other key pairs are equality
+    checks on that row. At most one match a probe row, so the output
+    is the probe page extended in place, as a generated join's is."""
+    table, floor, bpage = build
+    cap = table.shape[0]
+    kblk = page.block(pivot_ch)
+    off = kblk.data.astype(jnp.int64).astype(jnp.uint64) - \
+        floor.astype(jnp.uint64)
+    bid = table[jnp.minimum(off, jnp.uint64(cap - 1)).astype(jnp.int32)]
+    matched = (off < jnp.uint64(cap)) & (bid >= 0)
+    if kblk.nulls is not None:
+        matched = matched & ~kblk.nulls
+    found = gather_rows(bpage, bid, matched)
+    found = Page(blocks=tuple(_carried_narrow(b) for b in found.blocks),
+                 valid=found.valid)
+    return _extend_with_match(page, found, extra_pairs, join_type)
 
 
 def _generated_join_window_page(left_key_ch, extra_pairs, join_type, inv,
@@ -5621,23 +5952,7 @@ def _generated_join_window_page(left_key_ch, extra_pairs, join_type, inv,
     bpage = Page(blocks=blocks, valid=any_match & gvalid)
     for fn in chain_fns:
         bpage = fn(bpage)
-    matched = bpage.valid
-    if join_type == "left":
-        right_blocks = tuple(
-            Block(
-                data=b.data, type=b.type,
-                nulls=(~matched if b.nulls is None
-                       else (b.nulls | ~matched)),
-                dictionary=b.dictionary,
-            )
-            for b in bpage.blocks
-        )
-        out_valid = page.valid
-    else:  # inner
-        right_blocks = bpage.blocks
-        out_valid = page.valid & matched
-    out = Page(blocks=page.blocks + right_blocks, valid=out_valid)
-    return out, jnp.any(multi)
+    return _extend_with_match(page, bpage, (), join_type), jnp.any(multi)
 
 
 def _build_join_index(left_keys, right_keys, page: Page, build: Page):

@@ -291,6 +291,26 @@ def audit(ex, node) -> AuditReport:
                     out_row_b, chunked=target < ex.page_rows)
                 walk(n.left)
                 return
+            sj = ex._stored_join_info(n, left_types)
+            if sj is not None:
+                # a stored build (connectors/cached.py): its lookup
+                # structure lives beside the resident tables for the
+                # statement: the build side's page, a row a stored
+                # slot, and the direct-address table's entries spread
+                # over those rows; the probe is a step of the fused
+                # chain, whose page is as wide as a generated join's
+                add(f"stored join build {sj.scan.table} "
+                    f"({n.join_type})", sj.rows,
+                    -(-sj.nbytes // max(sj.rows, 1)))
+                out_types = ex.output_types(n)
+                out_row_b = _row_bytes(out_types)
+                target = ex._governed_target_rows(
+                    out_types, count=False, row_bytes=out_row_b
+                )
+                add(f"stored join chain page ({n.join_type})", target,
+                    out_row_b, chunked=target < ex.page_rows)
+                walk(n.left)
+                return
             row_b = _row_bytes(right_types)
             est_build = ex.estimate_rows(n.right)
             parts, governed = ex._join_parts(

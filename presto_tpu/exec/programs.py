@@ -69,6 +69,14 @@ PROGRAM_LABELS: Dict[str, str] = {
     "radix_probe": "join",
     "pallas_ubuild": "join",
     "pallas_probe": "join",
+    # a join whose build side is a stored table (connectors/cached.py):
+    # the build, one program over the whole stored table that makes
+    # its lookup structure once a statement, and the fused scan step
+    # (one split, a batch of splits) whose step list probes such
+    # structures, handed to it as arguments
+    "stored_build": "join",
+    "stored_probe": "join",
+    "stored_probe_batch": "join",
     "semi": "join",
     "cross": "join",
     "genjoin": "join",
@@ -127,6 +135,7 @@ PROGRAM_LABELS: Dict[str, str] = {
 # over a mesh
 FUSED_SCAN_LABELS = frozenset(("fused", "fused_batch", "xq_batch",
                                "stored", "stored_batch",
+                               "stored_probe", "stored_probe_batch",
                                "d_scan", "d_fused", "d_fused_batch"))
 
 

@@ -116,6 +116,13 @@ SPAN_KINDS: Dict[str, str] = {
                      "statement whose scan touched it first; attrs: "
                      "columns, slots, bytes; on the profiler's host "
                      "plane resident_load:<table>",
+    "join_build": "one stored join's lookup structure built "
+                  "(Executor._stored_build), named by the build "
+                  "side's table: source lookup to the build "
+                  "program's enqueue, whose launch span lies inside "
+                  "it; attrs: table, rows, capacity, structure, bytes; "
+                  "its wall sums to join_build_wall_us; on the "
+                  "profiler's host plane join_build:<table>",
     "cache": "one result-cache point served (presto_tpu/cache/): "
              "hit:<Node> replays stored pages (attrs: pages, key) in "
              "the span's interval — compile+launch skipped; "

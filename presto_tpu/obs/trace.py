@@ -54,7 +54,8 @@ PHASE_KINDS = ("queue", "parse", "plan", "execute", "encode")
 # the phase, and what its self time is taken against. Not ``attempt``
 # (the container) nor ``operator`` (per-node wall totals laid at the
 # attempt's start, overlapping by design)
-INTERVAL_KINDS = ("launch", "wait", "xfer", "eager", "resident_load")
+INTERVAL_KINDS = ("launch", "wait", "xfer", "eager", "resident_load",
+                  "join_build")
 
 
 def annotation(name: str):

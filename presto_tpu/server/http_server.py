@@ -511,6 +511,8 @@ class QueryManager:
         "mesh_batched_rounds", "row_counts_launched", "row_counts_eager",
         "dispatch_wall_us", "device_wait_us",
         "resident_splits_scanned", "resident_bytes_scanned",
+        "join_builds", "join_build_rows", "join_build_bytes",
+        "join_build_wall_us",
         "plan_constants_folded",
     )
     _EXEC_TOTAL_MAX = ("queries_per_launch",)

@@ -20,6 +20,7 @@ executor's CPU-only rendezvous fence) to their TPU side.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -439,3 +440,96 @@ def test_four_device_repartition_is_an_all_to_all(topo, tpu_branches):
     # each device holds its shard, not the whole page
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < rows * (8 + 8 + 1)
+
+
+@pytest.mark.parametrize("template", ["q3", "q5"])
+def test_stored_join_build_and_probe_compile_at_sf1(
+        template, one_chip, tpu_branches, monkeypatch):
+    """ISSUE 44: the schema at SF1 as the resident store holds it,
+    handed to a stored Q3 / Q5 as ARGUMENTS: one ``stored_build`` a
+    join over the whole stored build table (orders: 1.5 M slots into a
+    direct-address table of 8 M entries), then the chip's batched
+    fused scan step over lineitem, 16 splits a launch, with every
+    probe inside and the builds as arguments. All of them compile for
+    the chip; no 64-bit sort is in a build and no sort at all in the
+    probe step, whose temporaries stay far below a table's size."""
+    from benchmarks.harness import manifest
+    from presto_tpu.cache.rules import snapshot_of
+    from presto_tpu.connectors import cached
+    from presto_tpu.connectors.base import Split
+    from presto_tpu.connectors.tpch import TpchConnector
+    from presto_tpu.exec import membudget as MB
+    from presto_tpu.exec import programs as PG
+    from presto_tpu.runner import LocalRunner
+
+    cell = manifest.load_cell("join_sf1_resident_solo")
+    (st,) = [s for s in cell.every if s.key == f"{template}_sf1#0"]
+    inner = TpchConnector(scale=1.0)
+    conn = cached.ResidentConnector(inner)
+    tables = {"q3": ("lineitem", "orders", "customer"),
+              "q5": ("lineitem", "orders", "customer", "supplier",
+                     "nation", "region")}[template]
+    held = 0
+    for table in tables:
+        slots = inner.row_count(table)
+        pad = min(cached.LOAD_ROWS, SH.bucket(slots))
+        names = tuple(inner.table_schema(table).column_names())
+        piece = jax.eval_shape(lambda t=table, n=names, p=pad: (
+            inner.page_for_split(Split(t, 0, min(p, 1 << 16)), n)))
+        page = jax.tree.map(
+            lambda x, c=slots + pad: _spec((2, c), jnp.uint32, one_chip)
+            if x.dtype.itemsize == 8 else _spec((c,), x.dtype, one_chip),
+            piece)
+        nbytes = sum(x.dtype.itemsize * x.size
+                     for x in jax.tree.leaves(page))
+        held += nbytes
+        conn._store[table] = cached._Stored(
+            snapshot_of(inner, table), page,
+            tuple(cached._leaf_dtypes([b]) for b in piece.blocks),
+            slots, pad, nbytes)
+    monkeypatch.setattr(MB, "device_hbm_bytes", lambda: 16 << 30)
+    built = []
+
+    def handed(sink, program, *args, **kwargs):
+        if program.label != "stored_build":
+            raise _Handed(program, args)
+        compiled = program.jitted.lower(*_on(args, one_chip)).compile()
+        built.append((compiled, args))
+        return jax.eval_shape(program.jitted, *args)
+
+    monkeypatch.setattr(PG, "launch", handed)
+    runner = LocalRunner({"tpch_sf1": conn}, default_catalog="tpch_sf1",
+                         page_rows=1 << 18)
+    runner.executor.fault_rows = SH.SAFE_BUFFER_ROWS
+    with pytest.raises(_Handed) as caught:
+        runner.execute(st.sql)
+    program, args = caught.value.args
+    assert program.label == "stored_probe_batch"
+    assert len(built) == {"q3": 2, "q5": 5}[template]
+    for compiled, _args in built:
+        # the compiler sorts a scatter's (index, row) pairs, 32-bit
+        # both: no 64-bit sort, the kind that compiles for minutes
+        sorts = [ln for ln in compiled.as_text().splitlines()
+                 if " sort(" in ln]
+        assert not [ln for ln in sorts if "64[" in ln], sorts
+    compiled = program.jitted.lower(*_on(args, one_chip)).compile()
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert " sort(" not in text
+    assert memory.temp_size_in_bytes < 1 << 30, memory
+    assert memory.argument_size_in_bytes < held
+    # every gather of the step reads a lookup structure the compiler
+    # keeps in the core's vector memory (memory space S(1) of the
+    # layout): a carried 32-bit column handed over as an argument of
+    # its own width stays in HBM, where a gathered row costs three
+    # times as much and the statement's time takes a level of its own
+    # every process (PERF.md, PR 44), so the build holds such columns
+    # as 64-bit (_carried_wide)
+    defined = dict(re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ", text, re.M))
+    gathers = [ln for ln in text.splitlines()
+               if "kind=kCustom" in ln and "/gather" in ln]
+    assert len(gathers) >= {"q3": 6, "q5": 10}[template]
+    for ln in gathers:
+        operand = re.search(r" fusion\(%?([\w.\-]+)", ln).group(1)
+        assert "S(1)" in defined[operand], (operand, defined[operand])

@@ -310,7 +310,28 @@ def test_load_catalogs_builds_the_resident_catalog(tmp_path):
     assert conn.gen_at("orders", ("o_orderdate",)) is not None
 
 
+@pytest.mark.parametrize("tables", ["*", " * ", None])
+def test_star_and_absent_both_store_every_table(tmp_path, tables):
+    """``resident.tables=*`` (ISSUE 44) is the explicit spelling of
+    "every table, each at its first scan"; nothing loads before one."""
+    props = {} if tables is None else {"resident.tables": tables}
+    etc = _write_catalog(tmp_path, **{
+        "connector.name": "resident", "resident.inner": "tpch",
+        "tpch.scale-factor": "0.01"}, **props)
+    conn = load_catalogs(etc)["tpch"]
+    assert isinstance(conn, ResidentConnector) and conn._tables is None
+    assert all(conn.stores(t) for t in conn.tables())
+    assert conn.resident_loads == 0 and not conn._store
+    assert conn.gen_body("orders", 8, ("o_orderkey",)) is None
+    assert conn.gen_at("orders", ("o_orderdate",)) is None
+    assert conn.key_inverse("orders", "o_orderkey") is None
+
+
 @pytest.mark.parametrize("props, what", [
+    ({"resident.inner": "tpch", "resident.tables": "*,lineitem"},
+     "* stands for every table and is given alone"),
+    ({"resident.inner": "tpch", "resident.tables": "lineitem, *"},
+     "* stands for every table and is given alone"),
     ({"resident.inner": "nosuch"}, "unknown resident.inner 'nosuch'"),
     ({"resident.inner": "resident"}, "unknown resident.inner"),
     ({}, "unknown resident.inner ''"),

@@ -80,7 +80,8 @@ TOP_EVENTS = 12
 # (obs/trace.INTERVAL_KINDS, not imported: that would import jax with
 # this module): the ones a device gap is put down to, innermost first
 # (``attempt`` is a container and ``execute:<id>`` the statement itself)
-SITE_KINDS = ("launch", "wait", "eager", "xfer", "resident_load")
+SITE_KINDS = ("launch", "wait", "eager", "xfer", "resident_load",
+              "join_build")
 Interval = Tuple[float, float]
 Note = Tuple[str, float, float]
 
@@ -357,6 +358,8 @@ def record(cell_name: str, sids: List[str], out_dir: str,
                          "spill_partitions_used",
                          "resident_splits_scanned",
                          "resident_bytes_scanned",
+                         "join_builds", "join_build_rows",
+                         "join_build_bytes", "join_build_wall_us",
                          "resident_table_bytes", "resident_loads",
                          "resident_load_wall_us")}
             out.append(acc)
