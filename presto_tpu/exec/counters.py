@@ -104,6 +104,12 @@ QUERY_COUNTERS: Dict[str, tuple] = {
         "gauge", "lookup structures this attempt built over stored "
         "tables (Executor._stored_build: one a join whose build side "
         "is a stored table, once a statement; program stored_build)"),
+    "join_probes_at_build": (
+        "gauge", "the joins among them that were probed inside "
+        "another join's build program, once a BUILD row and not once "
+        "a probe slot (Executor._ride_stored_joins: an inner join "
+        "whose key an earlier stored join's build carries; TPC-H Q3 "
+        "1, Q5 3); such a join has no step in the fused scan"),
     "join_build_rows": (
         "gauge", "stored slots those builds read (the build tables' "
         "sizes: from shapes, no device read)"),

@@ -129,6 +129,33 @@ class StoredJoin(NamedTuple):
     rows: int              # the stored table's slots
     cap: int               # entries of the direct-address table
     nbytes: int            # device bytes of the whole structure
+    # what Executor._ride_stored_joins adds, from the chain alone:
+    rides: bool = False    # probed inside an earlier link's build
+    riders: tuple = ()     # the StoredRiders probed inside this build
+
+
+class StoredRider(NamedTuple):
+    """A stored join probed inside the build of the earlier join that
+    carries its key (Executor._ride_stored_joins): once a BUILD row of
+    that join, not once a probe slot."""
+
+    node: object           # the rider's P.HashJoin
+    info: StoredJoin       # as _stored_join_info gave it
+    pivot_ch: int          # its probe key, a channel of the build page
+    pairs: tuple           # its key pairs checked in the build
+
+
+def _stored_link(link) -> bool:
+    """Whether a _scan_chain link is a (HashJoin, StoredJoin) pair."""
+    return isinstance(link, tuple) and isinstance(link[1], StoredJoin)
+
+
+def _stored_join_bytes(cap: int, rows: int, types) -> int:
+    """Device bytes of a stored join's lookup structure: the table's
+    int32 entries, its key floor, and the build page as the chain (and
+    the joins that ride on it) leave it, one row a stored slot; a
+    column narrower than 8 bytes counted at 8 (_carried_wide)."""
+    return 4 * cap + 8 + rows * max(_row_bytes(types), 2 + 8 * len(types))
 
 
 def _plain_int(t) -> bool:
@@ -597,11 +624,14 @@ class Executor:
         # this attempt: join_builds = lookup structures built (one a
         # join, once a statement), join_build_rows = stored slots those
         # builds read, join_build_bytes = device bytes the structures
-        # hold (both from shapes: no device read), join_build_wall_us
+        # hold (both from shapes: no device read), join_probes_at_build
+        # = the joins among them probed inside another's build program,
+        # once a build row (_ride_stored_joins), join_build_wall_us
         # = host microseconds from a build's start to its program's
         # enqueue (the join_build spans' sum; the device's part is the
         # trace's jit_stored_build)
         self.join_builds = 0
+        self.join_probes_at_build = 0
         self.join_build_rows = 0
         self.join_build_bytes = 0
         self.join_build_wall_us = 0
@@ -1581,8 +1611,9 @@ class Executor:
         """Walk a Filter/Project/Exchange chain (and, when
         through_joins, generated-join-eligible HashJoins; with
         stored_joins also the ones whose build is a stored table,
-        _stored_join_info: the one-chip fused stream alone asks for
-        them) down to its TableScan. THE one chain walker shared by
+        _stored_join_info, those that ride on an earlier build marked
+        by _ride_stored_joins: the one-chip fused stream alone asks
+        for them) down to its TableScan. THE one chain walker shared by
         the generated-join eligibility check and the fused-pipeline
         builder. Returns (scan, chain top-down) with HashJoins as
         (node, info) tuples, or None when any node breaks the chain."""
@@ -1602,9 +1633,63 @@ class Executor:
                 chain.append((cur, info))
                 cur = cur.left
             elif isinstance(cur, P.TableScan):
+                if stored_joins:
+                    chain = self._ride_stored_joins(cur, chain)
                 return cur, chain
             else:
                 return None
+
+    def _ride_stored_joins(self, scan: P.TableScan, chain: List) -> List:
+        """A stored join rides on the build that carries its key. In a
+        _scan_chain's links (top-down) a StoredJoin link J1 rides on an
+        earlier one J0 when both are inner joins, J1's pivot probe
+        channel is one J0 appended to the page (directly, or through a
+        join that itself rides on J0), only joins that ride on J0 lie
+        between them (any other link, a Filter, a Project, an Exchange
+        or another join, ends the run), and J0's build has no more
+        rows than the probe table has slots, so the lookup is made
+        fewer times, never more. J1 is then probed once a BUILD row inside J0's build
+        program (_stored_build_page) and emits no step: J0's page
+        carries its columns after J0's own, in the chain's order, and
+        J0's step gathers them all at once. A rider's key pair whose
+        probe side lies below J0's columns becomes a pair of J0's
+        step. Read from the chain and from nothing else; a chain
+        without such a pair of links comes back as it is."""
+        if sum(map(_stored_link, chain)) < 2:
+            return chain
+        out = list(chain)
+        probe_rows = int(self.catalogs[scan.catalog].row_count(scan.table))
+        budget = self._budget()
+        head = None     # the run's J0: index in out, its first channel
+        for i in range(len(out) - 1, -1, -1):
+            link = out[i]
+            if not (_stored_link(link) and link[0].join_type == "inner"):
+                head = None
+                continue
+            jnode, info = link
+            first = len(self.output_types(jnode.left))
+            if head is not None and head[1] <= info.pivot_ch < first:
+                j0, base = head
+                hnode, hinfo = out[j0]
+                at = first - base    # the rider's columns on J0's page
+                types = self.output_types(jnode)[base:]
+                nbytes = _stored_join_bytes(hinfo.cap, hinfo.rows, types)
+                if not budget or nbytes <= budget // MB.BUILD_SHARE_DIV:
+                    rider = StoredRider(
+                        node=jnode, info=info,
+                        pivot_ch=info.pivot_ch - base,
+                        pairs=tuple((lk - base, rk)
+                                    for lk, rk in info.extra_pairs
+                                    if lk >= base))
+                    out[j0] = (hnode, hinfo._replace(
+                        extra_pairs=hinfo.extra_pairs + tuple(
+                            (lk, at + rk) for lk, rk in info.extra_pairs
+                            if lk < base),
+                        nbytes=nbytes, riders=hinfo.riders + (rider,)))
+                    out[i] = (jnode, info._replace(rides=True))
+                    continue
+            head = (i, first) if info.rows <= probe_rows else None
+        return out
 
     def _chain_holds_cache_point(self, scan, chain) -> bool:
         """A chain member that is a live result-cache point must stay
@@ -1629,13 +1714,17 @@ class Executor:
         join, ("joinw", page -> (page, multi_flag)) for a windowed
         one, ("sjoin", (page, build) -> page) for the probe of a
         stored build (_apply_steps hands it the chain's builds in
-        this order; _fused_stream makes them). THE one step list of
+        this order; _fused_stream makes them; a join that rides on an
+        earlier build, _ride_stored_joins, has no step: that build's
+        step appends its columns). THE one step list of
         the one-chip fused stream and of the mesh executor's fused
         scan round (dist/executor.py)."""
         steps: List = []
         for nd in reversed(chain):
-            if isinstance(nd, tuple) and isinstance(nd[1], StoredJoin):
+            if _stored_link(nd):
                 jnode, info = nd
+                if info.rides:
+                    continue
                 steps.append(("sjoin", functools.partial(
                     _stored_join_page, info.pivot_ch, info.extra_pairs,
                     jnode.join_type)))
@@ -1725,10 +1814,11 @@ class Executor:
         # a join over a stored table (_stored_join_info): its lookup
         # structure, built once a statement (_stored_build), is handed
         # to every launch beside the source's buffers, bottom-up as
-        # the step list probes them
+        # the step list probes them (a join that rides on another's
+        # build is made with it and probed inside it)
         builds = []
         for link in reversed(chain):
-            if isinstance(link, tuple) and isinstance(link[1], StoredJoin):
+            if _stored_link(link) and not link[1].rides:
                 built = self._stored_build(link[0], link[1])
                 if built is None:
                     return None  # a column holds NULLs: through pages()
@@ -2601,6 +2691,8 @@ class Executor:
                            resident_bytes_scanned=(
                                self.resident_bytes_scanned),
                            join_builds=self.join_builds,
+                           join_probes_at_build=(
+                               self.join_probes_at_build),
                            join_build_rows=self.join_build_rows,
                            join_build_bytes=self.join_build_bytes,
                            **self._agg_sizing_attrs())
@@ -2668,6 +2760,7 @@ class Executor:
         self.resident_splits_scanned = 0
         self.resident_bytes_scanned = 0
         self.join_builds = 0
+        self.join_probes_at_build = 0
         self.join_build_rows = 0
         self.join_build_bytes = 0
         self.join_build_wall_us = 0
@@ -4146,11 +4239,7 @@ class Executor:
             return None
         rows = int(conn.row_count(scan.table))
         cap = SH.bucket(max(rows, 1)) * STORED_JOIN_KEY_SPREAD
-        # the table's int32 entries, its key floor, and the build side
-        # as its chain leaves it (one row a stored slot; a column
-        # narrower than 8 bytes counted at 8: _carried_wide)
-        nbytes = 4 * cap + 8 + rows * max(
-            _row_bytes(right_types), 2 + 8 * len(right_types))
+        nbytes = _stored_join_bytes(cap, rows, right_types)
         budget = self._budget()
         if budget and nbytes > budget // MB.BUILD_SHARE_DIV:
             return None
@@ -4171,14 +4260,24 @@ class Executor:
         """The lookup structure of one stored join (_stored_build_page:
         the direct-address table, its key floor, the build side's
         page), built ONCE an attempt by one program over the whole
-        stored table, whose buffers are the program's arguments. None
-        where the table cannot be read as a fused source (a column
-        holds NULLs). Its flag (keys wider than the table, or a
-        duplicate key) joins the deferred ladder."""
-        key = (node.right, info.build_key_ch)
+        stored table, whose buffers are the program's arguments. The
+        joins that ride on it (_ride_stored_joins) are built first and
+        probed inside that program, their builds its arguments after
+        the table's. None where a table cannot be read as a fused
+        source (a column holds NULLs). Its flag (keys wider than the
+        table, or a duplicate key) joins the deferred ladder."""
+        riding = tuple((r.node.right, r.info.build_key_ch, r.pivot_ch,
+                        r.pairs) for r in info.riders)
+        key = (node.right, info.build_key_ch, *riding)
         built = self._stored_builds.get(key)
         if built is not None:
             return built
+        ridden = []
+        for r in info.riders:
+            rbuilt = self._stored_build(r.node, r.info)
+            if rbuilt is None:
+                return None
+            ridden.append(rbuilt)
         t0 = time.perf_counter()
         scan = info.scan
         conn = self.catalogs[scan.catalog]
@@ -4193,18 +4292,20 @@ class Executor:
             scan_dicts = tuple(dicts.get(c) for c in names)
             fn = self._jit(
                 ("stored_build", node.right, info.build_key_ch,
-                 src.rows, info.cap),
+                 src.rows, info.cap, *riding),
                 functools.partial(
                     _stored_build_page, src.reads, scan_types,
                     scan_dicts, info.chain_fns, info.build_key_ch,
-                    src.rows, info.cap))
-            built, flag = fn(*src.args)
+                    src.rows, info.cap,
+                    tuple((r.pivot_ch, r.pairs) for r in info.riders)))
+            built, flag = fn(*src.args, *ridden)
         self._pending_overflow.append(flag)
         self._stored_builds[key] = built
         self.peak_memory_bytes = max(self.peak_memory_bytes,
                                      info.nbytes)
         wall = time.perf_counter() - t0
         self.join_builds += 1
+        self.join_probes_at_build += len(ridden)
         self.join_build_rows += src.rows
         self.join_build_bytes += info.nbytes
         self.join_build_wall_us += int(round(wall * 1e6))
@@ -4212,7 +4313,8 @@ class Executor:
             self.span_ending_now(
                 "join_build", scan.table, wall, table=scan.table,
                 rows=src.rows, capacity=info.cap, structure="direct",
-                bytes=info.nbytes)
+                bytes=info.nbytes,
+                riders=[r.info.scan.table for r in info.riders])
         return built
 
     def _exec_join_generated(self, node: P.HashJoin, info
@@ -5805,10 +5907,16 @@ def _generated_join_page(left_key_ch, extra_pairs, join_type, inv, gen,
 
 
 def _stored_build_page(reads, scan_types, scan_dicts, chain_fns, key_ch,
-                       rows: int, cap: int, datas, valid):
+                       rows: int, cap: int, riders, datas, valid,
+                       *ridden):
     """A stored join's build (kernel): the whole stored table as ONE
     page (its buffers are the program's arguments), the build side's
-    Filter / Project chain over it, and a direct-address table over
+    Filter / Project chain over it, the probes of the inner joins that
+    ride on it (``riders``: each one's (pivot channel, key pairs) on
+    this page; ``ridden``: their builds, arguments too), each the
+    probe step's own kernel with this page as its probe page, so a
+    row whose rider finds no match is dropped here, once, and its
+    columns follow this build's own; then a direct-address table over
     the surviving rows' keys: entry ``key - floor`` holds the row's
     index, -1 where no row has the key (``floor``: the least key that
     survived, so the table spans the keys that can match and a filter
@@ -5823,6 +5931,8 @@ def _stored_build_page(reads, scan_types, scan_dicts, chain_fns, key_ch,
     ), valid=valid)
     for fn in chain_fns:
         page = fn(page)
+    for (pivot_ch, pairs), rbuilt in zip(riders, ridden):
+        page = _stored_join_page(pivot_ch, pairs, "inner", page, rbuilt)
     kblk = page.block(key_ch)
     ok = page.valid
     if kblk.nulls is not None:

@@ -230,7 +230,7 @@ def audit(ex, node) -> AuditReport:
     so the prediction and the execution cannot drift apart. No pages
     are generated and nothing touches the device."""
     from presto_tpu.exec import plan as P
-    from presto_tpu.exec.executor import _row_bytes
+    from presto_tpu.exec.executor import _row_bytes, _stored_link
 
     budget = ex._budget()
     fault = ex._fault_rows()
@@ -266,6 +266,8 @@ def audit(ex, node) -> AuditReport:
     # topn_merge site — but TopN never reaches add(): its candidate
     # set is bounded by the limit bucket, noise next to real buffers)
 
+    ridden = {}     # id(HashJoin) -> its StoredJoin as the chain has it
+
     def walk(n):
         if isinstance(n, P.TableScan):
             types = ex.output_types(n)
@@ -298,7 +300,18 @@ def audit(ex, node) -> AuditReport:
                 # statement: the build side's page, a row a stored
                 # slot, and the direct-address table's entries spread
                 # over those rows; the probe is a step of the fused
-                # chain, whose page is as wide as a generated join's
+                # chain, whose page is as wide as a generated join's.
+                # A build's page also holds the columns of the joins
+                # that ride on it (Executor._ride_stored_joins), which
+                # the chain says, so the chain's links are kept from
+                # its topmost join down
+                if id(n) not in ridden:
+                    walked = ex._scan_chain(n, through_joins=True,
+                                            stored_joins=True)
+                    for link in walked[1] if walked else ():
+                        if _stored_link(link):
+                            ridden.setdefault(id(link[0]), link[1])
+                sj = ridden.get(id(n), sj)
                 add(f"stored join build {sj.scan.table} "
                     f"({n.join_type})", sj.rows,
                     -(-sj.nbytes // max(sj.rows, 1)))

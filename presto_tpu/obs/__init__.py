@@ -120,7 +120,9 @@ SPAN_KINDS: Dict[str, str] = {
                   "(Executor._stored_build), named by the build "
                   "side's table: source lookup to the build "
                   "program's enqueue, whose launch span lies inside "
-                  "it; attrs: table, rows, capacity, structure, bytes; "
+                  "it; attrs: table, rows, capacity, structure, bytes, "
+                  "riders (the tables of the joins probed inside this "
+                  "build's program, join_probes_at_build); "
                   "its wall sums to join_build_wall_us; on the "
                   "profiler's host plane join_build:<table>",
     "cache": "one result-cache point served (presto_tpu/cache/): "

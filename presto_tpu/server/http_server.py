@@ -512,7 +512,7 @@ class QueryManager:
         "dispatch_wall_us", "device_wait_us",
         "resident_splits_scanned", "resident_bytes_scanned",
         "join_builds", "join_build_rows", "join_build_bytes",
-        "join_build_wall_us",
+        "join_build_wall_us", "join_probes_at_build",
         "plan_constants_folded",
     )
     _EXEC_TOTAL_MAX = ("queries_per_launch",)

@@ -23,12 +23,20 @@ PAGE_ROWS = 16384
 CELL = manifest.load_cell("join_sf1_resident_solo")
 STATEMENTS = {st.key: st for st in CELL.every}
 JOINS = {"q3": 2, "q5": 5}
+# the joins among them whose key an earlier build carries (ISSUE 45):
+# probed inside that build's program, once a build row, with no step of
+# their own in the fused scan (Q3: customer on orders; Q5: nation and
+# region on supplier, customer on orders)
+RIDERS = {"q3": {"orders": ["customer"], "customer": []},
+          "q5": {"supplier": ["nation", "region"], "nation": [],
+                 "region": [], "orders": ["customer"], "customer": []}}
+AT_BUILD = {"q3": 1, "q5": 3}
 # stored slots a statement's builds read at SF0.01 (orders 15,000,
 # customer 1,500, supplier 100, nation 25, region 5)
 BUILD_ROWS = {"q3": 16500, "q5": 16630}
 DRIVERS = {"one_split": "auto", "batched": 4}
 COUNTERS = ("join_builds", "join_build_rows", "join_build_bytes",
-            "join_build_wall_us")
+            "join_build_wall_us", "join_probes_at_build")
 CHIP_PATH = {"fused_partial_agg_enabled": "true",
              "split_batch_size": "8",
              "query_trace_enabled": "true"}
@@ -53,6 +61,21 @@ def _runner(conn, split_batch="auto"):
 def _attempts(runner):
     return [sp for sp in runner.last_trace.spans()
             if sp.kind == "attempt"]
+
+
+@pytest.fixture
+def step_kinds(monkeypatch):
+    """The kinds of every step list Executor._chain_steps makes."""
+    seen = []
+    orig = EX.Executor._chain_steps
+
+    def spy(self, chain):
+        steps = orig(self, chain)
+        seen.append([kind for kind, _fn in steps])
+        return steps
+
+    monkeypatch.setattr(EX.Executor, "_chain_steps", spy)
+    return seen
 
 
 @pytest.fixture(scope="module")
@@ -85,14 +108,17 @@ def oracle(generated):
 @pytest.mark.parametrize("driver", sorted(DRIVERS))
 @pytest.mark.parametrize("key", sorted(STATEMENTS))
 def test_stored_q3_q5_equal_the_oracle_and_the_generated_catalog(
-        key, driver, generated, stored, want):
+        key, driver, generated, stored, want, step_kinds):
     """Q3 and Q5 (both variants of join_solo.json) over the stored
     schema: the sqlite reference's rows and, row for row, the generated
-    catalog's; every join reports a build and none is generated."""
+    catalog's; every join reports a build and none is generated; a
+    join whose key an earlier build carries is probed in that build
+    and has no step in the scan."""
     st = STATEMENTS[key]
     gen = _runner(generated, DRIVERS[driver]).execute(st.sql)
     runner = _runner(stored, DRIVERS[driver])
     before = runner.executor.generated_joins_used
+    del step_kinds[:]
     res = runner.execute(st.sql)
     ex = runner.executor
     assert res.rows == gen.rows and res.rows
@@ -103,6 +129,9 @@ def test_stored_q3_q5_equal_the_oracle_and_the_generated_catalog(
     joins = JOINS[st.template]
     assert (ex.join_builds, ex.join_build_rows) == (
         joins, BUILD_ROWS[st.template])
+    assert ex.join_probes_at_build == AT_BUILD[st.template]
+    (kinds,) = [k for k in step_kinds if "sjoin" in k]
+    assert kinds.count("sjoin") == joins - AT_BUILD[st.template]
     assert ex.join_build_bytes > 4 * ex.join_build_rows
     assert ex.join_build_wall_us > 0
     (attempt,) = _attempts(runner)
@@ -118,8 +147,9 @@ def test_stored_q3_q5_equal_the_oracle_and_the_generated_catalog(
         "join_build", "join_probe", "join_probe_unique", "genjoin",
         "resident_read"}, launches
     assert (attempt.attrs["join_builds"],
-            attempt.attrs["join_build_rows"]) == (
-        joins, BUILD_ROWS[st.template])
+            attempt.attrs["join_build_rows"],
+            attempt.attrs["join_probes_at_build"]) == (
+        joins, BUILD_ROWS[st.template], AT_BUILD[st.template])
     builds = [sp for sp in runner.last_trace.spans()
               if sp.kind == "join_build"]
     assert len(builds) == joins
@@ -130,6 +160,12 @@ def test_stored_q3_q5_equal_the_oracle_and_the_generated_catalog(
     assert {sp.attrs["structure"] for sp in builds} == {"direct"}
     assert all(sp.attrs["capacity"] >= 4 * sp.attrs["rows"]
                and sp.attrs["table"] == sp.name for sp in builds)
+    assert {sp.name: sp.attrs["riders"] for sp in builds} == \
+        RIDERS[st.template]
+    # a rider is built before what it rides on, outside its span
+    at = {sp.name: sp for sp in builds}
+    for table, riders in RIDERS[st.template].items():
+        assert all(at[r].t1 <= at[table].t0 for r in riders)
 
 
 def test_star_loads_only_the_tables_a_statement_scans():
@@ -250,6 +286,133 @@ def test_edge_case_gives_the_oracles_rows(name, generated, stored,
     assert ex.generated_joins_used == 0
 
 
+# ------------------------- a join that rides on an earlier build (ISSUE 45)
+_TWO_JOINS = ("select count(*), sum(l_extendedprice), min(c_custkey), "
+              "max(c_custkey) from lineitem, orders, customer "
+              "where l_orderkey = o_orderkey and o_custkey = c_custkey ")
+RIDING = {
+    # sql, stored builds, joins probed inside a build, sjoin steps,
+    # boosted retries
+    "rider_without_a_match_for_some_build_rows": (
+        _TWO_JOINS + "and c_custkey between 500 and 620", 2, 1, 1, 0),
+    "empty_rider_build": (
+        _TWO_JOINS + "and c_mktsegment = 'NOSUCH'", 2, 1, 1, 0),
+    "key_pair_whose_probe_side_is_another_builds": (
+        EDGES["two_key_pairs"][0], 3, 1, 2, 0),
+    "rider_of_a_rider": (
+        "select count(*), sum(l_extendedprice), min(r_name) "
+        "from lineitem, supplier, nation, region where l_suppkey = "
+        "s_suppkey and s_nationkey = n_nationkey and n_regionkey = "
+        "r_regionkey and r_name = 'ASIA'", 3, 2, 1, 0),
+    "left_join_on_a_carried_key": (
+        "select count(*), sum(l_extendedprice), count(c_custkey) "
+        "from lineitem join orders on l_orderkey = o_orderkey "
+        "left join customer on o_custkey = c_custkey "
+        "and c_acctbal > 5000", 2, 0, 2, 0),
+    "inner_join_on_a_left_joins_key": (
+        "select count(*), sum(l_extendedprice), count(c_custkey) "
+        "from lineitem left join orders on l_orderkey = o_orderkey "
+        "and o_totalprice > 100000 "
+        "join customer on o_custkey = c_custkey", 2, 0, 2, 0),
+    "filter_between_the_two_joins": (
+        "select count(*), sum(p) from (select l_extendedprice as p, "
+        "o_custkey as k from lineitem, orders where l_orderkey = "
+        "o_orderkey and l_quantity + o_shippriority < 10) t, customer "
+        "where k = c_custkey and c_acctbal > 0", 2, 0, 2, 0),
+    # customer keyed by a column that repeats, which the first attempt
+    # is told is unique (below): customer rides on supplier, its
+    # build's flag joins the ladder, the boosted retry builds nothing
+    "rider_with_duplicate_keys": (
+        "select count(*), sum(l_extendedprice), sum(c_acctbal) "
+        "from lineitem, supplier, customer where l_suppkey = s_suppkey "
+        "and s_nationkey = c_nationkey and l_quantity < 3", 0, 0, 1, 1),
+}
+RIDING_ORACLE_SQL = {
+    "left_join_on_a_carried_key":
+        RIDING["left_join_on_a_carried_key"][0].replace(
+            "c_acctbal > 5000", "c_acctbal > 500000"),
+    "inner_join_on_a_left_joins_key":
+        RIDING["inner_join_on_a_left_joins_key"][0].replace(
+            "o_totalprice > 100000", "o_totalprice > 10000000"),
+    "filter_between_the_two_joins":
+        RIDING["filter_between_the_two_joins"][0].replace(
+            "l_quantity + o_shippriority < 10",
+            "l_quantity + 100 * o_shippriority < 1000"),
+    "rider_with_duplicate_keys":
+        RIDING["rider_with_duplicate_keys"][0].replace(
+            "l_quantity < 3", "l_quantity < 300"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RIDING))
+def test_a_join_rides_on_the_build_that_carries_its_key(
+        name, generated, stored, oracle, step_kinds, monkeypatch):
+    """Executor._ride_stored_joins: an inner stored join whose pivot
+    key an earlier inner stored join's build carries, with only such
+    joins between them, is probed inside that build's program (a build
+    row whose rider finds nothing has no entry, so its probe rows are
+    dropped) and has no step; a left join, or a Filter / Project
+    between the two, leaves today's steps. The rows are the oracle's
+    and the generated catalog's either way."""
+    sql, builds, at_build, sjoins, retries = RIDING[name]
+    if name == "rider_with_duplicate_keys":
+        honest = EX.Executor._scan_column_unique
+        monkeypatch.setattr(
+            EX.Executor, "_scan_column_unique",
+            lambda self, n, ch: self._capacity_boost == 1
+            or honest(self, n, ch))
+    runner = _runner(stored)
+    res = runner.execute(sql)
+    ex = runner.executor
+    monkeypatch.undo()
+    assert sorted(res.rows) == sorted(_runner(generated).execute(sql).rows)
+    want = oracle.execute(RIDING_ORACLE_SQL.get(name, sql)).fetchall()
+    assert sorted(res.rows, key=repr) == sorted(
+        (tuple(r) for r in want), key=repr)
+    assert (ex.join_builds, ex.join_probes_at_build,
+            ex.capacity_boost_retries) == (builds, at_build, retries)
+    assert ex.generated_joins_used == 0
+    first = next(k for k in step_kinds if "sjoin" in k)
+    assert first.count("sjoin") == sjoins
+    attempts = _attempts(runner)
+    assert len(attempts) == 1 + retries
+    built = {sp.name: sp.attrs["riders"]
+             for sp in runner.last_trace.spans()
+             if sp.kind == "join_build"}
+    if retries:
+        assert attempts[0].attrs["outcome"] == "overflow"
+        assert built == {"customer": [], "supplier": ["customer"]}
+        assert "stored_build" not in attempts[-1].attrs["launches"]
+    else:
+        assert sum(len(r) for r in built.values()) == at_build
+
+
+def test_the_audit_counts_a_riders_columns_on_the_build_it_rides(stored):
+    """membudget.audit reads the chain as _fused_stream does: the page
+    of orders' build holds customer's columns after its own, so its
+    line is what join_build's span reports, and a build without a
+    rider keeps its own."""
+    from presto_tpu.exec import membudget as MB
+
+    runner = _runner(stored)
+    sql = STATEMENTS["q5_sf1#0"].sql
+    runner.execute(sql)
+    spans = {sp.name: sp.attrs["bytes"]
+             for sp in runner.last_trace.spans()
+             if sp.kind == "join_build"}
+    lines = {b.label.split()[3]: b for b in MB.audit(
+        runner.executor, runner.plan(sql)).buffers
+        if b.label.startswith("stored join build")}
+    assert sorted(lines) == sorted(spans) == sorted(RIDERS["q5"])
+    for table, buf in lines.items():
+        assert buf.rows * buf.row_bytes >= spans[table]
+    # orders: 4 B x 65,536 entries, the floor, and 15,000 rows of
+    # three columns of its own and customer's two, 8 B each and the
+    # validity's two: without the rider's columns 16 B a row fewer
+    assert spans["orders"] == 4 * 65536 + 8 + 15000 * (2 + 8 * 5)
+    assert spans["customer"] == 4 * 8192 + 8 + 1500 * (2 + 8 * 2)
+
+
 def test_a_build_over_its_capacity_retries_to_the_same_rows(
         generated, stored, monkeypatch):
     """Order keys use 8 of every 32 values: with one entry a stored
@@ -301,6 +464,7 @@ def test_the_counters_have_one_meaning_after_two_statements(served):
         metrics = served.metrics()
         assert metrics["join_builds"] == JOINS[template]
         assert metrics["join_build_rows"] == BUILD_ROWS[template]
+        assert metrics["join_probes_at_build"] == AT_BUILD[template]
         assert metrics["join_build_bytes"] > 0
         assert metrics["join_build_wall_us"] > 0
         assert metrics["generated_joins_used"] == 0
@@ -310,6 +474,13 @@ def test_the_counters_have_one_meaning_after_two_statements(served):
                       if p["kind"] == "execute"]
         spans = [s for s in execute["spans"] if s["kind"] == "join_build"]
         assert len(spans) == JOINS[template]
+        tree = [sp for stage in info["stages"]
+                for task in stage["tasks"] for sp in task["spans"]]
+        (attempt,) = [sp for sp in tree if sp["kind"] == "attempt"]
+        assert attempt["attrs"]["join_probes_at_build"] == \
+            AT_BUILD[template]
+        assert {sp["name"]: sp["attrs"]["riders"] for sp in tree
+                if sp["kind"] == "join_build"} == RIDERS[template]
         assert sum(s["endUs"] - s["startUs"] for s in spans) == \
             pytest.approx(metrics["join_build_wall_us"], abs=25)
     assert seen["q3"]["join_build_bytes"] != seen["q5"]["join_build_bytes"]
@@ -409,7 +580,9 @@ def test_a_stored_build_page_carries_no_32_bit_integer_column(stored):
         runner.execute(STATEMENTS["q3_sf1#0"].sql)
     finally:
         EX.Executor._stored_build = orig
-    assert [t for t, _ in seen] == ["orders", "customer"]
+    # customer rides on orders: built first, probed in orders' build,
+    # whose page carries its columns too
+    assert [t for t, _ in seen] == ["customer", "orders"]
     for _table, dtypes in seen:
         assert dtypes and all(
             dt == jnp.int64 or not jnp.issubdtype(dt, jnp.integer)

@@ -360,6 +360,7 @@ def record(cell_name: str, sids: List[str], out_dir: str,
                          "resident_bytes_scanned",
                          "join_builds", "join_build_rows",
                          "join_build_bytes", "join_build_wall_us",
+                         "join_probes_at_build",
                          "resident_table_bytes", "resident_loads",
                          "resident_load_wall_us")}
             out.append(acc)
